@@ -9,7 +9,6 @@ genrec_tpu.data.sem_ids.
 
 from __future__ import annotations
 
-import asyncio
 import json
 import logging
 import os
@@ -33,62 +32,6 @@ def _flight():
     from genrec_tpu.obs.flight_recorder import get_flight_recorder
 
     return get_flight_recorder()
-
-
-def _per_host_type_handler_registry():
-    """Type-handler registry for `CheckpointManager(per_host=True)`:
-    the stock numpy/scalar handlers minus their hard-coded
-    ``process_index() == 0`` write gate (orbax assumes one shared
-    directory; with per-host record trees EVERY process is the sole
-    writer of its own tree, in a singleton orbax process group).
-
-    Built lazily because the ungated subclasses override PRIVATE orbax
-    internals (`_background_serialize`) verified against orbax 0.7 —
-    only this optional per-host mode depends on them, so an orbax that
-    reorganized those internals fails HERE with an actionable error,
-    not at import time for every shared-directory user."""
-    from orbax.checkpoint import type_handlers as _oth
-
-    try:
-
-        class _AllHostsNumpyHandler(_oth.NumpyHandler):
-            async def _background_serialize(self, values, infos, args=None):
-                write_coros = []
-                for value, info, arg in zip(values, infos, args):
-                    tspec = self._get_json_tspec_write(
-                        info,
-                        value,
-                        use_ocdbt=info.is_ocdbt_checkpoint,
-                        process_index=_oth.get_process_index_for_subdir(
-                            use_ocdbt=info.is_ocdbt_checkpoint,
-                            override_ocdbt_process_id=(
-                                self._override_ocdbt_process_id
-                            ),
-                        ),
-                        arg=arg,
-                    )
-                    write_coros.append(
-                        self._open_and_write(value, tspec, info.ts_context)
-                    )
-                await asyncio.gather(*write_coros)
-
-        class _AllHostsScalarHandler(_oth.ScalarHandler, _AllHostsNumpyHandler):
-            pass
-
-        return _oth.create_type_handler_registry(
-            (int, _AllHostsScalarHandler()),
-            (float, _AllHostsScalarHandler()),
-            (np.number, _AllHostsScalarHandler()),
-            (np.ndarray, _AllHostsNumpyHandler()),
-        )
-    except AttributeError as e:
-        raise RuntimeError(
-            "CheckpointManager(per_host=True) needs the orbax-checkpoint "
-            "0.7 type_handlers internals its ungated write handlers "
-            f"subclass, but this orbax does not provide them ({e}). "
-            "Install orbax-checkpoint==0.7.* or use the default "
-            "shared-directory mode."
-        ) from e
 
 
 class CheckpointCorruptError(RuntimeError):
@@ -326,7 +269,7 @@ class BestTracker:
 
 
 # Orbax finalizes a step by renaming its tmp dir and then writing this
-# marker (orbax 0.5+). A step dir without it was interrupted mid-commit.
+# marker. A step dir without it was interrupted mid-commit.
 _COMMIT_MARKER = "_CHECKPOINT_METADATA"
 
 
@@ -342,87 +285,37 @@ class CheckpointManager:
     ``<dir>/quarantine/p<process>/`` (kept for post-mortem, excluded from
     discovery) and the ladder falls through to the previous retained step.
 
-    Multi-host semantics:
-
-    - **Coordinated commit** (shared directory, the default): orbax
-      writes every host's shards into the step's tmp dir and process 0
-      finalizes (rename + commit marker) only after an ALL-HOST barrier
-      through the distributed coordination service — a host dying
-      mid-save can never yield a step that is commit-markered for some
-      hosts and absent for others. The barrier is bounded by
-      ``commit_timeout_secs`` so a lost host surfaces as an error on the
-      survivors instead of a silent hang.
-    - **Per-host directories** (``per_host=True``): each process keeps an
-      independent record tree under ``<dir>/p<process>/`` with no
-      cross-host coordination — the layout for host-local disks. The
-      orbax manager runs in a SINGLETON process group (``primary_host``
-      = this process, ``active_processes`` = {this process}) so every
-      host writes, finalizes, and commit-markers its own tree; trees
-      must be host-local (numpy leaves — cross-process jax.Arrays need
-      the shared-directory mode). Restores then MUST go through
-      `restore_latest_valid_consensus`, which makes every host restore
-      the SAME step (or aborts loudly with a per-host validity report).
+    Multi-host semantics — **coordinated commit** over one shared
+    directory: orbax writes every host's shards into the step's tmp dir
+    and process 0 finalizes (rename + commit marker) only after an
+    ALL-HOST barrier through the distributed coordination service — a
+    host dying mid-save can never yield a step that is commit-markered
+    for some hosts and absent for others. The barrier is bounded by
+    ``commit_timeout_secs`` so a lost host surfaces as an error on the
+    survivors instead of a silent hang. Restores on a fleet go through
+    `restore_latest_valid_consensus`, which makes every host restore the
+    SAME step (or aborts loudly with a per-host validity report).
     """
 
     def __init__(self, directory: str, max_to_keep: int = 3, *,
-                 per_host: bool = False, commit_timeout_secs: int = 300):
-        self.per_host = bool(per_host and jax.process_count() > 1)
-        root = _abs(directory)
-        async_options = ocp.options.AsyncOptions(
-            timeout_secs=commit_timeout_secs
-        )
-        if self.per_host:
-            pid = jax.process_index()
-            root = os.path.join(root, f"p{pid}")
-            # Singleton process group: orbax's own barriers and primary-
-            # host gating collapse to this process alone. The write gate
-            # baked into the stock numpy type handler still points at
-            # global process 0, so per-host trees use the ungated
-            # handlers above (and plain zarr, not OCDBT — the per-process
-            # OCDBT merge machinery serves the shared-directory layout).
-            mp_options = ocp.options.MultiprocessingOptions(
-                primary_host=pid,
-                active_processes={pid},
-                barrier_sync_key_prefix=f"perhost{pid}",
-            )
-            registry = _per_host_type_handler_registry()
-            os.makedirs(root, exist_ok=True)  # orbax create=False needs it
-            self.directory = root
-            self._mgr = ocp.CheckpointManager(
-                root,
-                options=ocp.CheckpointManagerOptions(
-                    max_to_keep=max_to_keep,
-                    create=False,
-                    async_options=async_options,
-                    multiprocessing_options=mp_options,
-                ),
-                item_handlers=ocp.PyTreeCheckpointHandler(
-                    use_ocdbt=False,
-                    multiprocessing_options=mp_options,
-                    type_handler_registry=registry,
-                ),
-            )
-            return
-        self.directory = root
+                 commit_timeout_secs: int = 300):
+        self.directory = _abs(directory)
         self._mgr = ocp.CheckpointManager(
             self.directory,
             options=ocp.CheckpointManagerOptions(
                 max_to_keep=max_to_keep,
-                async_options=async_options,
+                async_options=ocp.options.AsyncOptions(
+                    timeout_secs=commit_timeout_secs
+                ),
             ),
         )
-
-    def _save_args(self, tree: Any):
-        # Per-host managers carry an explicit handler (PyTree args);
-        # shared-directory managers use the standard route.
-        if self.per_host:
-            return ocp.args.PyTreeSave(tree)
-        return ocp.args.StandardSave(tree)
 
     def save(self, step: int, state: Any) -> None:
         _flight().record("checkpoint_save", step=step,
                          directory=self.directory)
-        saved = self._mgr.save(step, args=self._save_args(to_savable(state)))
+        saved = self._mgr.save(
+            step, args=ocp.args.StandardSave(to_savable(state))
+        )
         # Chaos hook: a host lost MID-SAVE (SIGKILL with the directory
         # write still in flight on the background thread). The
         # coordinated-commit guarantee under test: the marker is written
@@ -463,14 +356,8 @@ class CheckpointManager:
         step = step if step is not None else self._mgr.latest_step()
         if step is None:
             return None
-        like = to_savable(state_like)
         restored = self._mgr.restore(
-            step,
-            args=(
-                ocp.args.PyTreeRestore(like)
-                if self.per_host
-                else ocp.args.StandardRestore(like)
-            ),
+            step, args=ocp.args.StandardRestore(to_savable(state_like))
         )
         return from_savable(restored, state_like)
 
@@ -495,21 +382,22 @@ class CheckpointManager:
             # between the stored tree and the live state's structure.
             restored = self.restore(state_like, step)
         except Exception as e:
-            # Disambiguate "damaged bytes" from "different layout": a
-            # METADATA read (tree structure only, no array bytes — cheap
-            # even for multi-GB records) succeeding means the record is
-            # intact, just not ours to restore (old format / other
-            # trainer). Quarantining it would destroy a checkpoint a
-            # rollback could still use.
-            ckptr = ocp.StandardCheckpointer()
+            # Disambiguate "damaged bytes" from "different layout" by the
+            # one thing that separates them: can the record be read AS
+            # STORED? A target-free restore reads every array with the
+            # tree the record itself names — a truncated or garbled step
+            # fails it (tensorstore DATA_LOSS), an intact record of a
+            # different layout (old format / other trainer) passes, and
+            # quarantining that one would destroy a checkpoint a rollback
+            # could still use. (orbax's ``metadata()`` cannot tell them
+            # apart: on a damaged step it logs a warning and returns.)
+            # Only the failure path pays this second read.
             try:
-                ckptr.metadata(os.path.join(self.directory, str(step), "default"))
+                self._mgr.restore(step, args=ocp.args.StandardRestore())
             except Exception:
                 raise CheckpointCorruptError(
                     f"step {step}: unreadable ({e})"
                 ) from e
-            finally:
-                ckptr.close()
             raise CheckpointMismatchError(
                 f"step {step}: readable but tree structure does not match "
                 f"the live state ({e})"

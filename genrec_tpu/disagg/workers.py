@@ -53,7 +53,11 @@ from genrec_tpu.disagg.handoff import (
 )
 from genrec_tpu.obs.memory import MemoryLedger, tree_nbytes
 from genrec_tpu.obs.spans import NULL_TRACER
-from genrec_tpu.serving.aot import donate_argnums as _donate, sds_tree as _sds
+from genrec_tpu.serving.aot import (
+    donate_argnums as _donate,
+    paged_decode_donate_argnums,
+    sds_tree as _sds,
+)
 from genrec_tpu.serving.kv_pool import (
     KVPagePool,
     PoolExhausted,
@@ -728,12 +732,10 @@ class DecodeWorker:
             _sds(self.pool.k_pools),
             _sds(self.pool.v_pools),
         )
-        # Donate the slot-state operand (argnum 2 with one trie operand —
-        # the same PAGED_DECODE_DONATE_ARGNUMS discipline the engine
-        # holds; graftlint audits the production entry).
-        compiled = jax.jit(
-            fn, donate_argnums=_donate(1 + len(ops))
-        ).lower(*args).compile()
+        # Donate the slot-state operand — the engine's own rule
+        # (graftlint audits the production entry).
+        donate = _donate(*paged_decode_donate_argnums(len(ops)))
+        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
         self._count_compile()
         return compiled
 

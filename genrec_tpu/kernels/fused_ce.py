@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from genrec_tpu.kernels.policy import resolve_interpret
+
 NEG = -1e30
 
 
@@ -50,46 +52,53 @@ def _tile_logits(x_ref, w_ref, vlim, j, blk_v, V):
     return jnp.where(col < limit, logits, NEG), col
 
 
-def _fwd_kernel(x_ref, w_ref, v_ref, tgt_ref, loss_ref, lse_ref, m_sc, s_sc,
+# Per-row operands and statistics (targets, lse, cotangent, loss, the
+# running max / sumexp / target logit) are (blk_r, 1) COLUMNS end to end:
+# rows stay on sublanes from the logits tile through every reduction
+# (keepdims) to the store, so no value ever changes between a lane-major
+# and a sublane-major layout inside the kernel.
+
+
+def _fwd_kernel(v_ref, x_ref, w_ref, tgt_ref, loss_ref, lse_ref, m_sc, s_sc,
                 t_sc, *, blk_v: int, V: int, ignore_index: int):
     j = pl.program_id(1)
     nj = pl.num_programs(1)
 
     logits, col = _tile_logits(x_ref, w_ref, v_ref[0, 0], j, blk_v, V)
-    tgt = tgt_ref[0, 0]  # (blk_r,)
+    tgt = tgt_ref[...]  # (blk_r, 1)
     # Target logit if it falls inside this vocab tile (sum-select: no
     # dynamic gather on TPU).
-    t_here = jnp.sum(jnp.where(col == tgt[:, None], logits, 0.0), axis=1)
+    t_here = jnp.sum(jnp.where(col == tgt, logits, 0.0), axis=1, keepdims=True)
 
     @pl.when(j == 0)
     def _init():
-        m_sc[0] = jnp.full_like(m_sc[0], NEG)
-        s_sc[0] = jnp.zeros_like(s_sc[0])
-        t_sc[0] = jnp.zeros_like(t_sc[0])
+        m_sc[...] = jnp.full_like(m_sc[...], NEG)
+        s_sc[...] = jnp.zeros_like(s_sc[...])
+        t_sc[...] = jnp.zeros_like(t_sc[...])
 
-    m_old = m_sc[0]
-    m_new = jnp.maximum(m_old, jnp.max(logits, axis=1))
-    s_sc[0] = s_sc[0] * jnp.exp(m_old - m_new) + jnp.sum(
-        jnp.exp(logits - m_new[:, None]), axis=1
+    m_old = m_sc[...]
+    m_new = jnp.maximum(m_old, jnp.max(logits, axis=1, keepdims=True))
+    s_sc[...] = s_sc[...] * jnp.exp(m_old - m_new) + jnp.sum(
+        jnp.exp(logits - m_new), axis=1, keepdims=True
     )
-    m_sc[0] = m_new
-    t_sc[0] = t_sc[0] + t_here
+    m_sc[...] = m_new
+    t_sc[...] = t_sc[...] + t_here
 
     @pl.when(j == nj - 1)
     def _fin():
-        lse = m_sc[0] + jnp.log(s_sc[0])
-        loss = lse - t_sc[0]
-        loss_ref[0, 0] = jnp.where(tgt == ignore_index, 0.0, loss)
-        lse_ref[0, 0] = lse
+        lse = m_sc[...] + jnp.log(s_sc[...])
+        loss = lse - t_sc[...]
+        loss_ref[...] = jnp.where(tgt == ignore_index, 0.0, loss)
+        lse_ref[...] = lse
 
 
-def _dx_kernel(x_ref, w_ref, v_ref, tgt_ref, lse_ref, g_ref, dx_ref,
+def _dx_kernel(v_ref, x_ref, w_ref, tgt_ref, lse_ref, g_ref, dx_ref,
                *, blk_v: int, V: int):
     j = pl.program_id(1)
     logits, col = _tile_logits(x_ref, w_ref, v_ref[0, 0], j, blk_v, V)
-    p = jnp.exp(logits - lse_ref[0, 0][:, None])  # softmax tile
-    onehot = (col == tgt_ref[0, 0][:, None]).astype(jnp.float32)
-    coeff = g_ref[0, 0][:, None] * (p - onehot)  # (blk_r, blk_v)
+    p = jnp.exp(logits - lse_ref[...])  # softmax tile
+    onehot = (col == tgt_ref[...]).astype(jnp.float32)
+    coeff = g_ref[...] * (p - onehot)  # (blk_r, blk_v)
 
     @pl.when(j == 0)
     def _init():
@@ -100,15 +109,15 @@ def _dx_kernel(x_ref, w_ref, v_ref, tgt_ref, lse_ref, g_ref, dx_ref,
     )
 
 
-def _dw_kernel(x_ref, w_ref, v_ref, tgt_ref, lse_ref, g_ref, dw_ref,
+def _dw_kernel(v_ref, x_ref, w_ref, tgt_ref, lse_ref, g_ref, dw_ref,
                *, blk_v: int, V: int):
     # Transposed grid: i = vocab block, inner j = row block.
     i = pl.program_id(0)
     j = pl.program_id(1)
     logits, col = _tile_logits(x_ref, w_ref, v_ref[0, 0], i, blk_v, V)
-    p = jnp.exp(logits - lse_ref[0, 0][:, None])
-    onehot = (col == tgt_ref[0, 0][:, None]).astype(jnp.float32)
-    coeff = g_ref[0, 0][:, None] * (p - onehot)  # (blk_r, blk_v)
+    p = jnp.exp(logits - lse_ref[...])
+    onehot = (col == tgt_ref[...]).astype(jnp.float32)
+    coeff = g_ref[...] * (p - onehot)  # (blk_r, blk_v)
 
     @pl.when(j == 0)
     def _init():
@@ -130,8 +139,16 @@ def _prep(x, w, targets, blk_r, blk_v):
     # Padded rows get target -1: never equal to any column, never ignored
     # into the loss (their loss rows are sliced off anyway).
     tf = jnp.pad(targets.astype(jnp.int32), (0, Rp - R), constant_values=-1)
-    tf = tf.reshape(Rp // blk_r, 1, blk_r)
-    return xf, wf, tf, R, V, Rp, Vp, dp
+    return xf, wf, tf[:, None], R, V, Rp, Vp, dp
+
+
+def _vlim_operand(V, vlim):
+    """The live-vocab limit as the kernels read it: one int32 in SMEM (a
+    scalar read from a VMEM block is not a thing Mosaic does)."""
+    return jnp.full((1, 1), V if vlim is None else vlim, jnp.int32)
+
+
+_VLIM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def fused_linear_ce_fwd(x, w, targets, ignore_index=0, blk_r=128, blk_v=512,
@@ -142,39 +159,36 @@ def fused_linear_ce_fwd(x, w, targets, ignore_index=0, blk_r=128, blk_v=512,
     targets: (R,) int. ``vlim`` (optional traced int32): live-vocab limit —
     cols at/past it are excluded from the softmax (head pad rows under TP).
     Returns (loss (R,) f32, lse (R,) f32)."""
-    interpret = interpret or jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret, "fused_linear_ce[fwd]")
     xf, wf, tf, R, V, Rp, Vp, dp = _prep(x, w, targets, blk_r, blk_v)
     n_rb, n_vb = Rp // blk_r, Vp // blk_v
-    vf = jnp.full((1, 1), V if vlim is None else vlim, jnp.int32)
 
     kernel = functools.partial(
         _fwd_kernel, blk_v=blk_v, V=V, ignore_index=ignore_index
     )
+    col_spec = pl.BlockSpec((blk_r, 1), lambda i, j: (i, 0))
     loss, lse = pl.pallas_call(
         kernel,
         out_shape=[
-            jax.ShapeDtypeStruct((n_rb, 1, blk_r), jnp.float32),
-            jax.ShapeDtypeStruct((n_rb, 1, blk_r), jnp.float32),
+            jax.ShapeDtypeStruct((Rp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((Rp, 1), jnp.float32),
         ],
         grid=(n_rb, n_vb),
         in_specs=[
+            _VLIM_SPEC,
             pl.BlockSpec((blk_r, dp), lambda i, j: (i, 0)),
             pl.BlockSpec((blk_v, dp), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, 1, blk_r), lambda i, j: (i, 0, 0)),
+            col_spec,
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, blk_r), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, 1, blk_r), lambda i, j: (i, 0, 0)),
-        ],
+        out_specs=[col_spec, col_spec],
         scratch_shapes=[
-            pltpu.VMEM((1, blk_r), jnp.float32),
-            pltpu.VMEM((1, blk_r), jnp.float32),
-            pltpu.VMEM((1, blk_r), jnp.float32),
+            pltpu.VMEM((blk_r, 1), jnp.float32),
+            pltpu.VMEM((blk_r, 1), jnp.float32),
+            pltpu.VMEM((blk_r, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(xf, wf, vf, tf)
-    return loss.reshape(Rp)[:R], lse.reshape(Rp)[:R]
+    )(_vlim_operand(V, vlim), xf, wf, tf)
+    return loss[:R, 0], lse[:R, 0]
 
 
 def fused_linear_ce_bwd(x, w, targets, lse, g, ignore_index=0, blk_r=128,
@@ -182,47 +196,44 @@ def fused_linear_ce_bwd(x, w, targets, lse, g, ignore_index=0, blk_r=128,
     """(dx, dw) for the fused CE. g: (R,) cotangent of the per-row losses.
     Ignored rows must carry g=0 (the forward zeroed their losses, so any
     upstream reduction gives them zero cotangent through the where)."""
-    interpret = interpret or jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret, "fused_linear_ce[bwd]")
     xf, wf, tf, R, V, Rp, Vp, dp = _prep(x, w, targets, blk_r, blk_v)
     n_rb, n_vb = Rp // blk_r, Vp // blk_v
-    vf = jnp.full((1, 1), V if vlim is None else vlim, jnp.int32)
+    vf = _vlim_operand(V, vlim)
     # Zero cotangent at ignored AND padded rows.
-    tflat = tf.reshape(Rp)
-    gf = jnp.pad(g.astype(jnp.float32), (0, Rp - R))
-    gf = jnp.where(tflat == ignore_index, 0.0, gf).reshape(n_rb, 1, blk_r)
-    lsef = jnp.pad(lse.astype(jnp.float32), (0, Rp - R)).reshape(n_rb, 1, blk_r)
+    gf = jnp.pad(g.astype(jnp.float32), (0, Rp - R))[:, None]
+    gf = jnp.where(tf == ignore_index, 0.0, gf)
+    lsef = jnp.pad(lse.astype(jnp.float32), (0, Rp - R))[:, None]
 
+    def specs(row, voc):
+        """Operand specs for a grid whose (i, j) program ids pick the row
+        block through ``row`` and the vocab block through ``voc``."""
+        col_spec = pl.BlockSpec((blk_r, 1), lambda i, j: (row(i, j), 0))
+        return [
+            _VLIM_SPEC,
+            pl.BlockSpec((blk_r, dp), lambda i, j: (row(i, j), 0)),
+            pl.BlockSpec((blk_v, dp), lambda i, j: (voc(i, j), 0)),
+            col_spec, col_spec, col_spec,
+        ]
+
+    first, second = (lambda i, j: i), (lambda i, j: j)
     dx = pl.pallas_call(
         functools.partial(_dx_kernel, blk_v=blk_v, V=V),
         out_shape=jax.ShapeDtypeStruct((Rp, dp), jnp.float32),
         grid=(n_rb, n_vb),
-        in_specs=[
-            pl.BlockSpec((blk_r, dp), lambda i, j: (i, 0)),
-            pl.BlockSpec((blk_v, dp), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, 1, blk_r), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, 1, blk_r), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, 1, blk_r), lambda i, j: (i, 0, 0)),
-        ],
+        in_specs=specs(row=first, voc=second),
         out_specs=pl.BlockSpec((blk_r, dp), lambda i, j: (i, 0)),
         interpret=interpret,
-    )(xf, wf, vf, tf, lsef, gf)
+    )(vf, xf, wf, tf, lsef, gf)
 
     dw = pl.pallas_call(
         functools.partial(_dw_kernel, blk_v=blk_v, V=V),
         out_shape=jax.ShapeDtypeStruct((Vp, dp), jnp.float32),
         grid=(n_vb, n_rb),
-        in_specs=[
-            pl.BlockSpec((blk_r, dp), lambda i, j: (j, 0)),
-            pl.BlockSpec((blk_v, dp), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, 1, blk_r), lambda i, j: (j, 0, 0)),
-            pl.BlockSpec((1, 1, blk_r), lambda i, j: (j, 0, 0)),
-            pl.BlockSpec((1, 1, blk_r), lambda i, j: (j, 0, 0)),
-        ],
+        in_specs=specs(row=second, voc=first),
         out_specs=pl.BlockSpec((blk_v, dp), lambda i, j: (i, 0)),
         interpret=interpret,
-    )(xf, wf, vf, tf, lsef, gf)
+    )(vf, xf, wf, tf, lsef, gf)
 
     return dx[:R, : x.shape[1]].astype(x.dtype), dw[:V, : w.shape[1]].astype(w.dtype)
 

@@ -35,6 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from genrec_tpu.kernels.policy import resolve_interpret
+
 NEG = -1e9
 
 
@@ -57,18 +59,30 @@ def _time_bucket_f(diff, num_buckets):
     return jnp.clip(b, 0, num_buckets - 1)
 
 
-def _kernel(
-    q_ref, k_ref, v_ref, ts_ref, tsq_ref, mask_ref, seg_ref, segq_ref,
-    ptab_ref, ttab_ref, out_ref,
-    *, blk_q: int, num_pos_buckets: int, num_time_buckets: int,
+def _table_bias(buckets, tab_ref, h, num_buckets: int):
+    """sum_b where(buckets == b, table[h, b]): the (tiny) bucket table
+    sits whole in SMEM and is read one scalar at a time — TPU-friendly,
+    no dynamic gather."""
+    bias = jnp.zeros(buckets.shape, jnp.float32)
+    for b in range(num_buckets):
+        bias = bias + jnp.where(buckets == b, tab_ref[h, b], 0.0)
+    return bias
+
+
+def _masked_scores(
+    q, k, ts_ref, tsq_ref, mask_ref, seg_ref, segq_ref, ptab_ref, ttab_ref,
+    *, n_heads: int, blk_q: int, num_pos_buckets: int, num_time_buckets: int,
     max_position_distance: int, use_time: bool, use_seg: bool,
 ):
+    """(scores with -1e9 at masked pairs, mask, pos buckets, time buckets)
+    for this (batch*head, q-block) tile. The forward and the backward
+    kernel recompute identical scores only because both run THIS body."""
+    h = pl.program_id(0) % n_heads
     j = pl.program_id(1)
-    L = k_ref.shape[1]
-
-    q = q_ref[0]  # (blk_q, hd)
-    k = k_ref[0]  # (L, hd)
-    scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (blk_q, L)
+    L = k.shape[0]
+    scores = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )  # (blk_q, L)
 
     q_pos = j * blk_q + jax.lax.broadcasted_iota(jnp.int32, (blk_q, L), 0)
     k_pos = jax.lax.broadcasted_iota(jnp.int32, (blk_q, L), 1)
@@ -76,36 +90,45 @@ def _kernel(
     # Replicated reference quirk: rel = key - query, clamped >= 0 in the
     # bucket fn (see models/hstu.py RelativePositionBias).
     pbucket = _pos_bucket_f(k_pos - q_pos, num_pos_buckets, max_position_distance)
-    pbias = jnp.zeros_like(scores)
-    for b in range(num_pos_buckets):
-        pbias = pbias + jnp.where(pbucket == b, ptab_ref[0, 0, b], 0.0)
-    scores = scores + pbias
+    scores = scores + _table_bias(pbucket, ptab_ref, h, num_pos_buckets)
 
+    tbucket = None
     if use_time:
-        ts = ts_ref[0]  # (1, L) int32
-        # The q-tile timestamps arrive as their own blocked operand —
-        # dynamic_slice on a ref is not lowerable in Mosaic TC kernels.
-        t_q = tsq_ref[0]  # (1, blk_q)
-        tdiff = t_q.T - ts[0][None, :]  # (blk_q, L)
+        # The q-tile timestamps (and segment ids below) arrive as their
+        # own blocked (1, blk_q) operand — dynamic_slice on a ref is not
+        # lowerable in Mosaic TC kernels — and become a column by a 2-D
+        # transpose, never through a 1-D value.
+        tdiff = tsq_ref[0].T - ts_ref[0]  # (blk_q, 1) - (1, L)
         tbucket = _time_bucket_f(tdiff, num_time_buckets)
-        tbias = jnp.zeros_like(scores)
-        for b in range(num_time_buckets):
-            tbias = tbias + jnp.where(tbucket == b, ttab_ref[0, 0, b], 0.0)
-        scores = scores + tbias
+        scores = scores + _table_bias(tbucket, ttab_ref, h, num_time_buckets)
 
-    causal_or_pad = jnp.logical_or(k_pos > q_pos, mask_ref[0, 0][None, :] != 0)
+    masked = jnp.logical_or(k_pos > q_pos, mask_ref[0] != 0)
     if use_seg:
         # Packed rows: a query must not see keys from another segment
         # (same in-register fold as the causal/padding mask — packing does
         # not force the unfused fallback).
-        seg_k = seg_ref[0, 0][None, :]  # (1, L)
-        seg_q = segq_ref[0, 0][:, None]  # (blk_q, 1)
-        causal_or_pad = jnp.logical_or(causal_or_pad, seg_q != seg_k)
-    scores = jnp.where(causal_or_pad, NEG, scores)
+        masked = jnp.logical_or(masked, segq_ref[0].T != seg_ref[0])
+    return jnp.where(masked, NEG, scores), masked, pbucket, tbucket
+
+
+def _kernel(
+    q_ref, k_ref, v_ref, ts_ref, tsq_ref, mask_ref, seg_ref, segq_ref,
+    ptab_ref, ttab_ref, out_ref, **cfg,
+):
+    scores, _, _, _ = _masked_scores(
+        q_ref[0], k_ref[0], ts_ref, tsq_ref, mask_ref, seg_ref, segq_ref,
+        ptab_ref, ttab_ref, **cfg,
+    )
     attn = scores * jax.nn.sigmoid(scores)  # silu
     out_ref[0] = jnp.dot(
         attn.astype(v_ref.dtype), v_ref[0], preferred_element_type=jnp.float32
     ).astype(out_ref.dtype)
+
+
+# The (H, buckets) bias tables are read one scalar at a time: whole, in
+# SMEM, indexed [head, bucket].
+_TABLE_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
+_BWD_VMEM_BYTES = 64 * 1024 * 1024
 
 
 def _round_up(x, m):
@@ -166,9 +189,7 @@ def hstu_attention_pallas(
     B, H, L, hd = q.shape
     use_time = timestamps is not None and time_table is not None
     use_seg = segment_ids is not None
-    # Mosaic compiles only on TPU; elsewhere fall back to the interpreter
-    # so use_pallas=True models stay runnable (slowly) in CI.
-    interpret = interpret or jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret, "hstu_attention[fwd]")
     qf, kf, vf, maskf, tsf, segf, time_table, Lp, hp = _pad_inputs(
         q, k, v, timestamps, padding_mask, time_table, blk_q, segment_ids
     )
@@ -177,6 +198,7 @@ def hstu_attention_pallas(
 
     kernel = functools.partial(
         _kernel,
+        n_heads=H,
         blk_q=blk_q,
         num_pos_buckets=pos_table.shape[1],
         num_time_buckets=time_table.shape[1],
@@ -202,61 +224,53 @@ def hstu_attention_pallas(
             pl.BlockSpec((1, 1, Lp), lambda i, j: (i // H, 0, 0)),  # padding mask
             pl.BlockSpec((1, 1, Lp), lambda i, j: (i // H, 0, 0)),  # segments (keys)
             pl.BlockSpec((1, 1, blk_q), lambda i, j: (i // H, 0, j)),  # seg q-tile
-            pl.BlockSpec((1, 1, pos_table.shape[1]), lambda i, j: (i % H, 0, 0)),
-            pl.BlockSpec((1, 1, time_table.shape[1]), lambda i, j: (i % H, 0, 0)),
+            _TABLE_SPEC,
+            _TABLE_SPEC,
         ],
         out_specs=pl.BlockSpec((1, blk_q, hp), lambda i, j: (i, j, 0)),
         interpret=interpret,
     )(qf, kf, vf, tsf[:, None], tsf[:, None], maskf[:, None],
-      segf[:, None], segf[:, None], pos_table[:, None], time_table[:, None])
+      segf[:, None], segf[:, None], pos_table.astype(jnp.float32),
+      time_table.astype(jnp.float32))
     return out.reshape(B, H, Lp, hp)[:, :, :L, :hd]
+
+
+def _table_grad(buckets, ds, num_buckets: int):
+    """(1, num_buckets) row of sum(ds where buckets == b). Each bucket's
+    scalar lands in its lane through an iota select: a vector is never
+    assembled from scalars."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, num_buckets), 1)
+    row = jnp.zeros((1, num_buckets), jnp.float32)
+    for b in range(num_buckets):
+        row = row + jnp.where(
+            lane == b, jnp.sum(jnp.where(buckets == b, ds, 0.0)), 0.0
+        )
+    return row
 
 
 def _bwd_kernel(
     q_ref, k_ref, v_ref, do_ref, ts_ref, tsq_ref, mask_ref, seg_ref, segq_ref,
     ptab_ref, ttab_ref,
-    dq_ref, dk_ref, dv_ref, dpt_ref, dtt_ref,
-    *, blk_q: int, num_pos_buckets: int, num_time_buckets: int,
-    max_position_distance: int, use_time: bool, use_seg: bool,
+    dq_ref, dk_ref, dv_ref, dpt_ref, dtt_ref, **cfg,
 ):
     j = pl.program_id(1)
-    L = k_ref.shape[1]
-
     q = q_ref[0].astype(jnp.float32)  # (blk_q, hd)
     k = k_ref[0].astype(jnp.float32)  # (L, hd)
     v = v_ref[0].astype(jnp.float32)  # (L, hd)
     do = do_ref[0].astype(jnp.float32)  # (blk_q, hd)
 
     # --- Recompute the masked scores exactly as the forward kernel does.
-    scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (blk_q, L)
-    q_pos = j * blk_q + jax.lax.broadcasted_iota(jnp.int32, (blk_q, L), 0)
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, (blk_q, L), 1)
-    pbucket = _pos_bucket_f(k_pos - q_pos, num_pos_buckets, max_position_distance)
-    pbias = jnp.zeros_like(scores)
-    for b in range(num_pos_buckets):
-        pbias = pbias + jnp.where(pbucket == b, ptab_ref[0, 0, b], 0.0)
-    scores = scores + pbias
-    if use_time:
-        ts = ts_ref[0]
-        t_q = tsq_ref[0]
-        tdiff = t_q.T - ts[0][None, :]
-        tbucket = _time_bucket_f(tdiff, num_time_buckets)
-        tbias = jnp.zeros_like(scores)
-        for b in range(num_time_buckets):
-            tbias = tbias + jnp.where(tbucket == b, ttab_ref[0, 0, b], 0.0)
-        scores = scores + tbias
-
-    masked = jnp.logical_or(k_pos > q_pos, mask_ref[0, 0][None, :] != 0)
-    if use_seg:
-        masked = jnp.logical_or(
-            masked, segq_ref[0, 0][:, None] != seg_ref[0, 0][None, :]
-        )
-    s = jnp.where(masked, NEG, scores)
+    s, masked, pbucket, tbucket = _masked_scores(
+        q, k, ts_ref, tsq_ref, mask_ref, seg_ref, segq_ref, ptab_ref,
+        ttab_ref, **cfg,
+    )
 
     # --- Local grads. silu(s) = s*sig(s); silu'(s) = sig(s)*(1 + s*(1-sig(s))).
     sig = jax.nn.sigmoid(s)
     attn = s * sig  # (blk_q, L)
-    d_attn = jnp.dot(do, v.T, preferred_element_type=jnp.float32)  # (blk_q, L)
+    d_attn = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )  # (blk_q, L)
     # Gradient at the PRE-mask scores: masked entries get exactly zero
     # (the where() in the forward routes no gradient to them).
     ds = jnp.where(masked, 0.0, d_attn * sig * (1.0 + s * (1.0 - sig)))
@@ -269,26 +283,19 @@ def _bwd_kernel(
     def _init():
         dk_ref[0] = jnp.zeros_like(dk_ref[0])
         dv_ref[0] = jnp.zeros_like(dv_ref[0])
-
-    dk_ref[0] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
-    dv_ref[0] += jnp.dot(attn.T, do, preferred_element_type=jnp.float32)
-
-    # --- Bias-table partials for this tile (summed over tiles in XLA).
-    @pl.when(j == 0)
-    def _init_tabs():
         dpt_ref[0] = jnp.zeros_like(dpt_ref[0])
         # Zero even when use_time is False (1-wide dummy table): the output
         # buffer is otherwise uninitialized memory for any future consumer.
         dtt_ref[0] = jnp.zeros_like(dtt_ref[0])
 
-    dpt = [jnp.sum(jnp.where(pbucket == b, ds, 0.0)) for b in range(num_pos_buckets)]
-    dpt_ref[0] += jnp.stack(dpt)[None, :]
-    if use_time:
-        dtt = [
-            jnp.sum(jnp.where(tbucket == b, ds, 0.0))
-            for b in range(num_time_buckets)
-        ]
-        dtt_ref[0] += jnp.stack(dtt)[None, :]
+    tn = (((0,), (0,)), ((), ()))  # a.T @ b without materializing a.T
+    dk_ref[0] += jax.lax.dot_general(ds, q, tn, preferred_element_type=jnp.float32)
+    dv_ref[0] += jax.lax.dot_general(attn, do, tn, preferred_element_type=jnp.float32)
+
+    # --- Bias-table partials for this tile (summed over tiles in XLA).
+    dpt_ref[0] += _table_grad(pbucket, ds, cfg["num_pos_buckets"])
+    if cfg["use_time"]:
+        dtt_ref[0] += _table_grad(tbucket, ds, cfg["num_time_buckets"])
 
 
 def hstu_attention_bwd_pallas(
@@ -301,7 +308,7 @@ def hstu_attention_bwd_pallas(
     B, H, L, hd = q.shape
     use_time = timestamps is not None and time_table is not None
     use_seg = segment_ids is not None
-    interpret = interpret or jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret, "hstu_attention[bwd]")
     qf, kf, vf, maskf, tsf, segf, ttab, Lp, hp = _pad_inputs(
         q, k, v, timestamps, padding_mask, time_table, blk_q, segment_ids
     )
@@ -312,6 +319,7 @@ def hstu_attention_bwd_pallas(
 
     kernel = functools.partial(
         _bwd_kernel,
+        n_heads=H,
         blk_q=blk_q,
         num_pos_buckets=nb,
         num_time_buckets=ntb,
@@ -339,8 +347,8 @@ def hstu_attention_bwd_pallas(
             pl.BlockSpec((1, 1, Lp), lambda i, j: (i // H, 0, 0)),  # padding mask
             pl.BlockSpec((1, 1, Lp), lambda i, j: (i // H, 0, 0)),  # segments (keys)
             pl.BlockSpec((1, 1, blk_q), lambda i, j: (i // H, 0, j)),  # seg q-tile
-            pl.BlockSpec((1, 1, nb), lambda i, j: (i % H, 0, 0)),
-            pl.BlockSpec((1, 1, ntb), lambda i, j: (i % H, 0, 0)),
+            _TABLE_SPEC,
+            _TABLE_SPEC,
         ],
         out_specs=[
             pl.BlockSpec((1, blk_q, hp), lambda i, j: (i, j, 0)),  # dq per tile
@@ -349,9 +357,14 @@ def hstu_attention_bwd_pallas(
             pl.BlockSpec((1, 1, nb), lambda i, j: (i, 0, 0)),  # dpos accumulated
             pl.BlockSpec((1, 1, ntb), lambda i, j: (i, 0, 0)),  # dtime accumulated
         ],
+        # Whole-sequence K/V blocks plus a handful of live (blk_q, L) fp32
+        # score-sized temporaries outgrow the default scoped-VMEM window
+        # at long L (preflight runs L=2048).
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_BWD_VMEM_BYTES),
         interpret=interpret,
     )(qf, kf, vf, gf, tsf[:, None], tsf[:, None], maskf[:, None],
-      segf[:, None], segf[:, None], pos_table[:, None], ttab[:, None])
+      segf[:, None], segf[:, None], pos_table.astype(jnp.float32),
+      ttab.astype(jnp.float32))
 
     dq = dq.reshape(B, H, Lp, hp)[:, :, :L, :hd].astype(q.dtype)
     dk = dk.reshape(B, H, Lp, hp)[:, :, :L, :hd].astype(k.dtype)

@@ -15,7 +15,7 @@ gather. Applies to the raw-codebook configuration (no sim_vq projection /
 normalization — the shipped RQ-VAE configs); the general path falls back
 to the Flax model.
 
-MEASURED VERDICT (v5e, results/tpu/bench.json kernel_preflight): at
+MEASURED VERDICT (v5e, round-2 kernel preflight): at
 rqvae scale (B=2048, D=32, L=3, K=256) the op is too small for a custom
 kernel to pay off — XLA 0.17 ms vs Pallas 1.50 ms; per-tile grid
 overhead dominates an op whose whole working set is ~0.3 MB. The kernel
@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from genrec_tpu.kernels.policy import resolve_interpret
 
 
 def _kernel(x_ref, cb_ref, ids_ref, qsum_ref, *, n_layers: int, K: int):
@@ -81,7 +83,7 @@ def rq_cascade_pallas(
     """
     B, D = x.shape
     L, K, _ = codebooks.shape
-    interpret = interpret or jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret, "rq_cascade")
     Bp = _round_up(B, blk_b)
     Dp = _round_up(D, 128)
     Kp = _round_up(K, 128)

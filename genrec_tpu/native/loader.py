@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -13,20 +14,29 @@ logger = logging.getLogger(__name__)
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "amazon_parser.cpp")
-_LIB = os.path.join(_DIR, "libamazon_parser.so")
 _lib = None
 
 
-def _build() -> bool:
+def _lib_path() -> str:
+    """The library built from THIS source: named by the source's content
+    hash, so an existing file is trusted for what it was built from, never
+    for its mtime (a copied tree does not preserve those) — a stale or
+    foreign binary simply has another name and is not looked at."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"libamazon_parser.{digest}.so")
+
+
+def _build(lib_path: str) -> bool:
     # Build to a per-pid temp name and atomically rename: concurrent
     # processes never observe a half-written .so.
-    tmp = f"{_LIB}.{os.getpid()}.tmp"
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, _SRC, "-lz"],
             check=True, capture_output=True, timeout=120,
         )
-        os.replace(tmp, _LIB)
+        os.replace(tmp, lib_path)
         return True
     except Exception as e:  # toolchain absent or build failure
         logger.info("native parser build unavailable (%s); using Python path", e)
@@ -41,12 +51,12 @@ def _load():
     global _lib
     if _lib is not None:
         return _lib
-    if not os.path.exists(_LIB) or os.path.getmtime(_LIB) < os.path.getmtime(_SRC):
-        if not _build():
-            _lib = False
-            return _lib
+    lib_path = _lib_path()
+    if not os.path.exists(lib_path) and not _build(lib_path):
+        _lib = False
+        return _lib
     try:
-        lib = ctypes.CDLL(_LIB)
+        lib = ctypes.CDLL(lib_path)
         lib.parse_reviews.argtypes = [ctypes.c_char_p, ctypes.c_char_p]
         lib.parse_reviews.restype = ctypes.c_int64
         _lib = lib

@@ -7,9 +7,9 @@ slot names its pages through a block-table row, so HBM scales with the
 tokens actually resident, not with ``max_slots x max_history``.
 
 This module is the gather/segment fallback (and the numerics contract)
-for the Pallas kernel in ``kernels/paged_attention.py``: CPU tests and
-non-TPU backends run these exact ops, and the kernel is pinned against
-them the same way the HSTU kernel is pinned against its XLA reference.
+for the Pallas kernel in ``kernels/paged_attention.py``: non-TPU
+backends run these exact ops, and the kernel is pinned against them the
+same way the HSTU kernel is pinned against its XLA reference.
 
 Conventions shared by fallback and kernel:
 
@@ -116,8 +116,8 @@ def paged_attention_stats(
     docstring for why that matches the dense additive mask exactly).
 
     use_kernel: None resolves through kernels.policy.auto_paged_attention
-    (TPU-only); True forces the Pallas kernel (interpret mode off-TPU);
-    False forces this pure-JAX gather. ``QuantizedKVPool`` pools route
+    (TPU-only); True forces the Pallas kernel (off-TPU that needs
+    `kernels.policy.interpret_mode`); False forces this pure-JAX gather. ``QuantizedKVPool`` pools route
     to the dequant-in-kernel twin (or the dequant-after-gather fallback)
     with identical (acc, m, l) semantics.
     """

@@ -161,11 +161,6 @@ def item_topk(h, item_emb, k: int, *, mesh: Mesh | None = None,
     n = mesh.shape[model_axis]
     if n <= 1 or V % n != 0 or V // n < k:
         return plain(h, item_emb)
-    try:  # jax >= 0.5 exports shard_map at top level
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
     # in_specs must mirror the arg pytrees: a QuantizedTable operand is
     # a 2-leaf pytree — data rows and their scales shard dim 0 together
     # (built via type(item_emb) so the class arrives with the operand).
@@ -175,7 +170,7 @@ def item_topk(h, item_emb, k: int, *, mesh: Mesh | None = None,
     )
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(), emb_spec),
         out_specs=(P(None, model_axis), P(None, model_axis)),
     )

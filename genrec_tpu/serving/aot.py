@@ -1,13 +1,15 @@
 """AOT-lowering helpers shared by the serving engine and the disagg
 workers/transports.
 
-Both rules are load-bearing compile discipline, so they live in exactly
+These rules are load-bearing compile discipline, so they live in exactly
 one place:
 
 - ``sds_tree``: pytree -> ShapeDtypeStructs, lowering without live
   buffers;
 - ``donate_argnums``: the backend donation policy — CPU has no buffer
-  donation, and donating there only emits a per-call warning.
+  donation, and donating there only emits a per-call warning;
+- ``paged_decode_donate_argnums``: which operand of the paged decode
+  step is dead after the call.
 """
 
 from __future__ import annotations
@@ -18,6 +20,19 @@ def donate_argnums(*argnums):
     import jax
 
     return argnums if jax.default_backend() != "cpu" else ()
+
+
+def paged_decode_donate_argnums(n_operands: int) -> tuple:
+    """Argnums the paged decode step donates: the slot state, which is
+    dead after every call (step() overwrites it from the executable's
+    output). The paged signature is (params, *catalog operands, state,
+    ...), so the state's index follows the head's operand count — a fixed
+    index would donate params or a trie for a head with none or two. The
+    operands (catalog.TensorTrie) are threaded, NOT donated: they survive
+    every step and are swapped only by set_catalog. Shared with the
+    graftlint manifest entry in serving/heads.py so the donation audit
+    audits the SAME argnums production compiles."""
+    return (1 + n_operands,)
 
 
 def sds_tree(tree):

@@ -136,18 +136,8 @@ from genrec_tpu.serving.types import (
 
 
 from genrec_tpu.serving.aot import donate_argnums as _donate_argnums
+from genrec_tpu.serving.aot import paged_decode_donate_argnums
 from genrec_tpu.serving.aot import sds_tree as _sds
-
-
-#: The slot-state operand of the paged decode step is dead after every
-#: call (step() overwrites it from the executable's output) and is
-#: donated. The paged signature is (params, trie-operand, state, ...) —
-#: the trie (catalog.TensorTrie) is threaded, NOT donated: it survives
-#: every step and is swapped only by set_catalog. Shared with the
-#: graftlint manifest entry in serving/heads.py so the donation audit
-#: audits the SAME argnums production compiles — changing this constant
-#: changes both.
-PAGED_DECODE_DONATE_ARGNUMS = (2,)
 
 
 def _operand_avals(operands) -> tuple:
@@ -346,9 +336,8 @@ class _PagedRunner:
         # overwrites every row, so the input tree is dead after the call —
         # undonated, XLA would double-buffer the whole slot ladder's
         # decode state (graftlint missing_donation; docs/PERF.md note).
-        compiled = jax.jit(
-            fn, donate_argnums=self._donate(*PAGED_DECODE_DONATE_ARGNUMS)
-        ).lower(*args).compile()
+        donate = self._donate(*paged_decode_donate_argnums(len(ops)))
+        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
         eng.metrics.record_compile(catalog=catalog_compile)
         return compiled
 
@@ -371,9 +360,8 @@ class _PagedRunner:
             _sds(self.pool.k_pools),
             _sds(self.pool.v_pools),
         )
-        compiled = jax.jit(
-            fn, donate_argnums=self._donate(*PAGED_DECODE_DONATE_ARGNUMS)
-        ).lower(*args).compile()
+        donate = self._donate(*paged_decode_donate_argnums(len(ops)))
+        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
         eng.metrics.record_compile(catalog=catalog_compile)
         return compiled
 
@@ -488,21 +476,55 @@ class _PagedRunner:
                     eng._log.exception(
                         f"serving: paged prefill on head {self.head.name} failed"
                     )
-                    for slot, (_req, fut, _t, _tr) in zip(
-                        slots, (e for e, _k in admitted)
-                    ):
+                    for slot in slots:
                         self.pool.evict(slot)
                         # Undo any slot bookkeeping a partial prefill set,
                         # or step() would decode an entry-less slot.
                         self.active[slot] = False
                         self.entries[slot] = None
                         self.buckets[slot] = None
+                    if self.pool.device_pools_consumed():
+                        self._recover_lost_pools(e)
+                    # Futures last: a caller that sees the failure sees an
+                    # engine already fit to take its retry.
+                    for (_req, fut, _t, _tr), _k in admitted:
                         if not fut.done():
                             fut.set_exception(e)
                     eng.metrics.record_failure(len(admitted))
                 progressed = True
             if leftover:
                 return progressed
+
+    def _recover_lost_pools(self, cause: BaseException) -> None:
+        """The prefill donates the page pools, so one that fails AFTER
+        launch has consumed them: `pool.k_pools` / `v_pools` name deleted
+        buffers, and with them went the K/V of every resident slot and
+        every retained prefix run. Left alone, every later prefill and
+        decode call raises "Array has been deleted" while the engine
+        keeps admitting. Fail the resident requests with the cause, drop
+        the prefix cache, and carry on from fresh zero pools."""
+        eng = self.engine
+        lost = [int(s) for s in np.nonzero(self.active)[0]]
+        for slot in lost:
+            fut = self.entries[slot][1]
+            self.pool.evict(slot)
+            self.active[slot] = False
+            self.entries[slot] = None
+            self.buckets[slot] = None
+            if not fut.done():
+                fut.set_exception(cause)
+        eng.metrics.record_failure(len(lost))
+        eng.metrics.record_evict(len(lost))
+        self.clear_prefix_cache("kv_pools_lost")
+        self.pool.reset_device_pools()
+        eng.metrics.set_pool_gauges(self.head.name, self.pool.stats())
+        eng._flight.record("kv_pools_lost", head=self.head.name,
+                           slots_failed=len(lost))
+        eng._log.error(
+            f"serving: failed prefill on head {self.head.name} consumed the "
+            f"donated KV page pools; failed {len(lost)} resident request(s) "
+            "and reset the pools"
+        )
 
     # -- cross-request prefix cache ------------------------------------------
 

@@ -1311,7 +1311,7 @@ def _graftlint_paged_decode_entry() -> BuiltEntry:
     runtime operand at argnum 1 (catalog.TensorTrie) — NOT donated, it
     survives across every step — and the 256 B constant threshold now
     asserts the old baked-table debt stays retired."""
-    from genrec_tpu.serving.engine import PAGED_DECODE_DONATE_ARGNUMS
+    from genrec_tpu.serving.aot import paged_decode_donate_argnums
     from genrec_tpu.serving.kv_pool import KVPagePool, PagedConfig
 
     head, params, _B, _L = _tiny_tiger_head()
@@ -1320,10 +1320,11 @@ def _graftlint_paged_decode_entry() -> BuiltEntry:
     S = cfg.max_slots
     state = {k: jnp.asarray(v) for k, v in head.paged_state_zeros(S).items()}
     # Same donate argnums production compiles (engine shares the
-    # constant); donation is requested unconditionally here because the
+    # function); donation is requested unconditionally here because the
     # audit reads the declaration, which CPU lowering preserves.
     fn = jax.jit(head.make_decode_paged_fn(),
-                 donate_argnums=PAGED_DECODE_DONATE_ARGNUMS)
+                 donate_argnums=paged_decode_donate_argnums(
+                     len(head.runtime_operands())))
     args = (
         params, *head.runtime_operands(), state,
         jnp.zeros((S,), jnp.int32),
@@ -1331,10 +1332,10 @@ def _graftlint_paged_decode_entry() -> BuiltEntry:
         jnp.zeros((S,), jnp.int32),
         pool.k_pools, pool.v_pools,
     )
-    # expect_donated stays a LITERAL, independent of the shared constant:
+    # expect_donated stays a LITERAL, independent of the shared function:
     # it states which buffers are dead (a fact about step()'s write-back:
-    # params 0, trie 1, slot state 2), so emptying
-    # PAGED_DECODE_DONATE_ARGNUMS fails the audit instead of both sides
+    # params 0, trie 1, slot state 2), so a paged_decode_donate_argnums
+    # that stops naming the state fails the audit instead of both sides
     # silently agreeing on "no donation".
     return BuiltEntry(fn=fn, args=args, expect_donated=(2,),
                       max_const_bytes=256)

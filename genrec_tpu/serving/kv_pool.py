@@ -225,20 +225,12 @@ class KVPagePool:
         self.cfg = cfg
         self.n_layers = n_layers
         self._bank = bank
+        self._placement = None  # sharding_of, once place() has run
         if bank is None:
-            shape = (cfg.num_pages, cfg.page_size, n_heads, head_dim)
-            if cfg.kv_dtype == "int8":
-                from genrec_tpu.ops.quant import QuantizedKVPool
-
-                self._k_pools = tuple(
-                    QuantizedKVPool.zeros(shape) for _ in range(n_layers)
-                )
-                self._v_pools = tuple(
-                    QuantizedKVPool.zeros(shape) for _ in range(n_layers)
-                )
-            else:
-                self._k_pools = tuple(jnp.zeros(shape, dtype) for _ in range(n_layers))
-                self._v_pools = tuple(jnp.zeros(shape, dtype) for _ in range(n_layers))
+            self._shape = (cfg.num_pages, cfg.page_size, n_heads, head_dim)
+            self._dtype = dtype
+            self._k_pools = self._zero_pools()
+            self._v_pools = self._zero_pools()
             self.allocator = PageAllocator(cfg.num_pages)
         else:
             if (cfg.num_pages, cfg.page_size, cfg.kv_dtype) != (
@@ -261,6 +253,41 @@ class KVPagePool:
         # shape covering max(active index) (the collapsed decode ladder).
         self._free_slots = list(range(cfg.max_slots))
         heapq.heapify(self._free_slots)
+
+    def _zero_pools(self) -> tuple:
+        if self.cfg.kv_dtype == "int8":
+            from genrec_tpu.ops.quant import QuantizedKVPool
+
+            return tuple(
+                QuantizedKVPool.zeros(self._shape) for _ in range(self.n_layers)
+            )
+        return tuple(
+            jnp.zeros(self._shape, self._dtype) for _ in range(self.n_layers)
+        )
+
+    def device_pools_consumed(self) -> bool:
+        """True when a device page array has been deleted under the pool
+        — what a DONATING prefill leaves behind when it fails after
+        launch. On backends without donation this never happens."""
+        import jax
+
+        return any(
+            leaf.is_deleted()
+            for leaf in jax.tree_util.tree_leaves((self.k_pools, self.v_pools))
+        )
+
+    def reset_device_pools(self) -> None:
+        """Replace the device page arrays with fresh zeros at the same
+        placement. Page CONTENTS are gone; slot and allocator bookkeeping
+        is untouched, so the caller evicts whatever read those pages
+        first (`_PagedRunner._recover_lost_pools`)."""
+        if self._bank is not None:
+            self._bank.reset_device_pools()
+            return
+        self._k_pools = self._zero_pools()
+        self._v_pools = self._zero_pools()
+        if self._placement is not None:
+            self.place(self._placement)
 
     # Device pools live on the BANK when this pool is a slot view: a
     # prefill executable donates + replaces the bank's arrays, and every
@@ -299,6 +326,7 @@ class KVPagePool:
             return
         import jax
 
+        self._placement = sharding_of
         put = lambda x: jax.device_put(x, sharding_of(x))  # noqa: E731
         self._k_pools = jax.tree_util.tree_map(put, self._k_pools)
         self._v_pools = jax.tree_util.tree_map(put, self._v_pools)

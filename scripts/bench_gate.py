@@ -218,10 +218,12 @@ def newest_committed_run() -> Optional[str]:
 
 
 def metric_backend(run: Mapping, name: str) -> Optional[str]:
-    """The backend a specific metric was MEASURED on. bench.py grafts
-    same-backend CPU supplements onto TPU-evidence lines (serve.source
-    / packed_source stamp the provenance); the gate must compare each
-    metric against its own backend, not the line's headline one."""
+    """The backend a specific metric was MEASURED on. Run files from
+    before PR 21 can carry CPU supplements grafted onto TPU-evidence
+    lines (serve.source / packed_source stamp the provenance); the gate
+    must compare each such metric against its own backend, not the
+    line's headline one. bench.py no longer writes either label: a line
+    it prints was measured whole on the chip."""
     backend = run.get("backend") or (run.get("meta") or {}).get("backend")
     if name.startswith("serve/"):
         src = (run.get("serve") or {}).get("source")

@@ -47,11 +47,11 @@ def main():
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
 
-    # Same architecture/shapes as bench.py's CPU fallback — imported, not
-    # copied, so they cannot drift.
+    # Same architecture as bench.py — imported, not copied, so it cannot
+    # drift. The batch is this script's own: one CPU core finishes 32.
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, repo_root)
-    from bench import BENCH_ITEMS, CPU_BATCH, TIGER_BENCH_ARCH, host_fingerprint
+    from bench import BENCH_ITEMS, TIGER_BENCH_ARCH, host_fingerprint
 
     _stub_gin()
     sys.path.insert(0, args.reference)
@@ -62,7 +62,7 @@ def main():
 
     torch.set_num_threads(args.threads)
     torch.manual_seed(0)
-    B = args.batch_size or CPU_BATCH
+    B = args.batch_size or 32
     items, D = BENCH_ITEMS, TIGER_BENCH_ARCH["sem_id_dim"]
     L = items * D
     model = Tiger(**TIGER_BENCH_ARCH)

@@ -27,9 +27,8 @@ inside the sharded-jit program. The partitioning question itself needs
 the verdict text and the docs/PERF.md note say which of the two cases
 was actually observed.
 
-Run on the TPU host:  python scripts/check_fused_ce_hlo.py
-Appends a verdict line to docs/PERF.md when --write-note is passed
-(the watchdog does).
+Run on the chip:  python scripts/check_fused_ce_hlo.py
+Appends a verdict line to docs/PERF.md when --write-note is passed.
 """
 
 from __future__ import annotations
@@ -49,16 +48,25 @@ def main(argv=None):
         argv,
         small_help="tiny shapes for fast CI runs (scripts/ci_checks.sh --smoke)",
     )
-
-    import jax
-
     if args.platform:
         # Platform pinning stays OUT of the leaf analysis package (its own
         # layering rule): scripts import the runtime helper directly.
         from genrec_tpu.parallel.mesh import pin_platform
 
         pin_platform(args.platform)
+    # `--platform cpu` is the caller asking for the CPU certify run: the
+    # only way the fused-CE kernel runs there is the Pallas interpreter,
+    # so that is asked for too (kernels/policy.py never infers it).
+    import contextlib
 
+    from genrec_tpu.kernels.policy import interpret_mode
+
+    with interpret_mode() if args.platform == "cpu" else contextlib.nullcontext():
+        return _check(args)
+
+
+def _check(args):
+    import jax
     import jax.numpy as jnp
     import numpy as np
     import optax

@@ -10,13 +10,6 @@ default "base"):
   to_host / barrier / allgather_host_ints / any_across_processes,
   TopKAccumulator.reduce(cross_process=True), and orbax save/restore of
   a NON-ADDRESSABLE (cross-process data-sharded) array.
-- ``consensus``: per-host checkpoint directories
-  (`CheckpointManager(per_host=True)` -> ``<dir>/p<process>/``), the
-  newest step garbled on process 1 ONLY (chaos fault injection scoped
-  to one host), then `restore_latest_valid_consensus`: process 1's
-  ladder quarantines its step locally, the fleet allgathers
-  newest-valid steps, and BOTH processes restore the same older step —
-  the divergence-free-restore guarantee.
 - ``commit``: coordinated commit under a host lost MID-SAVE. Both
   processes contribute shards of a cross-process-sharded array to a
   shared-directory save; a chaos plan SIGKILLs process 1 after its
@@ -113,88 +106,6 @@ def _scenario_base(process_id: int, ckpt_dir: str) -> None:
     mgr.close()
 
 
-def _scenario_consensus(process_id: int, ckpt_dir: str) -> None:
-    """One host's newest checkpoint corrupted -> both hosts restore the
-    SAME older step through `restore_latest_valid_consensus`."""
-    import numpy as np
-
-    from genrec_tpu.core import chaos
-    from genrec_tpu.core.checkpoint import CheckpointManager
-    from genrec_tpu.parallel import barrier
-
-    # Per-host record trees (host-local numpy state): <dir>/p<process>/.
-    mgr = CheckpointManager(ckpt_dir, per_host=True, max_to_keep=4)
-    assert mgr.directory.endswith(f"p{process_id}"), mgr.directory
-    for s in (1, 2):
-        mgr.save(s, {"w": np.full((4,), float(s), np.float32)})
-    mgr.wait()
-    barrier("per-host-saves-done")
-
-    # Per-host fault injection: garble the NEWEST step on process 1 ONLY
-    # (scoped exactly like ChaosPlan(only_process=1) scopes live faults).
-    plan = chaos.ChaosPlan(only_process=1)
-    if chaos._this_process_targeted(plan):
-        chaos.garble_checkpoint(mgr.directory, 2)
-    barrier("corruption-injected")
-
-    like = {"w": np.zeros((4,), np.float32)}
-    restored, step = mgr.restore_latest_valid_consensus(like)
-    # Process 0's newest-valid is 2, process 1's is 1 after its local
-    # ladder quarantines the garbled step: the fleet minimum wins on
-    # BOTH hosts — never a forked restore.
-    assert step == 1, f"p{process_id} restored step {step}, want 1"
-    np.testing.assert_array_equal(restored["w"], np.full((4,), 1.0))
-    if process_id == 1:
-        q = os.path.join(mgr.directory, "quarantine", "p1", "2")
-        assert os.path.isdir(q), "garbled step not quarantined per-host"
-    # Process 0's locally-VALID step 2 was abandoned by the fleet-agreed
-    # restore at step 1 and must be quarantined too: retained, orbax
-    # would silently drop every future save keyed below it, and the
-    # stale-step refusal would abort p0 alone while p1 trains on.
-    if process_id == 0:
-        q = os.path.join(mgr.directory, "quarantine", "p0", "2")
-        assert os.path.isdir(q), "consensus-abandoned step not quarantined"
-    mgr.close()
-
-    # --- the PRODUCTION restore path (`resume_exact`) over the same
-    # fork: p1's newest resume point garbled -> BOTH hosts must get the
-    # older cursor back (no per-host stale-step refusal, no deadlock),
-    # and a post-restore save must actually land.
-    from genrec_tpu.core import fault_tolerance as ft
-
-    mgr2 = CheckpointManager(
-        os.path.join(ckpt_dir, "exact"), per_host=True, max_to_keep=4
-    )
-    for s, (ep, nb) in ((3, (0, 3)), (6, (1, 2))):
-        ft.save_resume_point(
-            mgr2, {"w": np.full((4,), float(s), np.float32)},
-            epoch=ep, next_batch=nb, global_step=s, data_seed=17,
-        )
-    mgr2.wait()
-    barrier("exact-saves-done")
-    if chaos._this_process_targeted(plan):
-        chaos.garble_checkpoint(mgr2.directory, 6)
-    barrier("exact-corruption-injected")
-    point = ft.resume_exact(
-        mgr2, {"w": np.zeros((4,), np.float32)}, data_seed=17
-    )
-    assert point is not None, f"p{process_id} got no resume point"
-    assert (point.global_step, point.epoch, point.next_batch) == (3, 0, 3), (
-        f"p{process_id} cursor ({point.global_step}, {point.epoch}, "
-        f"{point.next_batch}), want (3, 0, 3)"
-    )
-    np.testing.assert_array_equal(point.state["w"], np.full((4,), 3.0))
-    # The hazard resume_exact refuses elsewhere is really gone: a save
-    # keyed above the restore point lands (CheckpointManager.save raises
-    # on an orbax-refused save).
-    ft.save_resume_point(
-        mgr2, {"w": np.full((4,), 4.0, np.float32)},
-        epoch=0, next_batch=4, global_step=4, data_seed=17, wait=True,
-    )
-    assert mgr2.latest_step() == 4, mgr2.latest_step()
-    mgr2.close()
-
-
 def _scenario_commit(process_id: int, ckpt_dir: str) -> None:
     """Process 1 dies (SIGKILL) mid-save of a cross-process-sharded
     array: the step must never gain a commit marker anywhere."""
@@ -261,7 +172,6 @@ def main(coordinator: str, process_id: int, ckpt_dir: str,
 
     fn = {
         "base": _scenario_base,
-        "consensus": _scenario_consensus,
         "commit": _scenario_commit,
     }[scenario]
     fn(process_id, ckpt_dir)

@@ -99,7 +99,7 @@ class TestIRRules:
         def upcast(x):
             return jnp.asarray(x, jnp.float64) * 2.0
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             built = BuiltEntry(fn=jax.jit(upcast),
                                args=(jnp.zeros((8,), jnp.float32),))
             found, _ = ir.analyze_entry("fix/f64", built)

@@ -125,7 +125,7 @@ def test_fused_ce_hlo_check_small_is_inconclusive_not_failed(capsys):
 
 def test_check_scripts_keep_their_cli():
     """The shared harness must preserve every script's flag surface
-    (ci_checks.sh and the watchdog pass these exact flags)."""
+    (ci_checks.sh passes these exact flags)."""
     for script in ("check_decode_hlo", "check_packed_hlo",
                    "check_fused_ce_hlo", "check_serving_hlo",
                    "check_catalog_hlo", "check_fleet", "check_disagg",

@@ -240,6 +240,63 @@ def test_integrity_ladder_falls_back(tmp_path, damage):
     mgr.close()
 
 
+def _fake_fleet(monkeypatch, peer_votes):
+    """Stand in for a 2-process fleet: this process is p0, the peer's
+    answers to the consensus pass's two allgathers (newest-valid step,
+    then ok flag) are scripted. The real 2-process collectives are
+    covered by tests/test_multihost.py; the DECISIONS are pinned here."""
+    from genrec_tpu.core import checkpoint
+    from genrec_tpu.parallel import mesh
+
+    class TwoProcessJax:
+        """`jax` as core.checkpoint sees it on a 2-process fleet — only
+        that module's view: orbax keeps reading the real one."""
+
+        process_count = staticmethod(lambda: 2)
+
+        def __getattr__(self, name):
+            return getattr(jax, name)
+
+    votes = iter(peer_votes)
+    monkeypatch.setattr(checkpoint, "jax", TwoProcessJax())
+    monkeypatch.setattr(
+        mesh, "allgather_host_ints",
+        lambda mine: np.asarray([list(mine), [next(votes)]], np.int64),
+    )
+    monkeypatch.setattr(mesh, "barrier", lambda name="barrier": None)
+
+
+@pytest.mark.chaos_unit
+def test_consensus_restore_falls_back_to_the_fleet_minimum(tmp_path, monkeypatch):
+    """The peer's newest step is damaged, so its newest-valid is 1 while
+    ours is 2: both must restore step 1 — never a forked restore — and our
+    locally-valid step 2, abandoned by that decision, leaves discovery
+    (retained, orbax would drop every later save keyed below it)."""
+    d = str(tmp_path / "ck")
+    mgr = CheckpointManager(d, max_to_keep=3)
+    for s in (1, 2):
+        mgr.save(s, _dict_state(float(s)))
+    mgr.wait()
+    _fake_fleet(monkeypatch, peer_votes=[1, 1])  # peer: step 1, then ok
+    restored, step = mgr.restore_latest_valid_consensus(_dict_state(0.0))
+    assert step == 1 and float(restored["w"][0, 0]) == 1.0
+    assert mgr.all_steps() == [1]
+    assert os.path.isdir(os.path.join(d, "quarantine", "p0", "2"))
+    mgr.close()
+
+
+@pytest.mark.chaos_unit
+def test_consensus_restore_refuses_a_valid_vs_nothing_fleet(tmp_path, monkeypatch):
+    d = str(tmp_path / "ck")
+    mgr = CheckpointManager(d, max_to_keep=3)
+    mgr.save(1, _dict_state(1.0))
+    mgr.wait()
+    _fake_fleet(monkeypatch, peer_votes=[-1])  # peer has nothing valid
+    with pytest.raises(RuntimeError, match="p0=1, p1=none"):
+        mgr.restore_latest_valid_consensus(_dict_state(0.0))
+    mgr.close()
+
+
 @pytest.mark.chaos_unit
 def test_integrity_ladder_rejects_nonfinite_and_mismatch(tmp_path):
     d = str(tmp_path / "ck")

@@ -1,7 +1,5 @@
 """Central Pallas auto-enable policy (kernels/policy.py)."""
 
-import os
-
 import jax
 
 from genrec_tpu.kernels import policy
@@ -13,20 +11,21 @@ def test_cpu_backend_disables_all_autos():
     assert policy.auto_fused_ce() is False
     assert policy.auto_fused_ce(tensor_parallel=2) is False
     assert policy.auto_pallas_attention() is False
+    assert policy.auto_paged_attention() is False
     assert policy.auto_sharded_fused_ce() is False
 
 
-def test_kill_switch_env(monkeypatch):
+def test_no_environment_switch_moves_a_tpu_run_off_its_kernels(monkeypatch):
+    """The old GENREC_TPU_DISABLE_PALLAS kill-switch is gone: on a TPU the
+    autos answer from the backend and nothing else."""
+    assert not hasattr(policy, "pallas_disabled")
+    monkeypatch.setattr(policy.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(policy.jax, "device_count", lambda: 1)
     monkeypatch.setenv("GENREC_TPU_DISABLE_PALLAS", "1")
-    assert policy.pallas_disabled() is True
-    assert policy.auto_fused_ce() is False
-    assert policy.auto_sharded_fused_ce() is False
-    monkeypatch.setenv("GENREC_TPU_DISABLE_PALLAS", "true")
-    assert policy.pallas_disabled() is True
-    monkeypatch.setenv("GENREC_TPU_DISABLE_PALLAS", "0")
-    assert policy.pallas_disabled() is False
-    monkeypatch.delenv("GENREC_TPU_DISABLE_PALLAS")
-    assert policy.pallas_disabled() is False
+    assert policy.auto_fused_ce() is True
+    assert policy.auto_pallas_attention() is True
+    assert policy.auto_paged_attention() is True
+    assert policy.auto_sharded_fused_ce() is True
 
 
 def test_dense_auto_requires_single_chip_and_tp1(monkeypatch):
@@ -41,6 +40,3 @@ def test_dense_auto_requires_single_chip_and_tp1(monkeypatch):
     assert policy.auto_fused_ce() is False
     assert policy.auto_pallas_attention() is True
     assert policy.auto_sharded_fused_ce() is True
-    monkeypatch.setenv("GENREC_TPU_DISABLE_PALLAS", "1")
-    assert policy.auto_pallas_attention() is False
-    assert policy.auto_sharded_fused_ce() is False

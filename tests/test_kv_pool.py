@@ -93,6 +93,33 @@ def test_pool_admit_evict_binds_block_tables():
     pool.check_invariants()
 
 
+def test_pool_reset_after_a_donating_call_consumed_the_pages():
+    """What a prefill that DONATES the pools leaves behind when it fails
+    after launch: deleted page arrays. The pool can tell, and can start
+    over from zeros without touching its slot bookkeeping (the runner
+    evicts what read the lost pages first)."""
+    cfg = PagedConfig(max_slots=2, page_size=8, pages_per_slot=2)
+    pool = KVPagePool(cfg, n_layers=2, n_heads=2, head_dim=4)
+    slot = pool.admit(9)
+    assert not pool.device_pools_consumed()
+    pool.k_pools[1].delete()  # the failed call's donation
+    assert pool.device_pools_consumed()
+    pool.reset_device_pools()
+    assert not pool.device_pools_consumed()
+    assert len(pool.k_pools) == len(pool.v_pools) == 2
+    assert pool.k_pools[1].shape == (cfg.num_pages, 8, 2, 4)
+    assert not np.asarray(pool.k_pools[1]).any()
+    assert pool.seq_lens[slot] == 9  # bookkeeping untouched
+    pool.evict(slot)
+    pool.check_invariants()
+    # A slot view resets (and sees) its bank's arrays.
+    view = KVPagePool(cfg, 2, 2, 4, bank=pool)
+    pool.v_pools[0].delete()
+    assert view.device_pools_consumed()
+    view.reset_device_pools()
+    assert not pool.device_pools_consumed()
+
+
 def test_pool_exhaustion_defers_cleanly():
     cfg = PagedConfig(max_slots=8, page_size=8, pages_per_slot=2, num_pages=4)
     pool = KVPagePool(cfg, n_layers=1, n_heads=2, head_dim=4)

@@ -5,11 +5,10 @@ the `jax.process_count() > 1` branches (VERDICT r3 weak #7): shard_batch's
 make_array_from_process_local_data upload, metric_allreduce /
 TopKAccumulator(cross_process=True) partial-sum reduction, to_host's
 process_allgather, barrier, orbax checkpointing of non-addressable
-arrays — and, since PR 4, the multi-host fault-tolerance guarantees:
-checkpoint-restore CONSENSUS (one host's corrupt newest checkpoint pulls
-every host to the same older step instead of forking the fleet) and
-COORDINATED COMMIT (a host SIGKILLed mid-save never yields a
-commit-markered checkpoint). Each test launches two ACTUAL processes
+arrays — and, since PR 4, COORDINATED COMMIT (a host SIGKILLed mid-save
+never yields a commit-markered checkpoint). The consensus-restore
+decision logic is pinned in-process, with the collectives faked, by
+tests/test_fault_tolerance.py. Each test launches two ACTUAL processes
 (4 virtual CPU devices each -> one 8-device global mesh over the gRPC
 coordinator) running tests/_multihost_worker.py.
 """
@@ -74,9 +73,8 @@ def _launch_workers(tmp_path, scenario):
     return procs, outs, ckpt_dir
 
 
-@pytest.mark.parametrize("scenario", ["base", "consensus"])
-def test_two_process_distributed(tmp_path, scenario):
-    procs, outs, _ = _launch_workers(tmp_path, scenario)
+def test_two_process_distributed(tmp_path):
+    procs, outs, _ = _launch_workers(tmp_path, "base")
     for pid, (p, out) in enumerate(zip(procs, outs)):
         assert p.returncode == 0, f"worker {pid} failed:\n{out[-4000:]}"
         assert f"MULTIHOST_OK {pid}" in out, out[-2000:]

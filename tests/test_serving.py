@@ -480,3 +480,44 @@ def test_retrieval_head_clamps_history_bucket_to_max_seq_len(sasrec_setup, rng):
         assert r.bucket == (1, 32)  # ladder key; shapes clamp inside the head
     finally:
         eng.stop()
+
+
+@pytest.mark.slow  # compiles an engine
+def test_failed_prefill_that_consumed_donated_pools_resets_them(zoo, corpus):
+    """On a TPU the prefill donates the page pools, so a prefill that
+    fails after launch takes them with it. The engine must fail what was
+    resident, reset the pools, and keep serving — not raise "Array has
+    been deleted" on every later call. CPU does not donate, so the lost
+    buffers are simulated by deleting them as the failing call would."""
+    models, params = zoo
+    valid, _ = corpus
+    head = TigerGenerativeHead(models["tiger"], valid, top_k=4, name="tiger")
+    engine = ServingEngine(
+        [head], params["tiger"], ladder=BucketLadder((1, 2), (2,)),
+        max_batch=2, max_wait_ms=1.0, handle_signals=False,
+        paged_config=PagedConfig(max_slots=2, page_size=8, pages_per_slot=1),
+    ).start()
+    try:
+        runner = engine._runners["tiger"]
+        real_prefill = runner._run_prefill
+
+        def failing_prefill(*a, **k):
+            for leaf in jax.tree_util.tree_leaves(
+                (runner.pool.k_pools, runner.pool.v_pools)
+            ):
+                leaf.delete()
+            raise RuntimeError("device fault mid-prefill")
+
+        rng = np.random.default_rng(0)
+        runner._run_prefill = failing_prefill
+        with pytest.raises(RuntimeError, match="device fault"):
+            engine.serve(_req("tiger", rng, 2, len(valid)), timeout=60)
+        runner._run_prefill = real_prefill
+        assert not runner.pool.device_pools_consumed()
+        resp = engine.serve(_req("tiger", rng, 2, len(valid)), timeout=60)
+        assert len(resp.items) == 4
+    finally:
+        stats = engine.stop()
+    assert stats["failed"] == 1 and stats["completed"] == 1
+    pool = stats["kv_pool"]["tiger"]
+    assert pool["pages_in_use"] == 0 and pool["slots_active"] == 0
