@@ -1,0 +1,7 @@
+"""Per-layer metric ``device_idle_share.train``: 1 - union of device-op intervals over the traced window."""
+
+from benchmark.harness import readers
+
+
+def read(ctx):
+    return readers.device_idle_share(ctx, "train")
