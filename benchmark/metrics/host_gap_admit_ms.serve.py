@@ -1,0 +1,7 @@
+"""Per-layer metric ``host_gap_admit_ms.serve``: the host gap per decode step that falls inside `admit.pop` and the four `prefill.*` spans of the batcher's lane (trace + program spans)."""
+
+from benchmark.harness import phase_readers
+
+
+def read(ctx):
+    return phase_readers.host_gap_phase_ms(ctx, "admit")

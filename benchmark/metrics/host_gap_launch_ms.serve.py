@@ -1,0 +1,7 @@
+"""Per-layer metric ``host_gap_launch_ms.serve``: the host gap per decode step that falls inside `decode.launch` spans of the batcher's lane (trace + program spans)."""
+
+from benchmark.harness import phase_readers
+
+
+def read(ctx):
+    return phase_readers.host_gap_phase_ms(ctx, "launch")

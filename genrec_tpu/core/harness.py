@@ -45,8 +45,13 @@ def make_train_step(
     accum_steps: int = 1,
     clip_norm: float | None = 1.0,
     skip_nonfinite: bool = True,
+    name: str = "train_step",
 ):
     """Build `step(state, batch) -> (state, metrics)`, ready to jit.
+
+    ``name`` (`<model>_train_step`, `<model>_train_step_packed`) is the
+    step function's own: jitted, the executable reads `jit_<name>` on a
+    device profile's `XLA Modules` line and in every op's scope path.
 
     With ``accum_steps > 1`` the batch's leading dim is split into
     ``accum_steps`` microbatches scanned sequentially — same semantics as
@@ -82,7 +87,7 @@ def make_train_step(
         step_rng = fast_step_rng(step_rng)
 
         # named_scope: phase labels survive into the compiled HLO/XLA
-        # profile, so a device trace (core.profiling.trace / ProfileWindow)
+        # profile, so a device trace (core.profiling.ProfileWindow)
         # attributes kernel time to grads vs clip vs optimizer — the
         # device-side half of the obs layer's host spans.
         if accum_steps == 1:
@@ -153,4 +158,5 @@ def make_train_step(
         )
         return new_state, metrics
 
+    step.__name__ = step.__qualname__ = name
     return step

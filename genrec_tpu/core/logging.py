@@ -92,11 +92,19 @@ def log_serving_stats(logger, tracker, stats: Mapping[str, Any]) -> None:
     # they can never read as belonging to whichever head's pool line they
     # used to be printed inside.
     if stats.get("kv_pool"):
+        # Useful over attempted: live over compiled decode slots, real
+        # over bucketed prefill positions.
+        slot_steps = stats.get("decode_slot_steps", 0)
+        token_slots = stats.get("prefill_token_slots", 0)
         logger.info(
             f"serving paged engine totals: admits={stats.get('admits', 0)} "
             f"evictions={stats.get('evictions', 0)} "
             f"oom_deferred={stats.get('oom_deferred_admits', 0)} "
-            f"decode_steps={stats.get('decode_steps', 0)}"
+            f"decode_steps={stats.get('decode_steps', 0)} "
+            f"slot_occupancy="
+            f"{100.0 * stats.get('decode_live_slot_steps', 0) / max(slot_steps, 1):.1f}% "
+            f"prefill_occupancy="
+            f"{100.0 * stats.get('prefill_tokens', 0) / max(token_slots, 1):.1f}%"
         )
     # Paged decode heads: one pool-pressure line per head (pages + slot
     # occupancy + churn), so an operator sees "pool-bound" vs "idle" at a

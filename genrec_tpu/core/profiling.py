@@ -1,39 +1,24 @@
 """Profiling / tracing hooks.
 
 The reference has NO tracing or profiling at all (SURVEY.md §5.1 — tqdm
-bars only). Here profiling is a first-class utility: `trace()` wraps
-jax.profiler (TensorBoard-viewable XLA traces incl. per-kernel timing),
-`StepTimer` gives steps/sec + seq/sec with compile-step exclusion, and
-`annotate` names regions inside traces.
+bars only). Here profiling is a first-class utility: `ProfileWindow`
+captures a jax.profiler trace of a few steps (TensorBoard-viewable XLA
+traces incl. per-kernel timing) and `StepTimer` gives steps/sec + seq/sec
+with compile-step exclusion.
 
 Host-side span tracing, goodput accounting, and the crash flight
 recorder live in `genrec_tpu/obs` (docs/OBSERVABILITY.md); a device
-profile captured here lines up with those host spans via
-`SpanTracer(bridge_jax=True)` and the named_scope phase labels in
-core/harness.py and ops/trie.py.
+profile captured here joins those host spans by the clock anchor
+`ProfileWindow` has the tracer leave (`SpanTracer.profile_anchor`), and
+reads by the named_scope phase labels in core/harness.py, the models and
+ops/trie.py.
 """
 
 from __future__ import annotations
 
-import contextlib
 import time
 
 import jax
-
-
-@contextlib.contextmanager
-def trace(logdir: str):
-    """Capture a jax.profiler trace (view with TensorBoard's profile tab)."""
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named region inside a trace (context manager)."""
-    return jax.profiler.TraceAnnotation(name)
 
 
 class ProfileWindow:
@@ -44,7 +29,8 @@ class ProfileWindow:
     Trainers construct one unconditionally (n_steps=0 or an empty logdir
     disables) and call ``tick(n_finished)`` after each optimizer step with
     the RUNNING COUNT of finished steps; ``close()`` stops a still-open
-    trace when the run ends early.
+    trace when the run ends early. A ``tracer`` handed to ``tick`` that is
+    on leaves its clock anchor in the profile as the trace starts.
     """
 
     def __init__(self, logdir: str, n_steps: int = 0, start: int = 1):
@@ -53,10 +39,12 @@ class ProfileWindow:
         self.start = start
         self._state = "idle" if (n_steps > 0 and logdir) else "done"
 
-    def tick(self, n_finished: int) -> None:
+    def tick(self, n_finished: int, tracer=None) -> None:
         """Call after each step with the 1-based count of finished steps."""
         if self._state == "idle" and n_finished >= self.start:
             jax.profiler.start_trace(self.logdir)
+            if tracer is not None:
+                tracer.profile_anchor()
             self._state = "on"
         elif self._state == "on" and n_finished >= self.start + self.n_steps:
             jax.profiler.stop_trace()

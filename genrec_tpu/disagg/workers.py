@@ -976,7 +976,9 @@ class DecodeWorker:
                     )
         if spec:
             self.steps[active_idx] += adv
-            self.metrics.record_decode_step()
+            self.metrics.record_decode_step(
+                S, len(active_idx),
+                int(self.pool.seq_lens[active_idx].sum()))
             self.metrics.record_spec(
                 self.head.name,
                 drafted=len(active_idx)
@@ -996,7 +998,9 @@ class DecodeWorker:
                         )
         else:
             self.steps[self.active] += 1
-            self.metrics.record_decode_step()
+            self.metrics.record_decode_step(
+                S, len(active_idx),
+                int(self.pool.seq_lens[active_idx].sum()))
         self.decode_steps += 1
         self.sweep_finished()
         return True

@@ -187,6 +187,7 @@ def fused_linear_ce_fwd(x, w, targets, ignore_index=0, blk_r=128, blk_v=512,
             pltpu.VMEM((blk_r, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_ce_fwd",
     )(_vlim_operand(V, vlim), xf, wf, tf)
     return loss[:R, 0], lse[:R, 0]
 
@@ -224,6 +225,7 @@ def fused_linear_ce_bwd(x, w, targets, lse, g, ignore_index=0, blk_r=128,
         in_specs=specs(row=first, voc=second),
         out_specs=pl.BlockSpec((blk_r, dp), lambda i, j: (i, 0)),
         interpret=interpret,
+        name="fused_ce_dx",
     )(vf, xf, wf, tf, lsef, gf)
 
     dw = pl.pallas_call(
@@ -233,6 +235,7 @@ def fused_linear_ce_bwd(x, w, targets, lse, g, ignore_index=0, blk_r=128,
         in_specs=specs(row=second, voc=first),
         out_specs=pl.BlockSpec((blk_v, dp), lambda i, j: (i, 0)),
         interpret=interpret,
+        name="fused_ce_dw",
     )(vf, xf, wf, tf, lsef, gf)
 
     return dx[:R, : x.shape[1]].astype(x.dtype), dw[:V, : w.shape[1]].astype(w.dtype)

@@ -229,6 +229,7 @@ def hstu_attention_pallas(
         ],
         out_specs=pl.BlockSpec((1, blk_q, hp), lambda i, j: (i, j, 0)),
         interpret=interpret,
+        name="hstu_attention_fwd",
     )(qf, kf, vf, tsf[:, None], tsf[:, None], maskf[:, None],
       segf[:, None], segf[:, None], pos_table.astype(jnp.float32),
       time_table.astype(jnp.float32))
@@ -362,6 +363,7 @@ def hstu_attention_bwd_pallas(
         # at long L (preflight runs L=2048).
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_BWD_VMEM_BYTES),
         interpret=interpret,
+        name="hstu_attention_bwd",
     )(qf, kf, vf, gf, tsf[:, None], tsf[:, None], maskf[:, None],
       segf[:, None], segf[:, None], pos_table.astype(jnp.float32),
       ttab.astype(jnp.float32))

@@ -140,9 +140,12 @@ def _block_diag_queries(q, Kp: int):
 
 
 def _paged_call(kernel, q, pool_operands, pool_specs, block_tables, seq_lens,
-                page: int, interpret: bool):
+                page: int, interpret: bool, name: str):
     """Shared pallas_call of the fp32 and int8 kernels: block-diagonal
-    queries in, per-head (acc, m, l) stats out."""
+    queries in, per-head (acc, m, l) stats out. ``name`` is the kernel's
+    own on a device profile; it alone may hold the word `paged`, by
+    which a profile reader finds the kernel's custom calls (an enclosing
+    scope with that word would claim every other custom call inside it)."""
     S, K, H, hd = q.shape
     Pm = block_tables.shape[1]
     Kp = _round_up(K, 8)
@@ -171,6 +174,7 @@ def _paged_call(kernel, q, pool_operands, pool_specs, block_tables, seq_lens,
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
+        name=name,
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32), qbd,
       *pool_operands)
 
@@ -211,7 +215,7 @@ def paged_attention_stats_pallas_quantized(q, k_pool, v_pool, block_tables,
         (k_pool.data.reshape(P, page, H * hd), k_pool.scale[:, None, :],
          v_pool.data.reshape(P, page, H * hd), v_pool.scale[:, None, :]),
         (data_spec, scale_spec, data_spec, scale_spec),
-        block_tables, seq_lens, page, interpret,
+        block_tables, seq_lens, page, interpret, "paged_attention_int8",
     )
 
 
@@ -230,4 +234,5 @@ def paged_attention_stats_pallas(q, k_pool, v_pool, block_tables, seq_lens,
         _kernel, q,
         (k_pool.reshape(P, page, H * hd), v_pool.reshape(P, page, H * hd)),
         (spec, spec), block_tables, seq_lens, page, interpret,
+        "paged_attention",
     )

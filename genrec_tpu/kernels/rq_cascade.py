@@ -119,6 +119,7 @@ def rq_cascade_pallas(
         # v5e has 128MB VMEM; 64MB headroom measured OK on hardware.
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 * 2**20),
         interpret=interpret,
+        name="rq_cascade",
     )(xf, cbf)
     return (
         ids.T[:B],

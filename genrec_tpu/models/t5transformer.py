@@ -82,13 +82,15 @@ class T5Attention(nn.Module):
         ((B, L) int32, within-segment for packed rows) -> (B, H, L, L).
         Cross-segment pairs get arbitrary buckets here; the caller masks
         them before softmax so they never contribute."""
-        buckets = t5_bucket_grid_from_positions(
-            positions, self.num_relative_buckets, self.max_distance,
-            bidirectional=True,
-        )  # (B, L, L)
-        head_offset = jnp.arange(self.n_heads)[:, None, None] * self.num_relative_buckets
-        idx = buckets[:, None] + head_offset[None]  # (B, H, L, L)
-        return self.rel_bias[idx, 0]
+        with jax.named_scope("position_bias"):
+            buckets = t5_bucket_grid_from_positions(
+                positions, self.num_relative_buckets, self.max_distance,
+                bidirectional=True,
+            )  # (B, L, L)
+            head_offset = (jnp.arange(self.n_heads)[:, None, None]
+                           * self.num_relative_buckets)
+            idx = buckets[:, None] + head_offset[None]  # (B, H, L, L)
+            return self.rel_bias[idx, 0]
 
     def __call__(
         self,
@@ -497,9 +499,10 @@ class TransformerDecoder(nn.Module):
         layer)."""
         new_caches = []
         for layer, cache, kp, vp in zip(self.layers, caches, k_pools, v_pools):
-            x, nc = layer.decode_step_paged(
-                x, cache, kp, vp, block_tables, seq_lens, steps
-            )
+            with jax.named_scope("decoder_layer"):
+                x, nc = layer.decode_step_paged(
+                    x, cache, kp, vp, block_tables, seq_lens, steps
+                )
             new_caches.append(nc)
         return x, new_caches
 

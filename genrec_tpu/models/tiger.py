@@ -224,10 +224,11 @@ class Tiger(nn.Module):
         enc = self.in_proj_context(
             self.drop(self.norm_context(enc), deterministic=deterministic)
         )
-        memory = self.transformer.encoder(
-            enc, attn_mask=seg_mask, key_padding_mask=pad,
-            deterministic=deterministic, positions=positions,
-        )
+        with jax.named_scope("encoder"):
+            memory = self.transformer.encoder(
+                enc, attn_mask=seg_mask, key_padding_mask=pad,
+                deterministic=deterministic, positions=positions,
+            )
 
         _, S, D = target_ids.shape
         N = R * S
@@ -241,20 +242,22 @@ class Tiger(nn.Module):
         mem = jnp.repeat(memory, S, axis=0)  # (N, L, attn_dim)
         seg_of = jnp.tile(jnp.arange(1, S + 1), R)  # (N,)
         mem_pad = jnp.repeat(segment_ids, S, axis=0) != seg_of[:, None]
-        out = self.transformer.decoder(
-            dec, mem,
-            attn_mask=causal_mask(dec.shape[1]),
-            memory_key_padding_mask=mem_pad,
-            deterministic=deterministic,
-        )
-        logits = self._mask_pad_logits(self.output_head(out))
-        target_vocab = tgt_types * self.num_item_embeddings + tgt_flat
-        per_tok, _ = cross_entropy_with_ignore(
-            logits[:, :-1, :], target_vocab, ignore_index=-1
-        )
-        seq_loss = per_tok.sum(axis=1).reshape(R, S)
-        valid = segment_valid.astype(jnp.float32)
-        loss = (seq_loss * valid).sum() / jnp.maximum(valid.sum(), 1.0)
+        with jax.named_scope("decoder"):
+            out = self.transformer.decoder(
+                dec, mem,
+                attn_mask=causal_mask(dec.shape[1]),
+                memory_key_padding_mask=mem_pad,
+                deterministic=deterministic,
+            )
+        with jax.named_scope("loss"):
+            logits = self._mask_pad_logits(self.output_head(out))
+            target_vocab = tgt_types * self.num_item_embeddings + tgt_flat
+            per_tok, _ = cross_entropy_with_ignore(
+                logits[:, :-1, :], target_vocab, ignore_index=-1
+            )
+            seq_loss = per_tok.sum(axis=1).reshape(R, S)
+            valid = segment_valid.astype(jnp.float32)
+            loss = (seq_loss * valid).sum() / jnp.maximum(valid.sum(), 1.0)
         return TigerPackedOutput(
             per_example_loss=seq_loss, loss=loss,
             real_tokens=jnp.sum(segment_ids != 0),
@@ -263,9 +266,12 @@ class Tiger(nn.Module):
     # ---- generation --------------------------------------------------------
 
     def encode_context(self, user_input_ids, item_input_ids, token_type_ids, seq_mask):
-        enc, pad = self._encoder_input(user_input_ids, item_input_ids, token_type_ids, seq_mask)
-        enc = self.in_proj_context(self.norm_context(enc))
-        memory = self.transformer.encoder(enc, key_padding_mask=pad, deterministic=True)
+        with jax.named_scope("encoder"):
+            enc, pad = self._encoder_input(
+                user_input_ids, item_input_ids, token_type_ids, seq_mask)
+            enc = self.in_proj_context(self.norm_context(enc))
+            memory = self.transformer.encoder(
+                enc, key_padding_mask=pad, deterministic=True)
         return memory, pad
 
     def decode_step(self, memory, memory_pad, tgt_ids, tgt_type):
@@ -386,12 +392,13 @@ def _dedup_top_k(scores, keys, k):
     key reduced to its best instance (vectorized replacement for the
     reference's per-batch Python dedup loop, tiger.py:396-447).
     """
-    order = jnp.lexsort((-scores, keys))  # sort by key, best score first
-    ks = keys[order]
-    first = jnp.concatenate([jnp.ones((1,), bool), ks[1:] != ks[:-1]])
-    keep = jnp.zeros_like(first).at[order].set(first)
-    masked = jnp.where(keep, scores, -jnp.inf)
-    top_scores, top_idx = jax.lax.top_k(masked, k)
+    with jax.named_scope("beam_dedup_top_k"):
+        order = jnp.lexsort((-scores, keys))  # sort by key, best score first
+        ks = keys[order]
+        first = jnp.concatenate([jnp.ones((1,), bool), ks[1:] != ks[:-1]])
+        keep = jnp.zeros_like(first).at[order].set(first)
+        masked = jnp.where(keep, scores, -jnp.inf)
+        top_scores, top_idx = jax.lax.top_k(masked, k)
     return top_scores, top_idx
 
 
@@ -559,39 +566,40 @@ def _tiger_beam_update(model: Tiger, trie, logits, beam_seqs, beam_logps,
     """
     from genrec_tpu.ops.trie import advance_ragged, legal_mask_ragged
 
-    S_, K, D = beam_seqs.shape
-    Kcb = model.num_item_embeddings
-    KK = min(K * sample_factor, Kcb)
-    flat = logits.reshape(S_ * K, -1)
-    window = jax.vmap(
-        lambda row, st: jax.lax.dynamic_slice(row, (st * Kcb,), (Kcb,))
-    )(flat, jnp.repeat(steps, K))  # per-row vocab window at its own step
-    legal = legal_mask_ragged(trie, prefix_idx, steps).reshape(S_ * K, Kcb)
-    masked = jnp.where(legal, window, -1e32)
-    logp = jax.nn.log_softmax(masked / temperature, axis=-1)
+    with jax.named_scope("beam_update"):
+        S_, K, D = beam_seqs.shape
+        Kcb = model.num_item_embeddings
+        KK = min(K * sample_factor, Kcb)
+        flat = logits.reshape(S_ * K, -1)
+        window = jax.vmap(
+            lambda row, st: jax.lax.dynamic_slice(row, (st * Kcb,), (Kcb,))
+        )(flat, jnp.repeat(steps, K))  # per-row vocab window at its own step
+        legal = legal_mask_ragged(trie, prefix_idx, steps).reshape(S_ * K, Kcb)
+        masked = jnp.where(legal, window, -1e32)
+        logp = jax.nn.log_softmax(masked / temperature, axis=-1)
 
-    perturbed = logp if rng is None else logp + jax.random.gumbel(rng, logp.shape)
-    _, cand_tok = jax.lax.top_k(perturbed, KK)
-    cand_logp = jnp.take_along_axis(logp, cand_tok, axis=1)
-    cand_legal = jnp.take_along_axis(legal, cand_tok, axis=1)
-    cand_logp = jnp.where(cand_legal, cand_logp, -1e32)
+        perturbed = logp if rng is None else logp + jax.random.gumbel(rng, logp.shape)
+        _, cand_tok = jax.lax.top_k(perturbed, KK)
+        cand_logp = jnp.take_along_axis(logp, cand_tok, axis=1)
+        cand_legal = jnp.take_along_axis(legal, cand_tok, axis=1)
+        cand_logp = jnp.where(cand_legal, cand_logp, -1e32)
 
-    total = (beam_logps.reshape(S_ * K, 1) + cand_logp).reshape(S_, K * KK)
-    toks = cand_tok.reshape(S_, K * KK)
-    parents = jnp.broadcast_to(jnp.arange(K)[:, None], (K, KK)).reshape(1, K * KK)
-    parents = jnp.broadcast_to(parents, (S_, K * KK))
+        total = (beam_logps.reshape(S_ * K, 1) + cand_logp).reshape(S_, K * KK)
+        toks = cand_tok.reshape(S_, K * KK)
+        parents = jnp.broadcast_to(jnp.arange(K)[:, None], (K, KK)).reshape(1, K * KK)
+        parents = jnp.broadcast_to(parents, (S_, K * KK))
 
-    parent_prefix = jnp.take_along_axis(prefix_idx, parents, axis=1)
-    keys = parent_prefix * Kcb + toks
-    top_scores, top_idx = jax.vmap(lambda s, c: _dedup_top_k(s, c, K))(total, keys)
+        parent_prefix = jnp.take_along_axis(prefix_idx, parents, axis=1)
+        keys = parent_prefix * Kcb + toks
+        top_scores, top_idx = jax.vmap(lambda s, c: _dedup_top_k(s, c, K))(total, keys)
 
-    sel_parent = jnp.take_along_axis(parents, top_idx, axis=1)  # (S, K)
-    sel_tok = jnp.take_along_axis(toks, top_idx, axis=1)
-    new_seqs = jnp.take_along_axis(beam_seqs, sel_parent[..., None], axis=1)
-    hit = jnp.arange(D)[None, None, :] == steps[:, None, None]
-    new_seqs = jnp.where(hit, sel_tok[..., None], new_seqs)
-    sel_prefix = jnp.take_along_axis(prefix_idx, sel_parent, axis=1)
-    new_prefix = advance_ragged(trie, sel_prefix, sel_tok, steps)
+        sel_parent = jnp.take_along_axis(parents, top_idx, axis=1)  # (S, K)
+        sel_tok = jnp.take_along_axis(toks, top_idx, axis=1)
+        new_seqs = jnp.take_along_axis(beam_seqs, sel_parent[..., None], axis=1)
+        hit = jnp.arange(D)[None, None, :] == steps[:, None, None]
+        new_seqs = jnp.where(hit, sel_tok[..., None], new_seqs)
+        sel_prefix = jnp.take_along_axis(prefix_idx, sel_parent, axis=1)
+        new_prefix = advance_ragged(trie, sel_prefix, sel_tok, steps)
     return new_seqs, top_scores, new_prefix, sel_parent, sel_tok
 
 

@@ -27,6 +27,12 @@ _COUNTER_LEAVES = frozenset({
     "submitted", "completed", "rejected", "failed", "batches",
     "warmup_compiles", "recompilations", "params_swaps", "admits",
     "evictions", "oom_deferred_admits", "decode_steps", "count", "steps",
+    # Useful-over-attempted totals of the paged batcher (serving/
+    # metrics.py): live against compiled decode slots, KV tokens
+    # attended, real against bucketed prefill rows and positions.
+    "decode_slot_steps", "decode_live_slot_steps", "decode_kv_tokens",
+    "prefill_rows", "prefill_row_slots", "prefill_tokens",
+    "prefill_token_slots",
     "catalog_swaps", "catalog_compiles", "overload_rejected", "breaches",
     # Prefix-cache lifetime totals (genrec_prefix_cache_<head>_*); the
     # entries/retained_pages/retained_bytes leaves stay gauges.
@@ -79,6 +85,10 @@ _COUNTER_LEAVES = frozenset({
     f"accept_len_{n}" for n in range(1, 17)
 )
 
+#: Sections whose every leaf is a lifetime total keyed by something open-
+#: ended (decode steps per slot rung: `decode_steps_by_slots/s32`).
+_COUNTER_GROUPS = frozenset({"decode_steps_by_slots"})
+
 _NAME_RE = re.compile(r"[^a-zA-Z0-9_]")
 
 
@@ -112,7 +122,10 @@ def prometheus_text(snapshot: Mapping[str, Any], namespace: str = "genrec") -> s
         if not math.isfinite(value):
             continue
         name = _metric_name(path, namespace)
-        kind = "counter" if path.rsplit("/", 1)[-1] in _COUNTER_LEAVES else "gauge"
+        parts = path.split("/")
+        counter = parts[-1] in _COUNTER_LEAVES or (
+            len(parts) > 1 and parts[-2] in _COUNTER_GROUPS)
+        kind = "counter" if counter else "gauge"
         lines.append(f"# TYPE {name} {kind}")
         text = repr(int(value)) if value == int(value) else repr(value)
         lines.append(f"{name} {text}")

@@ -30,10 +30,17 @@ context manager, and ``record_span`` returns immediately —
 `scripts/check_obs.py` asserts the disabled path costs <2% of a serving
 request.
 
-``bridge_jax=True`` additionally enters `jax.profiler.TraceAnnotation`
-for every context-manager span, so host spans line up with XLA kernels
-in a TensorBoard/Perfetto device profile captured by
-`core.profiling.trace`.
+One clock with a device profile: `profile_anchor()`, called right
+after `jax.profiler.start_trace` (`core.profiling.ProfileWindow` does),
+enters a `TraceAnnotation` named `ANCHOR` and records a span of that name
+at the same `time.monotonic()` reading, so the ring and the captured
+`.xplane.pb` join by one offset. ``bridge_jax=True`` additionally enters
+`jax.profiler.TraceAnnotation` for every context-manager span.
+
+Besides request and epoch traces the ring holds LANES: trace ids under
+`LANE_PREFIXES` (`batcher/<head>`, `train-e<n>`, `profile-<n>`) carry
+flat per-iteration phase spans, not a rooted tree, and readers that
+count requests leave them out (`is_lane`).
 """
 
 from __future__ import annotations
@@ -46,6 +53,17 @@ import os
 import threading
 import time
 from typing import Any, Mapping
+
+
+#: Name of the annotation and span `SpanTracer.profile_anchor` leaves.
+ANCHOR = "span_clock_anchor"
+
+#: Trace-id prefixes of lanes: flat phase spans of one thread's loop.
+LANE_PREFIXES = ("batcher/", "train-e", "profile-")
+
+
+def is_lane(trace_id: str) -> bool:
+    return trace_id.startswith(LANE_PREFIXES)
 
 
 @dataclasses.dataclass
@@ -240,6 +258,26 @@ class SpanTracer:
             attrs=attrs,
         ))
         return span_id
+
+    def profile_anchor(self) -> float | None:
+        """Mark one instant in both the device profile being captured and
+        this ring: a `TraceAnnotation` named `ANCHOR` entered at a
+        `time.monotonic()` reading, and a span of that name starting at
+        the same reading. The annotation's start on the profile's clock
+        less the span's ``t0`` is the offset that puts every span of the
+        ring on the profile's timeline. Call right after
+        `jax.profiler.start_trace`. Returns the reading, or None when
+        disabled."""
+        if not self.enabled:
+            return None
+        import jax
+
+        t0 = time.monotonic()
+        with jax.profiler.TraceAnnotation(ANCHOR):
+            time.sleep(0.0005)  # wide enough to find in a viewer
+        self.record_span(ANCHOR, self.new_trace("profile"), t0,
+                         time.monotonic())
+        return t0
 
     def _next_span_id(self) -> int:
         return next(self._span_ids)

@@ -82,17 +82,18 @@ def write_pages(pool, block_tables: jax.Array, kv: jax.Array):
             f"prefill KV of {L} tokens exceeds the {Pm} x {page} page "
             f"capacity of a slot's block-table row"
         )
-    rows = jnp.moveaxis(kv, 1, 2)  # (B, L, H, hd)
-    rows = jnp.pad(rows, ((0, 0), (0, cap - L), (0, 0), (0, 0)))
-    if isinstance(pool, QuantizedKVPool):
-        rows = rows.reshape(B, Pm, page, H, hd)
-        data, scale = quantize_symmetric(rows, (-2, -1))  # scale (B, Pm, page)
-        return QuantizedKVPool(
-            pool.data.at[block_tables].set(data),
-            pool.scale.at[block_tables].set(scale),
-        )
-    rows = rows.reshape(B, Pm, page, H, hd).astype(pool.dtype)
-    return pool.at[block_tables].set(rows)
+    with jax.named_scope("kv_write"):
+        rows = jnp.moveaxis(kv, 1, 2)  # (B, L, H, hd)
+        rows = jnp.pad(rows, ((0, 0), (0, cap - L), (0, 0), (0, 0)))
+        if isinstance(pool, QuantizedKVPool):
+            rows = rows.reshape(B, Pm, page, H, hd)
+            data, scale = quantize_symmetric(rows, (-2, -1))  # scale (B, Pm, page)
+            return QuantizedKVPool(
+                pool.data.at[block_tables].set(data),
+                pool.scale.at[block_tables].set(scale),
+            )
+        rows = rows.reshape(B, Pm, page, H, hd).astype(pool.dtype)
+        return pool.at[block_tables].set(rows)
 
 
 def paged_attention_stats(
@@ -213,7 +214,9 @@ def paged_attention(
     The full-softmax form (TIGER's cross-attention — no suffix to merge
     with): out = acc / l from the stats primitive.
     """
-    acc, _, l = paged_attention_stats(
-        q, k_pool, v_pool, block_tables, seq_lens, use_kernel=use_kernel
-    )
-    return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
+    # `kv_attend`, not a name with `paged` in it: see the kernel's `name=`.
+    with jax.named_scope("kv_attend"):
+        acc, _, l = paged_attention_stats(
+            q, k_pool, v_pool, block_tables, seq_lens, use_kernel=use_kernel
+        )
+        return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)

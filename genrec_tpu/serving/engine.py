@@ -64,8 +64,14 @@ attached (``tracer=`` or ``set_tracer`` live) every request carries a
 span tree — request -> queue_wait -> admission/prefill/per-decode_step
 (paged) or compute (dense) -> finalize — keyed by the request ID minted
 at submit() (`Response.request_id`), with p99-outlier exemplars
-persisted past ring eviction. Tracing is off by default (one attribute
-check per site; budget pinned <2% by scripts/check_obs.py). The flight
+persisted past ring eviction — and the batcher thread records each host
+phase of an iteration ONCE on a lane of its own, `batcher/<head>`
+(`admit.pop`, `prefill.stage/launch/pull/retain`,
+`decode.stage/launch/pull/sweep`, `batcher.idle_wait`), committed after
+the iteration's per-request spans so that a device-idle gap reads by its
+cause. Tracing is off by default (one attribute check per site; budget
+pinned <2% by scripts/check_obs.py). The slot and prefill occupancy
+counters of `ServingMetrics` count whether or not a tracer is on. The flight
 recorder gets lifecycle/drain/hot-reload/OOM-deferral events regardless.
 
 Device-memory ledger (obs/memory.py): warmup sums every compiled
@@ -149,6 +155,17 @@ def _operand_avals(operands) -> tuple:
         (tuple(int(s) for s in leaf.shape), str(leaf.dtype))
         for leaf in jax.tree_util.tree_leaves(operands)
     )
+
+
+def _named(fn, name: str):
+    """``fn`` under a stable name of its own, so that the compiled
+    executable is `jit_<name>` on a device profile's `XLA Modules` line
+    (and in every op's scope path) and not `jit_fn`. The name must not
+    hold the substring `paged`: a profile reader finds the paged-attention
+    kernel's custom calls by that word in their scope path, and an
+    executable's name is in every path."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 def is_transient_fs_error(e: BaseException) -> bool:
@@ -269,6 +286,12 @@ class _PagedRunner:
         self.slot_shapes = sorted(set(shapes))
         self._decode: dict[int, object] = {}
         self._prefill: dict[tuple[int, int], object] = {}
+        # The batcher's own lane of the span ring (docs/OBSERVABILITY.md
+        # "The batcher lane"): each host phase of an iteration ONCE, as
+        # (name, t0, t1, attrs), buffered here by admit() and step() and
+        # committed by flush_phases() when the iteration is over.
+        self.lane = f"batcher/{head.name}"
+        self._phases: list[tuple] = []
         # Futures already counted as OOM-deferred: the gauge counts
         # REQUESTS deferred, not per-batcher-iteration retries.
         self._oom_counted: set[int] = set()
@@ -320,7 +343,8 @@ class _PagedRunner:
 
     def _compile_decode(self, S: int, operands=None, catalog_compile=False):
         eng = self.engine
-        fn = self.head.make_decode_paged_fn()
+        fn = _named(self.head.make_decode_paged_fn(),
+                    f"{self.head.name}_decode_s{S}")
         ops = operands if operands is not None else self.head.runtime_operands()
         args = (
             eng._select(self.head, eng._params),
@@ -348,7 +372,8 @@ class _PagedRunner:
         static constant of the trace — one topology per rung, the
         check_spec_hlo pin."""
         eng = self.engine
-        fn = self.head.make_spec_decode_paged_fn(self.engine._spec_fanout)
+        fn = _named(self.head.make_spec_decode_paged_fn(eng._spec_fanout),
+                    f"{self.head.name}_spec_s{S}")
         ops = operands if operands is not None else self.head.runtime_operands()
         args = (
             eng._select(self.head, eng._params),
@@ -368,7 +393,8 @@ class _PagedRunner:
     def _compile_prefill(self, B: int, L: int, operands=None,
                          catalog_compile=False):
         eng = self.engine
-        fn = self.head.make_prefill_paged_fn(B, L)
+        fn = _named(self.head.make_prefill_paged_fn(B, L),
+                    f"{self.head.name}_prefill_b{B}_l{L}")
         ops = operands if operands is not None else self.head.runtime_operands()
         batch = self.head.make_batch([self.head.dummy_request()], B, L)
         n = 1 + len(ops) + len(batch)  # params + operands + batch
@@ -451,6 +477,12 @@ class _PagedRunner:
                 except PoolExhausted:
                     break
             leftover = [e for e, _k, _n in cold[len(admitted):]]
+            if eng._tracer.enabled:
+                self._phases.append((
+                    "admit.pop", now, time.monotonic(),
+                    {"warm": len(warm), "cold": len(admitted),
+                     "deferred": len(leftover)},
+                ))
             if leftover:  # out of pages: requeue at the FRONT (FIFO order)
                 with eng._lock:
                     eng._queues[self.head.name].extendleft(reversed(leftover))
@@ -719,10 +751,13 @@ class _PagedRunner:
         args = eng._stage(head.make_batch(reqs, B, L))
         bt = np.zeros((B, self.cfg.pages_per_slot), np.int32)
         bt[: len(slots)] = self.pool.block_tables[slots]
+        bt = eng._stage(bt)
+        t_launch = time.monotonic()
         k_pools, v_pools, init = compiled(
             eng._select(head, eng._params), *head.runtime_operands(), *args,
-            eng._stage(bt), self.pool.k_pools, self.pool.v_pools,
+            bt, self.pool.k_pools, self.pool.v_pools,
         )
+        t_launched = time.monotonic()
         self.pool.k_pools, self.pool.v_pools = k_pools, v_pools
         n = len(slots)
         for key in self.state:
@@ -730,6 +765,7 @@ class _PagedRunner:
         for key, val in init.items():
             self.state[key][slots] = np.asarray(val)[:n]
         t_prefilled = time.monotonic()
+        inserted = 0
         if self.prefix is not None and keys is not None:
             # Retain every freshly prefilled run under its history key:
             # the entry addrefs the slot's pages (COW) and snapshots the
@@ -750,7 +786,19 @@ class _PagedRunner:
                     init=snapshot, bucket=(B, L),
                 )
                 eng.metrics.record_prefix_insert(head.name)
+                inserted += 1
             self._publish_prefix_gauges()
+        # Real history positions, in the ladder's own unit (what L counts).
+        tokens = sum(min(max(head.natural_len(r), 1), L) for r in reqs)
+        if eng._tracer.enabled:
+            self._phases += [
+                ("prefill.stage", t_admit, t_launch,
+                 {"bucket_b": B, "bucket_l": L, "rows": n, "tokens": tokens}),
+                ("prefill.launch", t_launch, t_launched, {}),
+                ("prefill.pull", t_launched, t_prefilled, {}),
+                ("prefill.retain", t_prefilled, time.monotonic(),
+                 {"inserted": inserted}),
+            ]
         self.steps[slots] = head.paged_init_step
         self.active[slots] = True
         for e, slot in zip(entries, slots):
@@ -772,7 +820,7 @@ class _PagedRunner:
                                    parent_id=root, bucket_b=B, bucket_l=L,
                                    **ident)
         eng.metrics.record_admit(n)
-        eng.metrics.record_batch(head.name, (B, L))
+        eng.metrics.record_batch(head.name, (B, L), rows=n, tokens=tokens)
         self._sweep_finished()  # heads whose init step == total finish here
 
     # -- decode (one fixed-shape step over all slots) ------------------------
@@ -811,6 +859,7 @@ class _PagedRunner:
             out, accept = self._spec[S](*args)
         else:
             out = self._decode[S](*args)
+        t_launched = time.monotonic()
         for k, v in out.items():  # write back into the host rows
             self.state[k][:S] = np.asarray(v)
         active_idx = np.nonzero(self.active)[0]
@@ -826,7 +875,10 @@ class _PagedRunner:
             ).astype(np.int32)
             adv = np.maximum(adv, 1)  # root level is always exact
         t1 = time.monotonic()
-        if eng._tracer.enabled:
+        live = len(active_idx)
+        kv_tokens = int(self.pool.seq_lens[active_idx].sum())
+        tracing = eng._tracer.enabled
+        if tracing:
             # One fixed-shape step advances EVERY active slot: each
             # resident request gets the same interval(s), tagged with its
             # own position so the span tree reads per-request. Spec
@@ -859,14 +911,14 @@ class _PagedRunner:
                     )
         if spec:
             self.steps[active_idx] += adv
-            eng.metrics.record_decode_step()
+            eng.metrics.record_decode_step(S, live, kv_tokens)
             eng.metrics.record_spec(
                 self.head.name,
                 drafted=len(active_idx)
                 * (self.spec_topology.n_nodes - self.spec_topology.beams),
                 accept_lens=adv,
             )
-            if eng._tracer.enabled:
+            if tracing:
                 t2 = time.monotonic()
                 ident = eng._span_ident()
                 for i, slot in enumerate(active_idx):
@@ -878,14 +930,36 @@ class _PagedRunner:
                         )
         else:
             self.steps[self.active] += 1
-            eng.metrics.record_decode_step()
-        self._sweep_finished()
+            eng.metrics.record_decode_step(S, live, kv_tokens)
+        t_sweep = time.monotonic()
+        finished = self._sweep_finished()
+        if tracing:
+            self._phases += [
+                ("decode.stage", t_stage, t0,
+                 {"slots": S, "live": live, "kv_tokens": kv_tokens}),
+                ("decode.launch", t0, t_launched, {"slots": S}),
+                ("decode.pull", t_launched, t1, {"leaves": len(out)}),
+                ("decode.sweep", t_sweep, time.monotonic(),
+                 {"finished": finished}),
+            ]
         # Chaos hook: a real SIGTERM after the Nth decode step exercises
         # drain mid-churn for the continuous-batching loop.
         chaos.maybe_kill(step=eng.metrics.decode_steps)
         return True
 
-    def _sweep_finished(self) -> None:
+    def flush_phases(self, seq: int) -> None:
+        """Commit the iteration's buffered phases to the batcher's lane,
+        in order of time and AFTER every per-request span of the
+        iteration: a reader that names a device-idle gap by the last
+        span committed over it (the benchmark's `breakdown.idle_gaps`)
+        then reads the phase, not the `decode_step` that holds it."""
+        tracer, ident = self.engine._tracer, self.engine._span_ident()
+        for name, t0, t1, attrs in self._phases:
+            tracer.record_span(name, self.lane, t0, t1, seq=seq,
+                               **attrs, **ident)
+        self._phases.clear()
+
+    def _sweep_finished(self) -> int:
         eng = self.engine
         head = self.head
         total = head.paged_total_steps
@@ -964,6 +1038,7 @@ class _PagedRunner:
             eng.metrics.record_evict(1)
         eng.metrics.set_pool_gauges(head.name, self.pool.stats())
         self._publish_prefix_gauges()
+        return len(done)
 
 
 class ServingEngine:
@@ -1147,6 +1222,7 @@ class ServingEngine:
         # _lock (which stage_catalog takes nested, briefly).
         self._stage_lock = threading.Lock()
         self._rr = 0  # round-robin head cursor (_next_batch)
+        self._seq = 0  # batcher iterations: `seq` on the batcher lane's spans
         self._draining = False
         self._stop_watch = threading.Event()
         self._drained = threading.Event()
@@ -1536,6 +1612,7 @@ class ServingEngine:
                             "serving: shutdown signal latched — draining "
                             "in-flight requests, rejecting new submissions"
                         )
+                    self._seq += 1
                     swap_pending = self._apply_pending_params()
                     swap_pending |= self._apply_pending_catalog()
                     self._poll_slo()
@@ -1549,17 +1626,27 @@ class ServingEngine:
                         if not swap_pending:
                             progressed |= runner.admit()
                         progressed |= runner.step()
+                        if runner._phases:
+                            runner.flush_phases(self._seq)
                     batch = self._next_batch()
                     if batch is not None:
                         self._run_batch(*batch)
                         continue
                     if progressed:
                         continue
+                    waited = None
                     with self._lock:
                         empty = all(not q for q in self._queues.values())
                         runners_idle = all(r.idle for r in self._runners.values())
                         done = self._draining and empty and runners_idle
                         if not done:
+                            if self._tracer.enabled and not empty:
+                                waited = {
+                                    name: len(self._queues[name])
+                                    for name in self._runners
+                                    if self._queues[name]
+                                }
+                                t_wait = time.monotonic()
                             # Wake on submit/stop notify; when requests are
                             # queued, cap the wait so deadline flushes stay
                             # responsive — when idle, back off (guard/drain
@@ -1568,6 +1655,20 @@ class ServingEngine:
                                 timeout=max(self._max_wait_s / 4, 1e-3)
                                 if not (empty and runners_idle)
                                 else 0.05
+                            )
+                    if waited is not None:
+                        t_woke = time.monotonic()
+                        # The batcher stood still with requests queued
+                        # (under the coalescing deadline, or held by a
+                        # staged swap). A wait with nothing queued is not
+                        # recorded: an idle engine would fill the ring.
+                        ident = self._span_ident()
+                        for name, queued in waited.items():
+                            runner = self._runners[name]
+                            self._tracer.record_span(
+                                "batcher.idle_wait", runner.lane, t_wait,
+                                t_woke, seq=self._seq, queued=queued,
+                                live=int(runner.active.sum()), **ident,
                             )
                     if done:
                         # Drained: release every retained prefix page —
@@ -1743,7 +1844,7 @@ class ServingEngine:
         the batch; ``operands`` overrides them for catalog-growth
         precompiles (install=False: the staged swap installs the result,
         the live table keeps serving the old catalog meanwhile)."""
-        fn = head.make_fn(B, L)
+        fn = _named(head.make_fn(B, L), f"{head.name}_generate_b{B}_l{L}")
         ops = operands if operands is not None else head.runtime_operands()
         args = head.make_batch([head.dummy_request()], B, L)
         compiled = jax.jit(fn).lower(

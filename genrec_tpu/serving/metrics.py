@@ -2,7 +2,7 @@
 
 Day-one observability for the engine (the ISSUE's explicit requirement):
 per-request queue-wait / compute / total latency histograms with
-p50/p95/p99, lifetime + recent-window QPS, per-(head, batch, history)
+p50/p95/p99, lifetime QPS, per-(head, batch, history)
 bucket-hit counts, and the recompilation counter that
 scripts/check_serving_hlo.py asserts stays ZERO in steady state.
 
@@ -96,6 +96,19 @@ class ServingMetrics:
         self.evictions = 0
         self.oom_deferred_admits = 0
         self.decode_steps = 0
+        # Useful over attempted, counted where the work happens: slots a
+        # decode step was compiled for (sum of S) against slots that held
+        # a request (sum of live), the KV tokens those live slots attended
+        # over, and the steps taken at each slot rung; real prefill rows
+        # and history positions against the (B, L) bucket they ran in.
+        self.decode_slot_steps = 0
+        self.decode_live_slot_steps = 0
+        self.decode_kv_tokens = 0
+        self.decode_steps_by_slots: collections.Counter = collections.Counter()
+        self.prefill_rows = 0
+        self.prefill_row_slots = 0
+        self.prefill_tokens = 0
+        self.prefill_token_slots = 0
         self.rejected_by_head: collections.Counter = collections.Counter()
         # Per-head submit/deferral attribution (the SLO monitor's rate
         # denominators/numerators — engine totals would let one head's
@@ -139,7 +152,6 @@ class ServingMetrics:
         # overload is recoverable and per-head attributed.
         self.overload_rejected = 0
         self.overload_by_head: collections.Counter = collections.Counter()
-        self._recent = collections.deque(maxlen=recent_window)
         # PER-HEAD rings of (t, total_s) samples for SLIDING-WINDOW
         # percentiles — the SLO monitor evaluates p99 over its window,
         # not over the lifetime histogram (which can never recover from
@@ -213,9 +225,18 @@ class ServingMetrics:
             if head is not None:
                 self.oom_deferred_by_head[head] += n
 
-    def record_decode_step(self) -> None:
+    def record_decode_step(self, slots: int = 0, live: int = 0,
+                           kv_tokens: int = 0) -> None:
+        """One decode executable invocation at slot rung ``slots`` with
+        ``live`` of them resident, attending over ``kv_tokens`` KV tokens
+        in all."""
         with self._lock:
             self.decode_steps += 1
+            self.decode_slot_steps += slots
+            self.decode_live_slot_steps += live
+            self.decode_kv_tokens += kv_tokens
+            if slots:
+                self.decode_steps_by_slots[slots] += 1
 
     def record_spec(self, head: str, drafted: int, accept_lens) -> None:
         """One speculative tree-verify invocation: ``drafted`` speculated
@@ -287,10 +308,20 @@ class ServingMetrics:
         with self._lock:
             self.catalog_swaps += 1
 
-    def record_batch(self, head: str, bucket: tuple[int, int]) -> None:
+    def record_batch(self, head: str, bucket: tuple[int, int],
+                     rows: int | None = None, tokens: int = 0) -> None:
+        """One bucketed executable call. A paged prefill also gives its
+        real ``rows`` and ``tokens`` (history positions in the ladder's
+        own unit, `head.natural_len`), counted against the bucket's
+        B and B x L."""
         with self._lock:
             self.batches += 1
             self.bucket_hits[(head, *bucket)] += 1
+            if rows is not None:
+                self.prefill_rows += rows
+                self.prefill_row_slots += bucket[0]
+                self.prefill_tokens += tokens
+                self.prefill_token_slots += bucket[0] * bucket[1]
 
     def record_response(self, queue_wait: float, compute: float, total: float,
                         head: str | None = None) -> None:
@@ -300,7 +331,6 @@ class ServingMetrics:
             self.compute.record(compute)
             self.total.record(total)
             self.completed += 1
-            self._recent.append(now)
             ring = self._recent_lat.get(head)
             if ring is None:
                 ring = self._recent_lat[head] = collections.deque(
@@ -365,14 +395,6 @@ class ServingMetrics:
             dt = time.monotonic() - self._started
             return self.completed / dt if dt > 0 else 0.0
 
-    def recent_qps(self) -> float:
-        """QPS over the recent completion window (steady-state view)."""
-        with self._lock:
-            if len(self._recent) < 2:
-                return 0.0
-            dt = self._recent[-1] - self._recent[0]
-            return (len(self._recent) - 1) / dt if dt > 0 else 0.0
-
     def snapshot(self) -> dict:
         with self._lock:
             bucket_hits = {
@@ -394,8 +416,18 @@ class ServingMetrics:
                 evictions=self.evictions,
                 oom_deferred_admits=self.oom_deferred_admits,
                 decode_steps=self.decode_steps,
+                decode_slot_steps=self.decode_slot_steps,
+                decode_live_slot_steps=self.decode_live_slot_steps,
+                decode_kv_tokens=self.decode_kv_tokens,
+                prefill_rows=self.prefill_rows,
+                prefill_row_slots=self.prefill_row_slots,
+                prefill_tokens=self.prefill_tokens,
+                prefill_token_slots=self.prefill_token_slots,
                 overload_rejected=self.overload_rejected,
             )
+            decode_steps_by_slots = {
+                f"s{s}": n for s, n in sorted(self.decode_steps_by_slots.items())
+            }
             rejected_by_head = dict(sorted(self.rejected_by_head.items()))
             submitted_by_head = dict(sorted(self.submitted_by_head.items()))
             overload_by_head = dict(sorted(self.overload_by_head.items()))
@@ -440,11 +472,11 @@ class ServingMetrics:
         return {
             **counts,
             "qps": round(self.qps(), 3),
-            "recent_qps": round(self.recent_qps(), 3),
             "queue_wait_ms": self.queue_wait.summary(),
             "compute_ms": self.compute.summary(),
             "total_ms": self.total.summary(),
             "bucket_hits": bucket_hits,
+            "decode_steps_by_slots": decode_steps_by_slots,
             "rejected_by_head": rejected_by_head,
             "submitted_by_head": submitted_by_head,
             "overload_by_head": overload_by_head,

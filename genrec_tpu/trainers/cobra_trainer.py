@@ -216,7 +216,8 @@ def train(
             "codebook_entropy": out.codebook_entropy,
         }
 
-    step_fn = jit_train_step(make_train_step(loss_fn, optimizer, clip_norm=1.0))
+    step_fn = jit_train_step(make_train_step(
+        loss_fn, optimizer, clip_norm=1.0, name="cobra_train_step"))
     state = replicate(mesh, TrainState.create(params, optimizer, state_rng))
     # Reference eval: n_candidates=10 of n_beam=20 (cobra_trainer.py:433-435);
     # clamped so small-beam debug runs stay valid.

@@ -550,7 +550,8 @@ def train(
         trainable = params
         params_of = lambda tp: tp
 
-    step_fn = jit_train_step(make_train_step(loss_fn, optimizer, clip_norm=1.0))
+    step_fn = jit_train_step(make_train_step(
+        loss_fn, optimizer, clip_norm=1.0, name="lcrec_train_step"))
     from genrec_tpu.parallel.shardings import make_place_state, moe_rules, qwen_rules
 
     rules = (

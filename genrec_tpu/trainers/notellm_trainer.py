@@ -155,7 +155,8 @@ def train(
         )
         return out.loss, {"cl_loss": out.cl_loss}
 
-    step_fn = jit_train_step(make_train_step(loss_fn, optimizer, clip_norm=1.0))
+    step_fn = jit_train_step(make_train_step(
+        loss_fn, optimizer, clip_norm=1.0, name="notellm_train_step"))
     from genrec_tpu.parallel import replicate
 
     state = replicate(mesh, TrainState.create(params, optimizer, state_rng))

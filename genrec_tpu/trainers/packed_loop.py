@@ -77,6 +77,14 @@ from genrec_tpu.obs.memory import device_memory_stats
 from genrec_tpu.obs.spans import NULL_TRACER
 
 
+def _peak_device_bytes() -> int:
+    """What the fullest moment held on the device: live buffers plus what
+    compiled programs reserve for their temporaries (0 where the backend
+    keeps no allocator counters, as on the CPU)."""
+    mem = device_memory_stats()
+    return mem.get("peak_bytes_in_use", 0) + mem.get("peak_bytes_reserved", 0)
+
+
 @dataclasses.dataclass
 class EpochResult:
     state: Any
@@ -295,8 +303,7 @@ class PackedTrainLoop:
         self.prof.close()
         run = self.goodput.run_report()
         if run["wall_s"] > 0 and self._steps_run:
-            mem = device_memory_stats()
-            peak = mem.get("peak_bytes_in_use")
+            peak = _peak_device_bytes()
             self.logger.info(
                 f"run goodput {run['goodput_pct']:.1f}% over "
                 f"{run['wall_s']:.1f}s wall (see goodput/* metrics)"
@@ -374,21 +381,37 @@ class PackedTrainLoop:
             ),
             self.mesh,
         ))
+        # Host phases of the loop, one span each on the epoch's trace, on
+        # `time.monotonic()` like every span of the program. A step's
+        # `train.host_tail` ends where the next wait for data begins, so
+        # it is recorded then (or as the loop is left).
+        trace_id = f"train-e{epoch}"
+        tail = None  # (start, step) of a host tail not yet recorded
+
+        def close_tail(t_end: float) -> None:
+            nonlocal tail
+            if tail is not None and self.tracer.enabled:
+                self.tracer.record_span("train.host_tail", trace_id, tail[0],
+                                        t_end, step=tail[1])
+            tail = None
+
         while True:
             # Goodput: time blocked on the input pipeline (data_wait) is
             # measured apart from the step section, whose residual after
             # compile/skipped attribution is the compute bucket.
-            t_wait = time.perf_counter()
+            t_wait = time.monotonic()
+            close_tail(t_wait)
             try:
                 sharded, _ = next(batches)
             except StopIteration:
                 break
-            self.goodput.add("data_wait", time.perf_counter() - t_wait)
+            t_step = time.monotonic()
+            self.goodput.add("data_wait", t_step - t_wait)
             if max_steps is not None and global_step >= max_steps:
                 break
-            t_step = time.perf_counter()
             c_n0, c_s0 = self._compile_events.snapshot()
             state, m = step_fn(state, sharded)
+            t_dispatched = time.monotonic()
             c_n1, c_s1 = self._compile_events.snapshot()
             # Guard-skipped steps contribute 0 to the epoch mean — one
             # NaN batch must not turn the whole epoch summary NaN (NaN*0
@@ -405,7 +428,7 @@ class PackedTrainLoop:
             n_batches += 1
             consumed += 1
             global_step += 1
-            self.prof.tick(global_step)
+            self.prof.tick(global_step, tracer=self.tracer)
             if c_n1 > c_n0:
                 self._note_compile(c_n1 - c_n0, c_s1 - c_s0, global_step)
             if global_step % self.wandb_log_interval == 0:
@@ -421,20 +444,32 @@ class PackedTrainLoop:
             # step's device scalar, so this interval really holds device
             # compute. step_hook (rqvae's iteration-gated eval/save) and
             # the preemption poll land in `other`.
-            t_done = time.perf_counter()
+            t_done = time.monotonic()
             self.goodput.note_step(t_done - t_step,
                                    compile_seconds=c_s1 - c_s0)
             self._steps_run += 1
             self._flight.record("step", step=global_step, epoch=epoch)
             if self.tracer.enabled:
-                self.tracer.record_span(
-                    "train_step", f"train-e{epoch}", t_step, t_done,
-                    step=global_step,
-                )
+                # `train_step` first: a reader that names an idle gap by
+                # the last span committed over it gets the phase.
+                rec = self.tracer.record_span
+                rec("train_step", trace_id, t_step, t_done, step=global_step)
+                rec("train.data_wait", trace_id, t_wait, t_step,
+                    step=global_step)
+                rec("train.dispatch", trace_id, t_step, t_dispatched,
+                    step=global_step)
+                rec("train.sync", trace_id, t_dispatched, t_done,
+                    step=global_step)
+                if c_n1 > c_n0:
+                    rec("train.compile", trace_id, t_step, t_dispatched,
+                        step=global_step, n=c_n1 - c_n0,
+                        seconds=c_s1 - c_s0)
+            tail = (t_done, global_step)
             if self.step_hook is not None:
                 self.step_hook(state, epoch, consumed, global_step)
             chaos.maybe_kill(step=global_step)
             if self.fleet_preempted(global_step):
+                close_tail(time.monotonic())
                 self._preempt(state, epoch, consumed, global_step)
                 return EpochResult(state, global_step, True, n_batches)
         self.monitor.flush()
@@ -470,9 +505,9 @@ class PackedTrainLoop:
             # backend exposes allocator stats (TPU/GPU; CPU has none) —
             # the trainers' view of the same HBM lever the serving
             # ledger budgets (obs/memory.py).
-            mem = device_memory_stats()
-            if mem.get("peak_bytes_in_use"):
-                report["peak_device_bytes"] = mem["peak_bytes_in_use"]
+            peak = _peak_device_bytes()
+            if peak:
+                report["peak_device_bytes"] = peak
             log_goodput(self.logger, self.tracker, epoch, report)
             if jax.process_count() > 1:
                 # obs imports nothing upward (graftlint layering): the

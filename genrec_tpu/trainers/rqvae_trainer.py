@@ -206,7 +206,8 @@ def train(
             "p_unique_ids": out.p_unique_ids,
         }
 
-    step_fn = jit_train_step(make_train_step(loss_fn, optimizer, clip_norm=1.0))
+    step_fn = jit_train_step(make_train_step(
+        loss_fn, optimizer, clip_norm=1.0, name="rqvae_train_step"))
     state = replicate(mesh, TrainState.create(params, optimizer, state_rng))
 
     @jax.jit

@@ -211,7 +211,11 @@ def train(
             aux["real_tokens"] = jnp.sum(batch["segment_ids"] != 0).astype(jnp.float32)
         return loss, aux
 
-    step_fn = jit_train_step(make_train_step(loss_fn, optimizer, clip_norm=None))
+    step_fn = jit_train_step(make_train_step(
+        loss_fn, optimizer, clip_norm=None,
+        name="sasrec_train_step_packed" if pack_sequences
+        else "sasrec_train_step",
+    ))
     state = replicate(mesh, TrainState.create(params, optimizer, state_rng))
     # One jit cache for every eval call; packed training reads predictions
     # from the last valid slot of right-padded eval rows.

@@ -291,6 +291,8 @@ def train(
         make_train_step(
             loss_fn, optimizer,
             accum_steps=gradient_accumulate_every, clip_norm=1.0,
+            name="tiger_train_step_packed" if pack_sequences
+            else "tiger_train_step",
         )
     )
     from genrec_tpu.parallel.shardings import make_place_state, tiger_rules

@@ -17,9 +17,11 @@ ledger and SLO guard, CI-sized):
    with a NaN metric) round-trips through a STRICT JSON parser.
 3. The tracing-OFF hot path stays under the 2% overhead budget: the
    per-request instrumentation cost with a disabled tracer (measured by
-   microbenchmark x the per-request call count) must be <2% of the
-   measured per-request latency. bench.py's serve.obs section carries
-   the complementary tracing-ON closed-loop sweep.
+   microbenchmark x the per-request call count, plus what the batcher
+   lane's step-level sites and the always-on slot counters cost per
+   decode step, printed) must be <2% of the measured per-request
+   latency. bench.py's serve.obs section carries the complementary
+   tracing-ON closed-loop sweep.
 4. The memory ledger (obs/memory.py) accounts EVERY warmed executable
    of the engine in (1) plus its runtime operands, its per-head sums are
    internally consistent (total == operands + transient peak), and the
@@ -100,6 +102,28 @@ def check_span_tree(spans) -> list:
     return names
 
 
+def check_batcher_lane(lane, stats: dict) -> None:
+    """The batcher's own lane holds every phase of admission, prefill and
+    decode (flat spans, no root, grouped by `seq`), and the always-on slot
+    counters count (tests/test_obs_phases.py holds them equal to the
+    spans' attributes)."""
+    names = {s.name for s in lane}
+    need = {"admit.pop", "prefill.stage", "prefill.launch", "prefill.pull",
+            "prefill.retain", "decode.stage", "decode.launch", "decode.pull",
+            "decode.sweep"}
+    if not need <= names:
+        raise AssertionError(f"batcher lane lacks {sorted(need - names)}")
+    if any(s.parent_id is not None or "seq" not in s.attrs for s in lane):
+        raise AssertionError("batcher lane spans must be flat and carry seq")
+    if not 0 < stats["decode_live_slot_steps"] <= stats["decode_slot_steps"]:
+        raise AssertionError(
+            f"slot counters off: live {stats['decode_live_slot_steps']} of "
+            f"{stats['decode_slot_steps']} slot-steps")
+    log(f"batcher lane OK: {len(lane)} phase spans; "
+        f"{stats['decode_live_slot_steps']}/{stats['decode_slot_steps']} "
+        f"live/compiled slot-steps")
+
+
 def check_serve_trace(tmp: str) -> dict:
     """Paged TIGER engine with tracing on: full span tree + trace schema."""
     import jax
@@ -146,6 +170,7 @@ def check_serve_trace(tmp: str) -> dict:
         n_decode = sum(1 for s in spans
                        if s.name in ("decode_step", "tree_verify"))
         log(f"span tree OK: {names}, {n_decode} decode steps")
+        check_batcher_lane(tracer.spans("batcher/tiger"), eng.stats())
         memory = check_memory_ledger(eng)
     finally:
         eng.stop()
@@ -395,15 +420,41 @@ def check_disabled_overhead(mean_latency_s: float) -> dict:
     # mint + queue/admission/prefill + decode steps + finalize + root +
     # exemplar check, with margin.
     calls_per_request = 32
-    cost = per_call * calls_per_request
+    # The batcher lane's sites in `_PagedRunner.step` with the tracer off
+    # (serving/engine.py): two clock readings more than before (launch
+    # returned, sweep begins), the live-slot count and KV-token sum the
+    # always-on counters take, the `tracing` check and the batch loop's
+    # look at the empty phase buffer. A prefill adds as much again.
+    import numpy as np
+
+    seq_lens = np.arange(64, dtype=np.int32)
+    active_idx = np.arange(0, 64, 2)
+    phases: list = []
+    n_steps = 100_000
+    t0 = time.perf_counter()
+    for _ in range(n_steps):
+        time.monotonic()
+        time.monotonic()
+        live, kv_tokens = len(active_idx), int(seq_lens[active_idx].sum())
+        if NULL_TRACER.enabled:
+            phases.append((live, kv_tokens))
+        if phases:
+            phases.clear()
+    per_step = (time.perf_counter() - t0) / n_steps
+    # A request is charged every step it rides as if it rode alone:
+    # sem_id_dim decode steps and one prefill.
+    steps_per_request = 4
+    cost = per_call * calls_per_request + per_step * steps_per_request
     pct = 100.0 * cost / max(mean_latency_s, 1e-9)
     log(f"disabled-tracer cost: {per_call * 1e9:.0f}ns/site x "
-        f"{calls_per_request} sites = {cost * 1e6:.1f}us/request "
+        f"{calls_per_request} sites + {per_step * 1e6:.2f}us/decode step x "
+        f"{steps_per_request} steps = {cost * 1e6:.1f}us/request "
         f"({pct:.3f}% of {mean_latency_s * 1e3:.1f}ms mean latency)")
     if pct >= 2.0:
         raise AssertionError(
             f"tracing-off overhead {pct:.2f}% >= 2% budget")
     return {"disabled_ns_per_site": per_call * 1e9,
+            "disabled_us_per_decode_step": per_step * 1e6,
             "overhead_pct_of_request": pct}
 
 

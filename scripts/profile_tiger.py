@@ -52,6 +52,7 @@ def main():
     from genrec_tpu.core.harness import make_train_step
     from genrec_tpu.core.state import TrainState
     from genrec_tpu.models.tiger import Tiger
+    from genrec_tpu.obs.spans import SpanTracer
 
     from genrec_tpu.parallel.mesh import device_summary
 
@@ -95,7 +96,9 @@ def main():
             return out.loss, {}
 
         step = jax.jit(
-            make_train_step(loss_fn, optimizer, clip_norm=1.0), donate_argnums=0
+            make_train_step(loss_fn, optimizer, clip_norm=1.0,
+                            name="tiger_train_step"),
+            donate_argnums=0,
         )
         state = TrainState.create(params, optimizer, jax.random.key(1))
 
@@ -127,11 +130,21 @@ def main():
     # Trace the best configuration: 10 steps under the profiler.
     B, entry, state, batch, step = best
     os.makedirs(args.trace_dir, exist_ok=True)
+    # Host spans beside the device profile, on one clock: the tracer's
+    # anchor annotation is in the profile, its anchor span in the dump.
+    tracer = SpanTracer()
     jax.profiler.start_trace(args.trace_dir)
-    for _ in range(10):
+    tracer.profile_anchor()
+    for i in range(10):
+        t0 = time.monotonic()
         state, m = step(state, batch)
+        tracer.record_span("train.dispatch", "train-e0", t0, time.monotonic(),
+                           step=i)
+    t0 = time.monotonic()
     jax.block_until_ready(m["loss"])
+    tracer.record_span("train.sync", "train-e0", t0, time.monotonic())
     jax.profiler.stop_trace()
+    tracer.dump(os.path.join(args.trace_dir, "host_spans.json"))
     summary["trace_dir"] = args.trace_dir
     summary["best_batch"] = B
 

@@ -41,6 +41,12 @@ dump them beside the spans) are rendered as a per-component table —
 every event carries component/replica_id/worker_id stamps since the
 lineage PR, so a multi-replica ring reads attributably.
 
+Lanes (`batcher/<head>`, `train-e<n>`, `profile-<n>`: flat per-iteration
+phase spans of one thread's loop, docs/OBSERVABILITY.md "The batcher
+lane") show in the phase table like any span, but are not requests: they
+are counted apart from the traces and never enter the critical path, so
+they cannot read as unrooted.
+
 Exit codes: 0 ok, 1 unreadable/invalid trace file.
 """
 
@@ -50,6 +56,15 @@ import argparse
 import json
 import sys
 from collections import defaultdict
+
+
+#: Trace-id prefixes of lanes (genrec_tpu/obs/spans.LANE_PREFIXES; kept
+#: here too so this CLI needs nothing but the trace file).
+LANE_PREFIXES = ("batcher/", "train-e", "profile-")
+
+
+def is_lane(trace_id) -> bool:
+    return isinstance(trace_id, str) and trace_id.startswith(LANE_PREFIXES)
 
 
 def load_trace(path: str) -> dict:
@@ -70,7 +85,7 @@ def percentile(sorted_vals: list[float], q: float) -> float:
 
 def summarize(data: dict, phase: str | None = None) -> dict:
     by_name: dict[str, list[float]] = defaultdict(list)
-    traces = set()
+    traces, lanes = set(), set()
     accept_lens: list[int] = []
     for ev in data["traceEvents"]:
         if ev.get("ph") != "X":
@@ -82,7 +97,7 @@ def summarize(data: dict, phase: str | None = None) -> dict:
         args = ev.get("args") or {}
         tid = args.get("trace_id")
         if tid is not None:
-            traces.add(tid)
+            (lanes if is_lane(tid) else traces).add(tid)
         # Speculative decode: `accept` spans carry the per-slot accept
         # length (codes committed by that tree-verify invocation), so
         # the report shows the multi-token story beside the phase p99s.
@@ -113,6 +128,7 @@ def summarize(data: dict, phase: str | None = None) -> dict:
         }
     return {
         "n_traces": len(traces),
+        "n_lanes": len(lanes),
         "phases": phases,
         "exemplars": other.get("exemplars") or {},
         "goodput": other.get("goodput"),
@@ -121,7 +137,8 @@ def summarize(data: dict, phase: str | None = None) -> dict:
 
 
 def print_report(report: dict) -> None:
-    print(f"traces: {report['n_traces']}")
+    print(f"traces: {report['n_traces']}"
+          + (f" (+ {report['n_lanes']} lanes)" if report.get("n_lanes") else ""))
     if report["phases"]:
         w = max(len(n) for n in report["phases"])
         print(f"{'phase':<{w}}  {'count':>7} {'total':>10} {'p50':>8} "
@@ -174,7 +191,7 @@ def _trace_forest(data: dict) -> dict:
             continue
         args = ev.get("args") or {}
         tid = args.get("trace_id")
-        if tid is None or args.get("span_id") is None:
+        if tid is None or args.get("span_id") is None or is_lane(tid):
             continue
         t0 = float(ev["ts"]) / 1e3
         by_trace[tid].append({
