@@ -7,10 +7,19 @@ kv for self-attention (:72, 122-124), pre-norm blocks with optional
 cross-attention (:256-324), T5 relu FFN, RMS norms with fp32 statistics,
 additive attn-mask + boolean key-padding mask (-1e9 fill :143-151).
 
-TPU notes: all shapes static; softmax in fp32; the (H, Lq, Lk) bias grid is
-computed once per layer from integer buckets — for TIGER's tiny sequences
-XLA fuses it into the attention; longer-sequence models use the Pallas
-fused-bias attention kernel in genrec_tpu.kernels instead.
+TPU notes: all shapes static; softmax in fp32. Every self-attention,
+packed rows or not, adds ONE (1, H, Lq, Lk) bias grid per layer: 32
+buckets of SLOT distance gathered from `rel_bias` and broadcast over the
+batch, so the forward gather and the backward scatter touch H*Lq*Lk
+values (22k at TIGER's 61 slots), never batch times that; the batch axis
+of the score gradient is a dense row-sum. Packed encoder rows
+(`Tiger.forward_packed`) need no per-row grid: the packer lays a segment
+out contiguously, so within a segment relative distance IS slot distance,
+and cross-segment pairs are masked by the caller's additive `attn_mask`
+before the softmax. Do not index `rel_bias` with a tensor that has a
+batch axis: a per-row grid costs a gather and a scatter-add of
+rows*H*L*L values into 192 entries a layer, which was 89% of the packed
+step on a v5e (PERF.md section 6).
 
 Incremental decode (the KV-cached engine behind `tiger_generate`):
 beam-search generation keeps all decode tensors in (B, K, ...) layout —
@@ -30,10 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from genrec_tpu.models.layers import RMSNorm
-from genrec_tpu.ops.buckets import (
-    t5_bucket_grid_from_positions,
-    t5_relative_position_bucket,
-)
+from genrec_tpu.ops.buckets import t5_relative_position_bucket
 
 _NEG = -1e9
 
@@ -77,21 +83,6 @@ class T5Attention(nn.Module):
         idx = buckets[None] + head_offset  # (H, q, k)
         return self.rel_bias[idx, 0][None]  # (1, H, q, k)
 
-    def _position_bias_packed(self, positions):
-        """Per-batch bias grid from explicit per-token positions
-        ((B, L) int32, within-segment for packed rows) -> (B, H, L, L).
-        Cross-segment pairs get arbitrary buckets here; the caller masks
-        them before softmax so they never contribute."""
-        with jax.named_scope("position_bias"):
-            buckets = t5_bucket_grid_from_positions(
-                positions, self.num_relative_buckets, self.max_distance,
-                bidirectional=True,
-            )  # (B, L, L)
-            head_offset = (jnp.arange(self.n_heads)[:, None, None]
-                           * self.num_relative_buckets)
-            idx = buckets[:, None] + head_offset[None]  # (B, H, L, L)
-            return self.rel_bias[idx, 0]
-
     def __call__(
         self,
         query,
@@ -100,7 +91,6 @@ class T5Attention(nn.Module):
         attn_mask=None,
         key_padding_mask=None,
         deterministic: bool = True,
-        positions=None,
     ):
         B, Lq, _ = query.shape
         H, hd = self.n_heads, self.d_model // self.n_heads
@@ -118,10 +108,7 @@ class T5Attention(nn.Module):
         scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * (hd**-0.5)
         scores = scores.astype(jnp.float32)
         if self.has_relative_bias and not self.is_cross_attention:
-            if positions is not None:
-                scores = scores + self._position_bias_packed(positions)
-            else:
-                scores = scores + self._position_bias(Lq, Lk)
+            scores = scores + self._position_bias(Lq, Lk)
         if key_padding_mask is not None:  # True = padding
             scores = jnp.where(key_padding_mask[:, None, None, :], _NEG, scores)
         if attn_mask is not None:  # additive, (Lq, Lk) or broadcastable
@@ -345,14 +332,12 @@ class TransformerBlock(nn.Module):
         key_padding_mask=None,
         memory_key_padding_mask=None,
         deterministic: bool = True,
-        positions=None,
     ):
         h = self.self_attn(
             self.norm1(x),
             attn_mask=attn_mask,
             key_padding_mask=key_padding_mask,
             deterministic=deterministic,
-            positions=positions,
         )
         x = x + self.drop1(h, deterministic=deterministic)
         if self.cross_attn and context is not None:
@@ -428,12 +413,11 @@ class TransformerEncoder(nn.Module):
             for i in range(self.depth)
         ]
 
-    def __call__(self, src, attn_mask=None, key_padding_mask=None, deterministic=True,
-                 positions=None):
+    def __call__(self, src, attn_mask=None, key_padding_mask=None, deterministic=True):
         for layer in self.layers:
             src = layer(
                 src, attn_mask=attn_mask, key_padding_mask=key_padding_mask,
-                deterministic=deterministic, positions=positions,
+                deterministic=deterministic,
             )
         return src
 

@@ -203,11 +203,21 @@ class Tiger(nn.Module):
         segment starts with its user token (``user_mask`` marks the slot,
         ``user_token_ids`` carries the hashed id there), followed by the
         flattened sem-id history. Encoder self-attention is restricted to
-        same-segment pairs and the T5 relative bias reads WITHIN-SEGMENT
-        positions, so each segment's encoder output equals the unpacked
-        forward's exactly. Decoders stay per example — (R*S, D+1) rows
-        cross-attending into their own segment of the packed memory via a
-        per-example memory mask.
+        same-segment pairs, and every layer adds the ONE (1, H, L, L)
+        relative-bias grid of slot distances that the unpacked forward
+        builds, broadcast over rows. That is exact because of the packer's
+        contract (`data.batching.pack_examples`): a segment's slots are one
+        contiguous run numbered ``arange(n)``, so for a same-segment pair
+        relative distance is slot distance, ``positions[k] - positions[q]
+        == k - q``, and a T5 bias reads nothing but that difference;
+        cross-segment pairs are masked before the softmax whatever bias
+        they got. So each segment's encoder output equals the unpacked
+        forward's exactly. ``positions`` is NOT read: it stays in the
+        signature for the callers that pass it by position (the trainer,
+        the benchmark's adapter). Rows laid out any other way (a segment
+        split or interleaved) would need the per-row bias back. Decoders
+        stay per example — (R*S, D+1) rows cross-attending into their own
+        segment of the packed memory via a per-example memory mask.
 
         Shapes: token operands (R, L); ``target_ids`` (R, S, D);
         ``segment_valid`` (R, S) with S = max segments per row. Loss is the
@@ -227,7 +237,7 @@ class Tiger(nn.Module):
         with jax.named_scope("encoder"):
             memory = self.transformer.encoder(
                 enc, attn_mask=seg_mask, key_padding_mask=pad,
-                deterministic=deterministic, positions=positions,
+                deterministic=deterministic,
             )
 
         _, S, D = target_ids.shape

@@ -51,23 +51,6 @@ def t5_relative_position_bucket(
     return ret
 
 
-def t5_bucket_grid_from_positions(
-    positions: jax.Array,
-    num_buckets: int = 32,
-    max_distance: int = 128,
-    bidirectional: bool = True,
-) -> jax.Array:
-    """Bucket grid from PER-TOKEN positions: ``(..., L)`` int positions ->
-    ``(..., L, L)`` buckets of ``key_pos - query_pos``.
-
-    The packed-sequence path feeds within-segment positions here, so a
-    segment's relative distances match the unpacked layout regardless of
-    where the segment landed in its packed row (cross-segment pairs are
-    masked by the caller, so their buckets are irrelevant)."""
-    rel = positions[..., None, :] - positions[..., :, None]
-    return t5_relative_position_bucket(rel, num_buckets, max_distance, bidirectional)
-
-
 def hstu_position_bucket(
     relative_position: jax.Array,
     num_buckets: int = 32,

@@ -91,8 +91,9 @@ def train(
     tensor_parallel=1,
     # First-fit-decreasing sequence packing of the ENCODER stream: several
     # (user token + history) examples share one row with segment-restricted
-    # attention and within-segment T5 relative positions; decoders stay per
-    # example, cross-attending into their own segment of the packed memory.
+    # attention (segments are contiguous, so the T5 relative bias of slot
+    # distance is each segment's own); decoders stay per example,
+    # cross-attending into their own segment of the packed memory.
     # False restores the original one-example-per-row layout exactly.
     pack_sequences=True,
     # Decoder rows are sized rows x MAX-segments-per-row, so one dense row

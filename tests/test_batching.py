@@ -141,6 +141,33 @@ def test_pack_examples_layout_invariants():
         assert packed["input_ids"][r][row == 0].sum() == 0
 
 
+@pytest.mark.parametrize("max_segments,seed", [
+    (None, None), (4, None), (4, (7, 3)), (2, 11),
+])
+def test_pack_examples_relative_distance_is_slot_distance(max_segments, seed):
+    """The contract TIGER's packed encoder rests on (one shared relative-
+    bias grid of SLOT distances for every row, `Tiger.forward_packed`):
+    a segment is ONE contiguous run of slots numbered arange(n), so for
+    every same-segment pair positions[k] - positions[q] == k - q — under
+    a segment cap and under the trainers' per-epoch repack seeds too."""
+    exs = _examples(n=60, seed=9, with_seg_key=True)
+    packed, rep = pack_examples(exs, 16, segment_keys=("target_ids",),
+                                max_segments=max_segments, seed=seed)
+    seg, pos = packed["segment_ids"], packed["positions"]
+    slot = np.arange(seg.shape[1])
+    for r in range(rep.n_rows):
+        for s in range(1, int(seg[r].max()) + 1):
+            run = np.flatnonzero(seg[r] == s)
+            # one contiguous run, and positions count up from 0 over it
+            np.testing.assert_array_equal(run, np.arange(run[0], run[0] + len(run)))
+            np.testing.assert_array_equal(pos[r, run], np.arange(len(run)))
+    same = (seg[:, :, None] == seg[:, None, :]) & (seg[:, :, None] != 0)
+    rel_pos = pos[:, None, :] - pos[:, :, None]      # (R, q, k): pos[k] - pos[q]
+    rel_slot = np.broadcast_to(slot[None, :] - slot[:, None], rel_pos.shape)
+    assert same.sum() > seg.shape[0] * 2
+    np.testing.assert_array_equal(rel_pos[same], rel_slot[same])
+
+
 def test_pack_examples_roundtrips_every_example():
     exs = _examples(seed=3)
     packed, rep = pack_examples(exs, 16)

@@ -7,6 +7,8 @@ TIGER encoder-decoder, and a query in segment 2 must never attend to
 segment 1 (leak checks perturb a neighbor segment and assert the victim's
 loss is bit-stable)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -249,6 +251,25 @@ def test_hstu_segment_boundary_leak(use_pallas):
 # -------------------------------------------------------------------- TIGER
 
 
+@functools.lru_cache(maxsize=None)
+def _tiger_small(n_layers=2):
+    """The tiny TIGER every test below shares (n_layers counts encoder +
+    decoder layers); built once per depth."""
+    from genrec_tpu.models.tiger import Tiger
+
+    model = Tiger(embedding_dim=16, attn_dim=32, dropout=0.0, num_heads=4,
+                  n_layers=n_layers, num_item_embeddings=16,
+                  num_user_embeddings=100, sem_id_dim=3)
+    D = 3
+    params = model.init(
+        jax.random.key(0), jnp.zeros((1,), jnp.int32),
+        jnp.zeros((1, 18), jnp.int32), jnp.zeros((1, 18), jnp.int32),
+        jnp.zeros((1, D), jnp.int32), jnp.zeros((1, D), jnp.int32),
+        jnp.ones((1, 18), jnp.int32),
+    )["params"]
+    return model, params
+
+
 def test_tiger_packed_loss_and_grads_match_unpacked():
     """forward_packed == the unpacked encoder-decoder on the same example
     set: batch loss and grads through the full model (encoder rel-bias
@@ -264,16 +285,8 @@ def test_tiger_packed_loss_and_grads_match_unpacked():
     assert rep.n_rows < rep.padded_rows
     arrays = data.train_arrays()
 
-    model = Tiger(embedding_dim=16, attn_dim=32, dropout=0.0, num_heads=4,
-                  n_layers=2, num_item_embeddings=16, num_user_embeddings=100,
-                  sem_id_dim=3)
+    model, params = _tiger_small()
     D = 3
-    params = model.init(
-        jax.random.key(0), jnp.zeros((1,), jnp.int32),
-        jnp.zeros((1, 18), jnp.int32), jnp.zeros((1, 18), jnp.int32),
-        jnp.zeros((1, D), jnp.int32), jnp.zeros((1, D), jnp.int32),
-        jnp.ones((1, 18), jnp.int32),
-    )["params"]
 
     B = arrays["user_ids"].shape[0]
     tt = jnp.broadcast_to(jnp.arange(D), (B, D))
@@ -323,16 +336,8 @@ def test_tiger_packed_per_example_losses_match_unpacked():
     packed, rep = pack_examples(exs, L, segment_keys=("target_ids",))
     arrays = data.train_arrays()
 
-    model = Tiger(embedding_dim=16, attn_dim=32, dropout=0.0, num_heads=4,
-                  n_layers=2, num_item_embeddings=16, num_user_embeddings=100,
-                  sem_id_dim=3)
+    model, params = _tiger_small()
     D = 3
-    params = model.init(
-        jax.random.key(0), jnp.zeros((1,), jnp.int32),
-        jnp.zeros((1, 18), jnp.int32), jnp.zeros((1, 18), jnp.int32),
-        jnp.zeros((1, D), jnp.int32), jnp.zeros((1, D), jnp.int32),
-        jnp.ones((1, 18), jnp.int32),
-    )["params"]
 
     # Unpacked per-example token-sum CE.
     B = arrays["user_ids"].shape[0]
@@ -387,16 +392,7 @@ def test_tiger_packed_accum_weighting_invariant_to_row_order():
     packed, rep = pack_examples(exs, L, segment_keys=("target_ids",))
     R = rep.n_rows - (rep.n_rows % 2)  # even row count for accum=2
 
-    model = Tiger(embedding_dim=16, attn_dim=32, dropout=0.0, num_heads=4,
-                  n_layers=2, num_item_embeddings=16, num_user_embeddings=100,
-                  sem_id_dim=3)
-    D = 3
-    params = model.init(
-        jax.random.key(0), jnp.zeros((1,), jnp.int32),
-        jnp.zeros((1, 18), jnp.int32), jnp.zeros((1, 18), jnp.int32),
-        jnp.zeros((1, D), jnp.int32), jnp.zeros((1, D), jnp.int32),
-        jnp.ones((1, 18), jnp.int32),
-    )["params"]
+    model, params = _tiger_small()
     opt = optax.sgd(0.1)
     expected_per_micro = (R // 2) * rep.n_examples / rep.n_rows
 
@@ -451,16 +447,7 @@ def test_tiger_encoder_segment_boundary_leak():
     e2, e3 = others[0], others[1]
     L = 1 + 6 * 3
 
-    model = Tiger(embedding_dim=16, attn_dim=32, dropout=0.0, num_heads=4,
-                  n_layers=2, num_item_embeddings=16, num_user_embeddings=100,
-                  sem_id_dim=3)
-    D = 3
-    params = model.init(
-        jax.random.key(0), jnp.zeros((1,), jnp.int32),
-        jnp.zeros((1, 18), jnp.int32), jnp.zeros((1, 18), jnp.int32),
-        jnp.zeros((1, D), jnp.int32), jnp.zeros((1, D), jnp.int32),
-        jnp.ones((1, 18), jnp.int32),
-    )["params"]
+    model, params = _tiger_small()
 
     def packed_loss_of_first(neighbor):
         packed, _ = pack_examples([e1, neighbor], L, segment_keys=("target_ids",))
@@ -485,3 +472,134 @@ def test_tiger_encoder_segment_boundary_leak():
         return float(pk.per_example_loss[0, s1])
 
     assert packed_loss_of_first(e2) == packed_loss_of_first(e3)
+
+
+# ------------------------------------- TIGER: the shared relative-bias grid
+
+
+def _tiger_packed_loss(model, packed):
+    from genrec_tpu.models.tiger import Tiger
+
+    b = {k: jnp.asarray(v) for k, v in packed.items()}
+
+    def loss(p):
+        return model.apply(
+            {"params": p}, b["item_input_ids"], b["token_type_ids"],
+            b["user_token_ids"], b["user_mask"], b["segment_ids"],
+            b["positions"], b["target_ids"], b["segment_valid"],
+            method=Tiger.forward_packed,
+        ).loss
+
+    return loss
+
+
+def _lookup_sizes(jaxpr):
+    """Element counts of every gather RESULT and every scatter UPDATE in a
+    jaxpr, sub-jaxprs (pjit, custom_vjp, remat, scan…) included."""
+    sizes = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "gather":
+            sizes.append(("gather", int(np.prod(eqn.outvars[0].aval.shape))))
+        elif name.startswith("scatter"):
+            # operands: (operand, indices, updates)
+            sizes.append((name, int(np.prod(eqn.invars[2].aval.shape))))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            sizes.extend(_lookup_sizes(sub))
+    return sizes
+
+
+def test_tiger_packed_grad_has_no_per_row_bias_lookup():
+    """The packed encoder's relative bias is ONE (1, H, L, L) grid a layer,
+    broadcast over rows. A per-row grid (rows x H x L x L indices into the
+    192-entry table) was 89% of the v5e step until PR 27: no gather result
+    and no scatter update of the lowered gradient may be that large, so
+    the lookup cannot come back unnoticed."""
+    R, H, L, S = 8, 4, 19, 4
+    model, params = _tiger_small(n_layers=4)  # 2 encoder + 2 decoder layers
+    z = lambda *shape: np.zeros(shape, np.int32)
+    packed = {
+        "item_input_ids": z(R, L), "token_type_ids": z(R, L),
+        "user_token_ids": z(R, L), "user_mask": z(R, L),
+        "segment_ids": z(R, L), "positions": z(R, L),
+        "target_ids": z(R, S, 3), "segment_valid": z(R, S),
+    }
+    jaxpr = jax.make_jaxpr(jax.grad(_tiger_packed_loss(model, packed)))(params)
+    sizes = _lookup_sizes(jaxpr.jaxpr)
+    per_row = R * H * L * L
+    too_big = [(n, s) for n, s in sizes if s >= per_row]
+    assert not too_big, f"per-row-sized lookups {too_big} (R*H*L*L = {per_row})"
+    # The shared grid IS there, forward and backward, once per encoder
+    # layer: the walk sees lookups, sub-jaxprs included.
+    assert sum(1 for n, s in sizes if n == "gather" and s == H * L * L) == 2
+    assert sum(1 for n, s in sizes if n != "gather" and s == H * L * L) == 2
+
+    # The detector bites on what it exists to catch: the per-row lookup
+    # the model used to make, written out here.
+    def per_row_bias_loss(rel_bias, positions):
+        from genrec_tpu.ops.buckets import t5_relative_position_bucket
+
+        rel = positions[:, None, :] - positions[:, :, None]
+        idx = (t5_relative_position_bucket(rel, 32, 128)[:, None]
+               + (jnp.arange(H) * 32)[None, :, None, None])
+        return rel_bias[idx, 0].sum()
+
+    old = jax.make_jaxpr(jax.grad(per_row_bias_loss))(
+        jnp.zeros((H * 32, 1)), jnp.zeros((R, L), jnp.int32))
+    old_sizes = _lookup_sizes(old.jaxpr)
+    assert ("gather", per_row) in old_sizes
+    assert any(n != "gather" and s == per_row for n, s in old_sizes)
+
+
+def test_tiger_packed_rel_bias_grads_match_at_the_segment_cap():
+    """Rows that hold the cap of FOUR segments, at offsets 0, 7, 11, 15 in
+    some rows and 0, 4, 8, 12 in others: the shared slot-distance grid
+    must give every encoder layer's `rel_bias` the unpacked gradient,
+    leaf by leaf, since a segment's offset in its row cancels out of
+    k - q."""
+    from genrec_tpu.data.tiger_seq import synthetic_tiger_data
+
+    data = synthetic_tiger_data(num_items=40, codebook_size=16, sem_id_dim=3,
+                                max_items=6, seed=4, num_users=24)
+    exs = data.train_examples()
+    arrays = data.train_arrays()
+    L = 1 + 6 * 3
+    long, short = ([i for i, e in enumerate(exs) if len(e["item_input_ids"]) == n]
+                   for n in (7, 4))
+    assert len(long) >= 2 and len(short) >= 14
+    # 7 + 4 + 4 + 4 fills a row; 4 + 4 + 4 + 4 leaves three pad slots.
+    groups = [[long[0]] + short[0:3], [long[1]] + short[3:6],
+              short[6:10], short[10:14]]
+    pick = [i for g in groups for i in g]
+    # One call a row: FFD over the whole set would pair the long examples.
+    rows = [pack_examples([exs[i] for i in g], L, segment_keys=("target_ids",),
+                          max_segments=4)[0] for g in groups]
+    packed = {k: np.concatenate([row[k] for row in rows]) for k in rows[0]}
+    assert packed["segment_ids"].shape == (len(groups), L)
+    assert (packed["segment_valid"] == 1).all()
+    starts = [[int(np.flatnonzero(row == s)[0]) for s in (1, 2, 3, 4)]
+              for row in packed["segment_ids"]]
+    assert starts == [[0, 7, 11, 15]] * 2 + [[0, 4, 8, 12]] * 2
+
+    model, params = _tiger_small(n_layers=4)  # 2 encoder layers
+    sub = {k: jnp.asarray(np.asarray(v)[pick]) for k, v in arrays.items()}
+    D = 3
+    tt = jnp.broadcast_to(jnp.arange(D), (len(pick), D))
+
+    def loss_unpacked(p):
+        return model.apply(
+            {"params": p}, sub["user_ids"], sub["item_input_ids"],
+            sub["token_type_ids"], sub["target_ids"], tt, sub["seq_mask"],
+        ).loss
+
+    lp, gp = jax.value_and_grad(loss_unpacked)(params)
+    lq, gq = jax.value_and_grad(_tiger_packed_loss(model, packed))(params)
+    assert float(lp) == pytest.approx(float(lq), abs=1e-5)
+    enc_p, enc_q = gp["transformer"]["encoder"], gq["transformer"]["encoder"]
+    assert sorted(enc_p) == ["layer_0", "layer_1"]
+    for layer in enc_p:
+        a = np.asarray(enc_p[layer]["self_attn"]["rel_bias"])
+        b = np.asarray(enc_q[layer]["self_attn"]["rel_bias"])
+        assert a.shape == (4 * 32, 1)
+        assert np.abs(a).max() > 1e-4, layer  # the leaf is trained at all
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-4, err_msg=layer)
