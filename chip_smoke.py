@@ -20,7 +20,13 @@ random weights from a seed, synthetic data from a seed:
 3. **kernels** — the `kernels.preflight` legs compiled (interpret=False):
    the paged kernel at this engine's shapes in fp32, int8 and the pool's
    own dtype; the other default-on kernels at their preflight shapes.
-4. **four_chip** — with four or more chips: the trainer data parallel
+4. **lcrec_keye** — `lcrec_trainer.train()` on
+   `config/lcrec/keye_vl2_30b_a3b.gin`: two optimizer steps of the cut
+   Keye-VL-2.0 language model at its published widths (indexer-selected
+   attention over 8,192-slot rows, dropless experts on the 16 held of
+   128), then the trainer's constrained-beam evaluate through the cache
+   that holds the indexer's keys.
+5. **four_chip** — with four or more chips: the trainer data parallel
    over four and at tensor_parallel=2, against a one-chip step of the
    same seed. On fewer chips the leg prints that it did not run. The
    engine with ``mesh=`` is left out of it, by name: see `LEFT_OUT`.
@@ -65,6 +71,20 @@ REHEARSE_BINDINGS = {
     "num_users": 24, "max_items": 6, "num_user_embeddings": 50,
 }
 BEAMS = 10
+#: The LCRec leg: the cut Keye-VL-2.0 language model at its published widths
+#: (config/lcrec/keye_vl2_30b_a3b.gin), two optimizer steps and the
+#: trainer's own constrained-beam evaluate through the cache.
+KEYE_GIN = os.path.join(REPO, "config", "lcrec", "keye_vl2_30b_a3b.gin")
+KEYE_BINDINGS = {"epochs": 1, "max_eval_samples": 2, "eval_every_epoch": 1}
+#: --rehearse only: every mechanism on, at a width one CPU core finishes.
+KEYE_REHEARSE_BINDINGS = {
+    "hidden_size": 32, "intermediate_size": 64, "num_heads": 4,
+    "num_kv_heads": 2, "head_dim": 8, "n_layers": 2, "sparse_topk": 16,
+    "indexer_heads": 2, "indexer_head_dim": 8, "sparse_chunk": 32,
+    "num_experts": 8, "num_experts_per_tok": 2, "moe_intermediate_size": 16,
+    "moe_experts_held": 4, "codebook_size": 8, "num_codebooks": 3,
+    "vocab_rows": 0, "max_text_len": 96, "amp": False,
+}
 #: Not run, and why. `ServingEngine(mesh=...)` at model axis 2 was tried on
 #: a four-chip host (PR 21): params and pools shard, then warmup() dies in
 #: `_compile_decode` — GSPMD meets the paged pallas_call and jax raises
@@ -290,6 +310,46 @@ def phase_train(entry: dict, save_dir: str, bindings: dict, on_tpu: bool):
     return model, data, params, cfg
 
 
+def phase_lcrec_keye(entry: dict, save_dir: str, rehearse: bool) -> None:
+    """`lcrec_trainer.train()` on the Keye gin the way a user runs it: two
+    optimizer steps of the cut (indexer-selected attention, dropless
+    experts on the 16 held of 128), then the trainer's evaluate, whose
+    constrained beam runs through the cache that holds the indexer's keys."""
+    import jax
+
+    from genrec_tpu import configlib
+    from genrec_tpu.configlib.parser import clear_macros
+    from genrec_tpu.trainers import lcrec_trainer
+
+    rows = max(2, jax.device_count())
+    bindings = {**KEYE_BINDINGS, "batch_size": rows, "eval_batch_size": rows,
+                "max_train_samples": 2 * rows, "save_dir_root": save_dir}
+    if rehearse:
+        bindings.update(KEYE_REHEARSE_BINDINGS)
+    configlib.clear_bindings()
+    clear_macros()
+    argv = [KEYE_GIN]
+    for key, value in bindings.items():
+        argv += ["--gin", f"train.{key}={value!r}"]
+    configlib.parse_config(argv)
+    cfg = configlib.get_bindings("train")
+    valid, test = lcrec_trainer.train()
+    losses = [m["train/loss"] for m in _read_metrics(save_dir)
+              if "global_step" in m and "train/loss" in m]
+    check(len(losses) == 2, losses)
+    check(all(l is not None and math.isfinite(l) for l in losses), losses)
+    for name, metrics in (("valid", valid), ("test", test)):
+        check("Recall@10" in metrics, (name, metrics))
+        check(all(0.0 <= v <= 1.0 for v in metrics.values()), (name, metrics))
+    entry.update(
+        steps=len(losses), loss_first=round(losses[0], 4),
+        loss_last=round(losses[-1], 4),
+        widths={k: cfg[k] for k in ("hidden_size", "n_layers", "num_experts",
+                                    "moe_experts_held", "sparse_topk",
+                                    "max_text_len", "vocab_rows")},
+    )
+
+
 def _check_responses(responses, item_sem_ids) -> None:
     import numpy as np
 
@@ -494,6 +554,9 @@ def main(argv=None) -> int:
                 entry, phases.report["serve"]["paged_config"],
                 interpret=args.rehearse,
             )
+        with phases.phase("lcrec_keye") as entry:
+            phase_lcrec_keye(entry, os.path.join(out, "lcrec_keye"),
+                             rehearse=args.rehearse)
         if device["count"] >= 4:
             with phases.phase("four_chip") as entry:
                 phase_four_chip(entry, out, bindings)

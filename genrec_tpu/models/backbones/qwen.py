@@ -19,11 +19,13 @@ compiled while-free loop per new token.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from genrec_tpu.models.layers import RMSNorm
 
@@ -51,12 +53,54 @@ class QwenConfig:
     # Static C keeps every shape jit-compilable; overflow tokens fall back
     # to the residual stream (their MLP delta is zero), the standard
     # Switch/GShard trade.
-    moe_capacity_factor: float = 2.0
+    # None = DROPLESS: every routed token-expert pair is computed, whatever
+    # the imbalance (sorted pairs, grouped products: QwenMoEMLP._dropless).
+    moe_capacity_factor: Optional[float] = 2.0
     router_aux_coef: float = 0.01
+    # Width of one expert (None: intermediate_size, the Qwen2-MoE layout).
+    moe_intermediate_size: Optional[int] = None
+    # Renormalise the chosen gates over the chosen experts.
+    norm_topk_prob: bool = True
+    # Which experts THIS chip holds (expert parallelism without the
+    # exchange): the router keeps `num_experts` outputs and its
+    # `num_experts_per_tok`; only experts [first, first + held) have
+    # weights here and add to the result. None = all of them. Dropless only.
+    moe_first_expert: int = 0
+    moe_experts_held: Optional[int] = None
+    # Attention layout. head_dim None = hidden_size // heads (Qwen2);
+    # Qwen3-class models state it (heads x head_dim != hidden_size).
+    head_dim: Optional[int] = None
+    attention_bias: bool = True  # q/k/v bias (Qwen2 has it, Qwen3 not)
+    qk_norm: bool = False  # per-head RMSNorm on q and k before RoPE
+    # Learned sparse attention (DeepSeek-Sparse-Attention indexer): >0 =
+    # each query attends the `sparse_topk` keys its indexer scores
+    # highest among the real keys at or before it (all of them where
+    # there are no more). `sparse_chunk` is the query tile in which
+    # scores and selection are computed (never block-level selection).
+    sparse_topk: int = 0
+    indexer_heads: int = 0
+    indexer_head_dim: int = 0
+    sparse_chunk: int = 512
+
+    def __post_init__(self):
+        if self.head_dim is None:
+            object.__setattr__(
+                self, "head_dim", self.hidden_size // self.num_attention_heads)
+        if self.moe_capacity_factor is not None and (
+                self.moe_experts_held is not None or self.moe_first_expert):
+            raise ValueError(
+                "a share of the experts (moe_experts_held) needs the dropless "
+                "path: set moe_capacity_factor=None")
+        if self.sparse_topk and not (self.indexer_heads and self.indexer_head_dim):
+            raise ValueError("sparse_topk > 0 needs indexer_heads and indexer_head_dim")
 
     @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def experts_here(self) -> int:
+        return self.num_experts if self.moe_experts_held is None else self.moe_experts_held
 
 
 def causal_pad_bias(L: int, attention_mask=None):
@@ -85,6 +129,153 @@ def _rope(x, positions, theta):
     return out.astype(x.dtype)
 
 
+# ---------------------------------------------------------------------------
+# Learned sparse attention: indexer scores -> exact top-k -> masked softmax
+# ---------------------------------------------------------------------------
+
+_NEG = -1e9  # never -inf: a fully masked (padding) query row must stay finite
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def indexer_scores(q_idx, w_idx, k_idx):
+    """I[b,t,n] = sum_j w[b,t,j] * relu(q_idx[b,t,j] . k_idx[b,n]), float32
+    at full precision (16 x 64 a token: small beside the attention, and a
+    selection computed in bf16 flips at near ties for nothing)."""
+    s = jnp.einsum("bthd,bnd->bhtn", q_idx, k_idx, precision=_HIGHEST)
+    return jnp.einsum("bhtn,bth->btn", jax.nn.relu(s), w_idx, precision=_HIGHEST)
+
+
+def select_topk(scores, allowed, k: int):
+    """Exactly the ``k`` allowed keys of largest score in each row (all
+    allowed keys where there are at most ``k``); ties go to the lowest
+    index. scores (..., N) float32, allowed (..., N) bool -> bool mask.
+
+    No sort and no (N,)-wide top_k: the k-th largest score is found by
+    bisection on the scores' order-preserving integer image, one bit a
+    pass (32 counting passes over the tile), and a tie at that score is
+    cut at an index found the same way."""
+    n = scores.shape[-1]
+    if n <= k:
+        return allowed
+    scores = scores.astype(jnp.float32)
+    scores = jnp.where(scores == 0, 0.0, scores)  # -0.0 and 0.0 are one score
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    # unsigned image; 0 is below every float's image, so a key that is not
+    # allowed ranks last
+    u = jnp.where(allowed, key.astype(jnp.uint32) ^ jnp.uint32(0x80000000),
+                  jnp.uint32(0))
+
+    def count(mask):
+        return jnp.sum(mask, axis=-1, keepdims=True, dtype=jnp.int32)
+
+    def value_bit(i, thr):
+        cand = thr | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        return jnp.where(count(u >= cand) >= k, cand, thr)
+
+    # thr: the largest value that at least k keys reach = the k-th largest
+    thr = jax.lax.fori_loop(
+        0, 32, value_bit, jnp.zeros(u.shape[:-1] + (1,), jnp.uint32))
+    above = u > thr
+    tie = u == thr
+    need = k - count(above)  # >= 1 ties to take, lowest index first
+    idx = jnp.arange(n, dtype=jnp.int32)
+    nbits = max(1, (n - 1).bit_length())
+
+    def index_bit(i, cut):
+        cand = cut | (jnp.int32(1) << (jnp.int32(nbits - 1) - i))
+        return jnp.where(count(tie & (idx < cand)) < need, cand, cut)
+
+    # cut: the largest index with fewer than `need` ties before it
+    cut = jax.lax.fori_loop(
+        0, nbits, index_bit, jnp.zeros(u.shape[:-1] + (1,), jnp.int32))
+    return (above | (tie & (idx <= cut))) & allowed
+
+
+#: Keys a softmax pass reads at once. Measured on a v5e (PERF.md section 6,
+#: PR 30): XLA's fused max/exp over (32, 512, n) float32 scores takes 1.2 ms
+#: at n = 4,096 and 1.5 ms at 5,120, then 26 ms at 5,632 and 56 ms at 8,192
+#: (the reduction stops fitting on chip). Longer key runs are merged chunk by
+#: chunk with a running max and sum, which is the same softmax.
+_KEY_CHUNK = 4096
+
+
+def _attend_row(q, k, v, sel):
+    """One row, one query tile against its keys. q (T, KV, rep, hd),
+    k/v (N, KV, hd), sel (T, N) bool -> (T, KV, rep, hd). Softmax in
+    float32 over the selected keys only, `_KEY_CHUNK` keys at a time."""
+    hd = q.shape[-1]
+    N = k.shape[0]
+    m = l = acc = None
+    for lo in range(0, N, _KEY_CHUNK):
+        hi = min(lo + _KEY_CHUNK, N)
+        s = jnp.einsum("tgrd,ngd->grtn", q, k[lo:hi],
+                       preferred_element_type=jnp.float32) * (hd ** -0.5)
+        s = jnp.where(sel[None, None, :, lo:hi], s, _NEG)
+        m_new = s.max(axis=-1) if m is None else jnp.maximum(m, s.max(axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        part = jnp.einsum("grtn,ngd->grtd", p.astype(v.dtype), v[lo:hi],
+                          preferred_element_type=jnp.float32)
+        if m is None:
+            l, acc = p.sum(axis=-1), part
+        else:
+            # a chunk with no selected key holds exp(0) = 1 everywhere; the
+            # first chunk that has one rescales it away (exp(-1e9 - m) = 0)
+            scale = jnp.exp(m - m_new)
+            l = l * scale + p.sum(axis=-1)
+            acc = acc * scale[..., None] + part
+        m = m_new
+    out = (acc / l[..., None]).astype(v.dtype)
+    return out.transpose(2, 0, 1, 3)  # (g, r, t, d) -> (t, g, r, d)
+
+
+def _attend_tile(q, k, v, sel):
+    """One query tile of every row, a row at a time, each rematerialised
+    in the backward pass: the live scores are (heads, T, N) of ONE row."""
+    row = jax.checkpoint(_attend_row)
+    return jax.lax.map(lambda a: row(*a), (q, k, v, sel))
+
+
+def sparse_attention(q, k, v, q_idx, w_idx, k_idx, key_valid, topk: int,
+                     chunk: int, q_slot=None, query_valid=None):
+    """Attention over each query's top-``topk`` indexer-selected keys,
+    exact, a query tile at a time: the largest temporary is the
+    (heads, chunk, N) scores of ONE row, never (rows, heads, L, L).
+
+    q (B, L, KV, rep, hd); k, v (B, N, KV, hd); q_idx (B, L, Hi, di),
+    w_idx (B, L, Hi), k_idx (B, N, di) float32; key_valid (B, N) bool.
+    ``q_slot`` None: the queries ARE keys 0..L-1 (training, N == L), and a
+    tile reads only the keys up to its own end. Otherwise (L,) traced
+    slots of the queries in a cache of N slots. Returns the output and
+    (sum over real queries of kept/available keys, real queries) for the
+    ``sparse_keys_kept_share`` counter (None without ``query_valid``)."""
+    B, L = q.shape[:2]
+    N = k.shape[1]
+    outs, kept, seen = [], 0.0, 0.0
+    for lo in range(0, L, chunk):
+        hi = min(lo + chunk, L)
+        n = min(hi, N) if q_slot is None else N
+        slots = jnp.arange(lo, hi) if q_slot is None else q_slot[lo:hi]
+        with jax.named_scope("indexer"):
+            scores = indexer_scores(q_idx[:, lo:hi], w_idx[:, lo:hi], k_idx[:, :n])
+        with jax.named_scope("sparse_select"):
+            allowed = (key_valid[:, None, :n]
+                       & (jnp.arange(n)[None, None, :] <= slots[None, :, None]))
+            sel = checkpoint_name(select_topk(scores, allowed, topk), "sparse_sel")
+            if query_valid is not None:
+                avail = jnp.sum(allowed, -1).astype(jnp.float32)
+                share = jnp.sum(sel, -1).astype(jnp.float32) / jnp.maximum(avail, 1.0)
+                real = query_valid[:, lo:hi].astype(jnp.float32)
+                kept = kept + jnp.sum(share * real)
+                seen = seen + jnp.sum(real)
+        with jax.named_scope("sparse_attend"):
+            outs.append(checkpoint_name(
+                _attend_tile(q[:, lo:hi], k[:, :n], v[:, :n], sel),
+                "sparse_attn_out"))
+    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+    return out, (None if query_valid is None else (kept, seen))
+
+
 class QwenAttention(nn.Module):
     cfg: QwenConfig
     dtype: jnp.dtype = jnp.float32
@@ -96,32 +287,77 @@ class QwenAttention(nn.Module):
     ring_axis: Optional[str] = None
     ring_size: int = 1
 
+    def _indexer(self, x):
+        """The indexer's queries, head weights and (one shared head of)
+        keys, float32, from the layer's normed input. The selection is
+        discrete: nothing here receives a gradient from the LM loss."""
+        cfg = self.cfg
+        Hi, di = cfg.indexer_heads, cfg.indexer_head_dim
+        x = jax.lax.stop_gradient(x).astype(jnp.float32)
+        dense = lambda n, name: nn.Dense(
+            n, use_bias=False, dtype=jnp.float32, precision=_HIGHEST, name=name)
+        q_idx = dense(Hi * di, "idx_q")(x).reshape(x.shape[:2] + (Hi, di))
+        k_idx = nn.LayerNorm(epsilon=1e-6, dtype=jnp.float32,
+                             name="idx_k_norm")(dense(di, "idx_k")(x))
+        w_idx = dense(Hi, "idx_w")(x)
+        return q_idx, w_idx, k_idx
+
     @nn.compact
-    def __call__(self, x, positions, attn_bias, cache=None, ring_kv_valid=None):
+    def __call__(self, x, positions, attn_bias, cache=None, ring_kv_valid=None,
+                 key_valid=None):
         cfg = self.cfg
         B, L, _ = x.shape
         H, KV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-        q = nn.Dense(H * hd, use_bias=True, dtype=self.dtype, name="q_proj")(x)
-        k = nn.Dense(KV * hd, use_bias=True, dtype=self.dtype, name="k_proj")(x)
-        v = nn.Dense(KV * hd, use_bias=True, dtype=self.dtype, name="v_proj")(x)
+        bias = cfg.attention_bias
+        sparse = cfg.sparse_topk > 0
+        q = nn.Dense(H * hd, use_bias=bias, dtype=self.dtype, name="q_proj")(x)
+        k = nn.Dense(KV * hd, use_bias=bias, dtype=self.dtype, name="k_proj")(x)
+        v = nn.Dense(KV * hd, use_bias=bias, dtype=self.dtype, name="v_proj")(x)
         q = q.reshape(B, L, H, hd)
         k = k.reshape(B, L, KV, hd)
         v = v.reshape(B, L, KV, hd)
+        if cfg.qk_norm:
+            q = RMSNorm(hd, cfg.rms_norm_eps, name="q_norm")(q)
+            k = RMSNorm(hd, cfg.rms_norm_eps, name="k_norm")(k)
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
+        if sparse:
+            if self.ring_axis is not None:
+                raise ValueError("sparse attention is not wired with ring attention")
+            q_idx, w_idx, k_idx = self._indexer(x)
+            q_idx = _rope(q_idx, positions, cfg.rope_theta)
+            k_idx = _rope(k_idx[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
 
         new_cache = None
+        q_slot = None
         if cache is not None:
             # cache: dict(k=(B, S, KV, hd), v=..., idx scalar): static-size
-            # decode cache updated at position idx.
+            # decode cache updated at position idx (sparse attention keeps
+            # the indexer's keys beside K and V).
             idx = cache["idx"]
             ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, idx, 0, 0))
             cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, idx, 0, 0))
             k, v = ck, cv
             new_cache = {"k": ck, "v": cv, "idx": idx + L}
+            if sparse:
+                k_idx = jax.lax.dynamic_update_slice(cache["ki"], k_idx, (0, idx, 0))
+                new_cache["ki"] = k_idx
+                q_slot = idx + jnp.arange(L)
 
         rep = H // KV  # GQA expansion factor
-        if self.ring_axis is not None and cache is None:
+        if sparse:
+            if key_valid is None:
+                key_valid = jnp.ones(k.shape[:2], bool)
+            key_valid = key_valid.astype(bool)
+            out, kept = sparse_attention(
+                q.reshape(B, L, KV, rep, hd), k, v, q_idx, w_idx, k_idx,
+                key_valid, cfg.sparse_topk, cfg.sparse_chunk, q_slot=q_slot,
+                query_valid=key_valid if cache is None else None)
+            out = out.reshape(B, L, H * hd)
+            if kept is not None:
+                self.sow("counters", "sparse_keys_kept_share",
+                         100.0 * kept[0] / jnp.maximum(kept[1], 1.0))
+        elif self.ring_axis is not None and cache is None:
             from genrec_tpu.parallel.ring_attention import ring_attention
 
             # K/V rotate UNREPEATED (kv_rep expands on the local tile), so
@@ -199,20 +435,24 @@ class QwenMoEMLP(nn.Module):
             cfg.num_experts,
             cfg.num_experts_per_tok,
             cfg.hidden_size,
-            cfg.intermediate_size,
+            cfg.expert_width,
         )
         B, L, _ = x.shape
         S = B * L
         xf = x.reshape(S, D)
 
-        # Router in fp32: tiny matmul, and bf16 logits visibly perturb
-        # top-k order at realistic expert counts.
-        logits = nn.Dense(E, use_bias=False, dtype=jnp.float32, name="router")(
-            xf.astype(jnp.float32)
-        )
-        probs = jax.nn.softmax(logits, axis=-1)  # (S, E)
-        gates, eidx = jax.lax.top_k(probs, K)  # (S, K)
-        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+        # Router in fp32 at full precision: tiny matmul, and bf16 logits
+        # (a TPU's default for a float32 product) visibly perturb top-k
+        # order at realistic expert counts.
+        with jax.named_scope("moe_route"):
+            logits = nn.Dense(E, use_bias=False, dtype=jnp.float32,
+                              precision=_HIGHEST, name="router")(
+                xf.astype(jnp.float32)
+            )
+            probs = jax.nn.softmax(logits, axis=-1)  # (S, E)
+            gates, eidx = jax.lax.top_k(probs, K)  # (S, K)
+            if cfg.norm_topk_prob:
+                gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
 
         # Padding tokens must not claim capacity slots (at tight capacity
         # factors they would evict REAL tokens' primary experts with
@@ -222,6 +462,24 @@ class QwenMoEMLP(nn.Module):
             if token_mask is None
             else token_mask.reshape(S).astype(jnp.int32)
         )
+
+        held = cfg.experts_here
+        w_gate = self.param("gate_proj", nn.initializers.lecun_normal(), (held, D, F))
+        w_up = self.param("up_proj", nn.initializers.lecun_normal(), (held, D, F))
+        w_down = self.param("down_proj", nn.initializers.lecun_normal(), (held, F, D))
+
+        # Switch load-balance loss over VALID tokens only: E * sum_e
+        # mean(router prob_e) * mean(fraction whose TOP choice is e);
+        # 1.0 when uniform. Over all E router outputs: it needs no expert.
+        vf = valid.astype(jnp.float32)
+        nv = jnp.maximum(vf.sum(), 1.0)
+        top1 = jax.nn.one_hot(eidx[:, 0], E, dtype=jnp.float32) * vf[:, None]
+        aux = E * jnp.sum((probs * vf[:, None]).sum(0) / nv * (top1.sum(0) / nv))
+        self.sow("losses", "router_aux", cfg.router_aux_coef * aux)
+
+        if cfg.moe_capacity_factor is None:
+            y = self._dropless(xf, gates, eidx, valid, w_gate, w_up, w_down)
+            return y.reshape(B, L, D)
 
         C = max(1, int(-(-S // E) * cfg.moe_capacity_factor))
         expert_mask = (
@@ -251,10 +509,6 @@ class QwenMoEMLP(nn.Module):
             dispatch = dispatch + d
             combine = combine + gates[:, kk].astype(x.dtype)[:, None, None] * d
 
-        w_gate = self.param("gate_proj", nn.initializers.lecun_normal(), (E, D, F))
-        w_up = self.param("up_proj", nn.initializers.lecun_normal(), (E, D, F))
-        w_down = self.param("down_proj", nn.initializers.lecun_normal(), (E, F, D))
-
         expert_in = jnp.einsum("sec,sd->ecd", dispatch, xf)  # all-to-all boundary
         if self.expert_axis is not None and self.expert_axis in _ctx_mesh_axes():
             from jax.lax import with_sharding_constraint
@@ -268,17 +522,97 @@ class QwenMoEMLP(nn.Module):
         ) * jnp.einsum("ecd,edf->ecf", expert_in, w_up.astype(self.dtype))
         expert_out = jnp.einsum("ecf,efd->ecd", h, w_down.astype(self.dtype))
         y = jnp.einsum("sec,ecd->sd", combine, expert_out)
-
-        # Switch load-balance loss over VALID tokens only: E * sum_e
-        # mean(router prob_e) * mean(fraction whose TOP choice is e);
-        # 1.0 when uniform.
-        vf = valid.astype(jnp.float32)
-        nv = jnp.maximum(vf.sum(), 1.0)
-        top1 = jax.nn.one_hot(eidx[:, 0], E, dtype=jnp.float32) * vf[:, None]
-        aux = E * jnp.sum((probs * vf[:, None]).sum(0) / nv * (top1.sum(0) / nv))
-        self.sow("losses", "router_aux", cfg.router_aux_coef * aux)
-
         return y.reshape(B, L, D)
+
+    def _dropless(self, xf, gates, eidx, valid, w_gate, w_up, w_down):
+        """Every routed (token, expert) pair whose expert is held here,
+        none dropped: sort the pairs by expert, one grouped product a
+        projection over the experts held, weighted sum back per token.
+        The pairs of absent experts sort last and are never computed:
+        what those experts would have added is left out (the chip's share
+        of an expert-parallel layer, without its exchange)."""
+        cfg = self.cfg
+        S, K = eidx.shape
+        held = cfg.experts_here
+        with jax.named_scope("moe_route"):
+            local = eidx - cfg.moe_first_expert
+            here = (local >= 0) & (local < held) & (valid[:, None] > 0)
+            group = jnp.where(here, local, held).reshape(S * K)
+            order = jnp.argsort(group, stable=True).astype(jnp.int32)
+            inv = jnp.zeros_like(order).at[order].set(
+                jnp.arange(S * K, dtype=jnp.int32), unique_indices=True)
+            sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+            n_here = sizes.sum()
+            n_valid = jnp.maximum(valid.sum(), 1)
+            self.sow("counters", "expert_load_max_over_mean",
+                     sizes.max() * held / jnp.maximum(n_here, 1).astype(jnp.float32))
+            self.sow("counters", "expert_picks_here_share",
+                     100.0 * n_here / (n_valid * K).astype(jnp.float32))
+        with jax.named_scope("moe_experts"):
+            xs = _dispatch_rows(xf, order, inv, n_here, K)  # (S*K, D) by expert
+            dot = lambda a, w: jax.lax.ragged_dot(a, w.astype(self.dtype), sizes)
+            h = nn.silu(dot(xs, w_gate)) * dot(xs, w_up)
+            ys = dot(h, w_down)
+        with jax.named_scope("moe_combine"):
+            ys = _live(ys, n_here)  # before the gates: unwritten rows may hold anything
+            ys = ys * gates.reshape(S * K)[order].astype(ys.dtype)[:, None]
+            return _combine_rows(ys, order, inv, K)
+
+
+# The rows of a token's K pairs, in expert order, and back: a pair of
+# gathers that are each other's transpose (``order`` is a permutation of the
+# S*K pairs and ``inv`` its inverse), so neither direction scatters. Rows
+# from ``n_here`` on belong to experts held elsewhere: a grouped product
+# neither reads nor writes them, so whatever is summed back per token is
+# zeroed there first (`_live`), and nothing else needs to be.
+
+
+def _live(rows, n_here):
+    keep = jnp.arange(rows.shape[0], dtype=jnp.int32) < n_here
+    return jnp.where(keep[:, None], rows, jnp.zeros((), rows.dtype))
+
+
+def _gather_pairs(xf, order, K):
+    return xf[order // K]
+
+
+def _sum_pairs(rows, inv, K):
+    P, D = rows.shape
+    return rows[inv].reshape(P // K, K, D).sum(axis=1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _dispatch_rows(xf, order, inv, n_here, K):
+    return _gather_pairs(xf, order, K)
+
+
+def _dispatch_fwd(xf, order, inv, n_here, K):
+    return _gather_pairs(xf, order, K), (inv, n_here)
+
+
+def _dispatch_bwd(K, res, g):
+    inv, n_here = res
+    return _sum_pairs(_live(g, n_here), inv, K), None, None, None
+
+
+_dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine_rows(rows, order, inv, K):
+    """``rows`` already zeroed from ``n_here`` on."""
+    return _sum_pairs(rows, inv, K)
+
+
+def _combine_fwd(rows, order, inv, K):
+    return _sum_pairs(rows, inv, K), order
+
+
+def _combine_bwd(K, order, g):
+    return _gather_pairs(g, order, K), None, None
+
+
+_combine_rows.defvjp(_combine_fwd, _combine_bwd)
 
 
 def collect_moe_aux(mutables) -> jnp.ndarray:
@@ -301,6 +635,28 @@ def collect_moe_aux(mutables) -> jnp.ndarray:
     return sum(leaves) if leaves else jnp.asarray(0.0)
 
 
+def collect_counters(mutables) -> dict:
+    """Mean over the layers of every value sown into the ``counters``
+    collection during an ``apply(..., mutable=["counters"])`` forward:
+    ``expert_load_max_over_mean``, ``expert_picks_here_share`` (%),
+    ``sparse_keys_kept_share`` (%). Empty for a dense model."""
+    from collections.abc import Mapping
+
+    found: dict = {}
+
+    def walk(tree):
+        for k, v in tree.items():
+            if isinstance(v, Mapping):
+                walk(v)
+            else:
+                found.setdefault(k, []).extend(
+                    v if isinstance(v, (tuple, list)) else [v])
+
+    if isinstance(mutables, Mapping):
+        walk(mutables.get("counters", {}))
+    return {k: sum(v) / len(v) for k, v in found.items()}
+
+
 class QwenBlock(nn.Module):
     cfg: QwenConfig
     dtype: jnp.dtype = jnp.float32
@@ -310,11 +666,17 @@ class QwenBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, attn_bias, cache=None, ring_kv_valid=None,
-                 token_mask=None):
+                 token_mask=None, key_valid=None):
+        """Mixer then MLP, each composed from the config: dense or
+        learned-sparse attention (``cfg.sparse_topk``); SwiGLU, capacity
+        MoE or dropless MoE (``cfg.num_experts``, ``moe_capacity_factor``).
+        ``key_valid`` (B, keys) marks the real keys for sparse attention,
+        which builds no additive bias (``attn_bias`` is unused there)."""
         h = RMSNorm(self.cfg.hidden_size, self.cfg.rms_norm_eps, name="input_layernorm")(x)
         h, new_cache = QwenAttention(
             self.cfg, self.dtype, self.ring_axis, self.ring_size, name="self_attn"
-        )(h.astype(self.dtype), positions, attn_bias, cache, ring_kv_valid)
+        )(h.astype(self.dtype), positions, attn_bias, cache, ring_kv_valid,
+          key_valid)
         x = x + h
         h = RMSNorm(self.cfg.hidden_size, self.cfg.rms_norm_eps, name="post_attention_layernorm")(x)
         if self.cfg.num_experts > 0:
@@ -347,7 +709,15 @@ class QwenLM(nn.Module):
             "embed_tokens", nn.initializers.normal(0.02),
             (self.cfg.vocab_size, self.cfg.hidden_size),
         )
-        block_cls = nn.remat(QwenBlock, static_argnums=()) if self.remat else QwenBlock
+        # Under sparse attention a rematerialised block keeps the selected
+        # sets (a byte a causal pair) and the attention's output: its
+        # backward pass then recomputes neither the indexer and the
+        # selection nor a forward pass of the attention it needs only
+        # tile by tile (each tile rematerialises its own scores).
+        policy = (jax.checkpoint_policies.save_only_these_names(
+            "sparse_sel", "sparse_attn_out") if self.cfg.sparse_topk > 0 else None)
+        block_cls = (nn.remat(QwenBlock, static_argnums=(), policy=policy)
+                     if self.remat else QwenBlock)
         self.blocks = [
             block_cls(
                 self.cfg, self.dtype, self.ring_axis, self.ring_size,
@@ -388,6 +758,11 @@ class QwenLM(nn.Module):
             ring_valid = (
                 None if attention_mask is None else attention_mask.astype(bool)
             )
+        elif self.cfg.sparse_topk > 0:
+            # Selection, causality and padding are masks of a query tile
+            # inside sparse_attention: no (L, L) bias either.
+            bias = None
+            ring_valid = None
         else:
             bias = causal_pad_bias(L, attention_mask)
             ring_valid = None
@@ -396,7 +771,7 @@ class QwenLM(nn.Module):
         for block in self.blocks:
             x, _ = block(
                 x, positions, bias, ring_kv_valid=ring_valid,
-                token_mask=attention_mask,
+                token_mask=attention_mask, key_valid=attention_mask,
             )
         h = self.norm(x).astype(self.dtype)
         logits = self._head(h) if compute_logits else None
@@ -407,15 +782,22 @@ class QwenLM(nn.Module):
     # ---- KV-cache decode ---------------------------------------------------
 
     def init_cache(self, batch_size: int, max_len: int):
+        """Per layer: K and V (and, under sparse attention, the indexer's
+        keys ``ki`` beside them: the selection runs over the cache's
+        slots), with ``idx`` the next slot to write. Every entry but
+        ``idx`` has the batch in front."""
         cfg = self.cfg
-        return [
-            {
-                "k": jnp.zeros((batch_size, max_len, cfg.num_key_value_heads, cfg.head_dim), self.dtype),
-                "v": jnp.zeros((batch_size, max_len, cfg.num_key_value_heads, cfg.head_dim), self.dtype),
-                "idx": jnp.asarray(0, jnp.int32),
-            }
-            for _ in range(cfg.num_hidden_layers)
-        ]
+        kv = (batch_size, max_len, cfg.num_key_value_heads, cfg.head_dim)
+
+        def layer():
+            c = {"k": jnp.zeros(kv, self.dtype), "v": jnp.zeros(kv, self.dtype),
+                 "idx": jnp.asarray(0, jnp.int32)}
+            if cfg.sparse_topk > 0:
+                c["ki"] = jnp.zeros(
+                    (batch_size, max_len, cfg.indexer_head_dim), jnp.float32)
+            return c
+
+        return [layer() for _ in range(cfg.num_hidden_layers)]
 
     def decode_step(self, input_ids, positions, caches, pad_mask):
         """Advance by input_ids.shape[1] tokens against a static cache.
@@ -441,7 +823,8 @@ class QwenLM(nn.Module):
         )
         new_caches = []
         for block, cache in zip(self.blocks, caches):
-            x, nc = block(x, positions, bias, cache, token_mask=token_mask)
+            x, nc = block(x, positions, bias, cache, token_mask=token_mask,
+                          key_valid=pad_mask)
             new_caches.append(nc)
         h = self.norm(x).astype(self.dtype)
         return self._head(h)[:, -1, :], new_caches

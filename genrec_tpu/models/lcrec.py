@@ -39,6 +39,7 @@ def extend_vocab(
     key,
     base: int | None = None,
     pad_to: int = 1,
+    min_rows: int = 0,
 ):
     """Append num_codebooks*codebook_size codebook tokens to the vocab.
 
@@ -55,7 +56,9 @@ def extend_vocab(
     ``pad_to`` rounds the final vocab up to a multiple (tensor-parallel
     degree), so the embedding/lm_head rows stay shardable; the zero pad
     rows are never tokenizer-reachable and generation masks them via
-    ``valid_vocab``. Returns (new_cfg, new_params, base).
+    ``valid_vocab``. ``min_rows`` is a floor on the final row count (one
+    chip's slice of a vocabulary-parallel head, held at its real size).
+    Returns (new_cfg, new_params, base).
     """
     import dataclasses
 
@@ -65,7 +68,7 @@ def extend_vocab(
     if base > cfg.vocab_size:
         raise ValueError(f"base {base} beyond model vocab {cfg.vocab_size}")
     need = base + n_new
-    total = max(cfg.vocab_size, need)
+    total = max(cfg.vocab_size, need, min_rows)
     total = -(-total // pad_to) * pad_to
     grow = max(0, total - cfg.vocab_size)
     new_cfg = dataclasses.replace(cfg, vocab_size=total)
@@ -87,7 +90,8 @@ def extend_vocab(
 
 
 def sft_loss(model: QwenLM, params, input_ids, attention_mask, labels,
-             valid_vocab: int | None = None, use_fused_ce: bool = False):
+             valid_vocab: int | None = None, use_fused_ce: bool = False,
+             with_metrics: bool = False):
     """Causal-LM CE with -100-masked labels (HF convention: logits at t
     predict labels at t+1; reference lcrec_trainer.py uses model(labels=...)).
     ``valid_vocab`` masks vocab pad rows out of the softmax (TP padding).
@@ -96,28 +100,28 @@ def sft_loss(model: QwenLM, params, input_ids, attention_mask, labels,
     (B, L, V) logits never materialize — at Qwen vocab scale (~150k) that
     is the single largest activation of the SFT step. Exact same loss;
     the valid_vocab mask becomes a row-slice of the head weights (a
-    never-computed logit == a -inf-masked one)."""
+    never-computed logit == a -inf-masked one).
+
+    ``with_metrics`` returns (loss, metrics): the step's ``real_tokens``
+    and the backbone's counters (`qwen.collect_counters`: expert load,
+    picks on the experts held, keys the sparse selection kept)."""
+    from genrec_tpu.models.backbones.qwen import collect_counters, collect_moe_aux
     from genrec_tpu.ops.losses import cross_entropy_with_ignore, mask_vocab_logits
 
     apply_kwargs = {}
     if use_fused_ce:
         apply_kwargs = dict(return_hidden=True, compute_logits=False)
-    if model.cfg.num_experts > 0:
-        # MoE backbone: collect the router load-balance aux loss sown by
-        # each QwenMoEMLP (dropped silently without mutable=).
-        from genrec_tpu.models.backbones.qwen import collect_moe_aux
-
-        out, mut = model.apply(
-            {"params": params}, input_ids, attention_mask=attention_mask,
-            mutable=["losses"], **apply_kwargs,
-        )
-        aux = collect_moe_aux(mut)
-    else:
-        out = model.apply(
-            {"params": params}, input_ids, attention_mask=attention_mask,
-            **apply_kwargs,
-        )
-        aux = 0.0
+    # The router's load-balance loss (sown by each QwenMoEMLP) and the
+    # counters are dropped silently without mutable=; a dense backbone sows
+    # neither.
+    out, mut = model.apply(
+        {"params": params}, input_ids, attention_mask=attention_mask,
+        mutable=["losses", "counters"], **apply_kwargs,
+    )
+    aux = collect_moe_aux(mut) if model.cfg.num_experts > 0 else 0.0
+    metrics = {"real_tokens": jnp.sum(attention_mask).astype(jnp.float32),
+               **jax.lax.stop_gradient(collect_counters(mut))}
+    done = lambda ce: (ce + aux, metrics) if with_metrics else ce + aux
 
     if use_fused_ce:
         from genrec_tpu.kernels.fused_ce import fused_ce_mean_loss
@@ -130,15 +134,15 @@ def sft_loss(model: QwenLM, params, input_ids, attention_mask, labels,
         ).astype(model.dtype)
         if valid_vocab is not None:
             w = w[:valid_vocab]
-        return fused_ce_mean_loss(
+        return done(fused_ce_mean_loss(
             h[:, :-1, :], w, labels[:, 1:], ignore_index=-100
-        ) + aux
+        ))
 
     logits = mask_vocab_logits(out, valid_vocab)
     per_tok, valid = cross_entropy_with_ignore(
         logits[:, :-1, :], labels[:, 1:], ignore_index=-100
     )
-    return per_tok.sum() / jnp.maximum(valid.sum(), 1) + aux
+    return done(per_tok.sum() / jnp.maximum(valid.sum(), 1))
 
 
 def make_tp_sharded_fused_sft_loss(model: QwenLM, mesh, valid_vocab: int):
@@ -383,14 +387,12 @@ def generate_topk_constrained(
         method=QwenLM.decode_step,
     )
 
-    def bcast_cache(c):
-        return {
-            "k": jnp.repeat(c["k"], W, axis=0),
-            "v": jnp.repeat(c["v"], W, axis=0),
-            "idx": c["idx"],
-        }
+    def map_cache(fn, c):
+        # every entry but the write cursor has the batch in front (K, V
+        # and, under sparse attention, the indexer's keys)
+        return {name: a if name == "idx" else fn(a) for name, a in c.items()}
 
-    caches = [bcast_cache(c) for c in caches]
+    caches = [map_cache(lambda a: jnp.repeat(a, W, axis=0), c) for c in caches]
     pad_bw = jnp.repeat(pad, W, axis=0)
     next_pos = positions[:, -1] + 1  # (B,)
 
@@ -451,10 +453,7 @@ def generate_topk_constrained(
                 )
             # Reorder caches to follow the selected parents.
             flat_parent = (parent + jnp.arange(B)[:, None] * W).reshape(B * W)
-            caches = [
-                {"k": cc["k"][flat_parent], "v": cc["v"][flat_parent], "idx": cc["idx"]}
-                for cc in caches
-            ]
+            caches = [map_cache(lambda a: a[flat_parent], cc) for cc in caches]
         if c < C - 1:
             # Feed the chosen tokens and advance the cache one step.
             tok_ids = (beam_tokens[:, :, c] + base_vocab + c * K).reshape(B * W, 1)

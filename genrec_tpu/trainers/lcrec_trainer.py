@@ -142,6 +142,37 @@ def evaluate(gen_fn, params, arrays, batch_size, mesh, num_codebooks):
     return m
 
 
+def backbone_config(*, n_layers, num_heads, num_kv_heads, moe_dropless=False,
+                    **widths) -> QwenConfig:
+    """The random-init backbone's `QwenConfig` (untied head) from
+    `train()`'s scalar arguments, which a gin file binds: three of them
+    renamed, ``moe_dropless`` for ``moe_capacity_factor=None``, the rest
+    (``vocab_size``, ``hidden_size``, ``head_dim``, ``sparse_topk``, ...)
+    under `QwenConfig`'s own names."""
+    if moe_dropless:
+        widths["moe_capacity_factor"] = None
+    return QwenConfig(
+        num_hidden_layers=n_layers, num_attention_heads=num_heads,
+        num_key_value_heads=num_kv_heads, tie_word_embeddings=False, **widths)
+
+
+def make_dense_sft_loss(model, live_vocab: int, use_fused_ce: bool):
+    """`train()`'s data-parallel loss: (params, batch) -> (loss, metrics),
+    metrics = the step's real tokens and the backbone's counters."""
+    return lambda p, batch: sft_loss(
+        model, p, batch["input_ids"], batch["attention_mask"],
+        batch["labels"], valid_vocab=live_vocab, use_fused_ce=use_fused_ce,
+        with_metrics=True,
+    )
+
+
+def make_sft_step(loss_and_metrics, optimizer):
+    """`train()`'s jitted step over a (params, batch) -> (loss, metrics)."""
+    return jit_train_step(make_train_step(
+        lambda p, batch, step_rng: loss_and_metrics(p, batch), optimizer,
+        clip_norm=1.0, name="lcrec_train_step"))
+
+
 @configlib.configurable
 def train(
     epochs=4,
@@ -189,6 +220,31 @@ def train(
     n_layers=2,
     num_heads=4,
     num_kv_heads=2,
+    # Attention layout of the random-init backbone (Qwen3-class models
+    # state head_dim, drop the q/k/v bias and norm q and k per head).
+    head_dim=None,
+    attention_bias=True,
+    qk_norm=False,
+    rope_theta=10000.0,
+    # Expert layer beyond the capacity path: one expert's width, the gate
+    # renormalisation, DROPLESS routing (every routed pair computed), and
+    # the share of the experts this chip holds of an expert-parallel
+    # deployment (router and experts-per-token as published; no exchange).
+    moe_intermediate_size=None,
+    norm_topk_prob=True,
+    moe_dropless=False,
+    moe_first_expert=0,
+    moe_experts_held=None,
+    router_aux_coef=0.01,
+    # >0: learned sparse attention (an indexer picks each query's top-k
+    # keys; backbones.qwen.sparse_attention).
+    sparse_topk=0,
+    indexer_heads=0,
+    indexer_head_dim=0,
+    sparse_chunk=512,
+    # >0: rows of the embedding and the head held here (a vocabulary slice
+    # plus the codebook tokens); rows past the live vocabulary are inert.
+    vocab_rows=0,
     dataset="synthetic",
     dataset_folder="dataset/amazon",
     split="beauty",
@@ -293,6 +349,23 @@ def train(
         # rather than silently run at 1/tp throughput.
         raise ValueError("tensor_parallel with use_lora is not wired; "
                          "run LoRA data-parallel (it is already memory-light)")
+    if sparse_topk > 0 and (sequence_parallel > 1 or pipeline_parallel > 1
+                            or tensor_parallel > 1):
+        # Ring attention and the pipeline stage body build their own
+        # attention; qwen_rules know nothing of the indexer's leaves.
+        raise ValueError("sparse_topk>0 (indexer-selected attention) is wired "
+                         "for data-parallel runs only, not sequence_parallel / "
+                         "pipeline_parallel / tensor_parallel")
+    if moe_experts_held is not None and (expert_parallel > 1 or not moe_dropless):
+        raise ValueError("moe_experts_held (one chip's share of the experts) "
+                         "needs moe_dropless=True and runs without an exchange: "
+                         "expert_parallel must stay 1")
+    if use_lora and num_experts > 0 and any(
+            t in ("gate_proj", "up_proj", "down_proj", "router")
+            for t in lora_targets):
+        raise ValueError("LoRA on the experts or the router is not wired "
+                         "(stacked (E, D, F) weights have no adapter); keep "
+                         "lora_targets to the attention projections")
     if tp_ep_combo:
         from genrec_tpu.parallel import make_mesh
 
@@ -325,6 +398,23 @@ def train(
     rng = jax.random.key(seed)
     init_rng, vocab_rng, state_rng = jax.random.split(rng, 3)
 
+    def random_init_config(vocab_size, max_pos):
+        return backbone_config(
+            vocab_size=vocab_size, max_position_embeddings=max_pos,
+            hidden_size=hidden_size, intermediate_size=intermediate_size,
+            n_layers=n_layers, num_heads=num_heads, num_kv_heads=num_kv_heads,
+            head_dim=head_dim, attention_bias=attention_bias, qk_norm=qk_norm,
+            rope_theta=rope_theta, num_experts=num_experts,
+            num_experts_per_tok=num_experts_per_tok,
+            moe_intermediate_size=moe_intermediate_size,
+            norm_topk_prob=norm_topk_prob, moe_dropless=moe_dropless,
+            moe_first_expert=moe_first_expert,
+            moe_experts_held=moe_experts_held,
+            router_aux_coef=router_aux_coef, sparse_topk=sparse_topk,
+            indexer_heads=indexer_heads, indexer_head_dim=indexer_head_dim,
+            sparse_chunk=sparse_chunk,
+        )
+
     # None = each data source's default mix.
     tw_extra = {} if task_weights is None else {"task_weights": tuple(task_weights)}
     if dataset == "synthetic":
@@ -335,14 +425,8 @@ def train(
         data.max_len = max_text_len
         # Backbone vocab covers words only; codebook tokens are appended by
         # extend_vocab below, exactly like the HF resize path.
-        cfg = QwenConfig(
-            vocab_size=tok.base_vocab, hidden_size=hidden_size,
-            intermediate_size=intermediate_size, num_hidden_layers=n_layers,
-            num_attention_heads=num_heads, num_key_value_heads=num_kv_heads,
-            max_position_embeddings=max_text_len + num_codebooks + 1,
-            rope_theta=10000.0, tie_word_embeddings=False,
-            num_experts=num_experts, num_experts_per_tok=num_experts_per_tok,
-        )
+        cfg = random_init_config(
+            tok.base_vocab, max_text_len + num_codebooks + 1)
         model0 = QwenLM(cfg, dtype=compute_dtype, remat=gradient_checkpointing,
                         expert_axis="expert" if expert_parallel > 1 else None)
         params = model0.init(init_rng, jnp.zeros((1, 4), jnp.int32))["params"]
@@ -411,15 +495,7 @@ def train(
         else:
             # Tokenizer-only dir (or none): random-init backbone at the
             # configured dims, vocab sized to the tokenizer.
-            cfg = QwenConfig(
-                vocab_size=tok.base_vocab, hidden_size=hidden_size,
-                intermediate_size=intermediate_size, num_hidden_layers=n_layers,
-                num_attention_heads=num_heads, num_key_value_heads=num_kv_heads,
-                max_position_embeddings=max_pos,
-                rope_theta=10000.0, tie_word_embeddings=False,
-                num_experts=num_experts,
-                num_experts_per_tok=num_experts_per_tok,
-            )
+            cfg = random_init_config(tok.base_vocab, max_pos)
         model0 = QwenLM(cfg, dtype=compute_dtype, remat=gradient_checkpointing,
                         expert_axis="expert" if expert_parallel > 1 else None)
         params = (
@@ -443,7 +519,7 @@ def train(
     cfg, params, base_vocab = extend_vocab(
         cfg, params, num_codebooks, codebook_size, vocab_rng,
         base=getattr(tok, "base_vocab", None),
-        pad_to=math.lcm(8, max(tensor_parallel, 1)),
+        pad_to=math.lcm(8, max(tensor_parallel, 1)), min_rows=vocab_rows,
     )
     # remat mirrors the reference's gradient_checkpointing_enable (lcrec.py:42-46).
     model = QwenLM(cfg, dtype=compute_dtype, remat=gradient_checkpointing,
@@ -484,26 +560,22 @@ def train(
                 f"max_text_len {max_text_len} must divide by "
                 f"sequence_parallel {sequence_parallel}"
             )
-        _, base_loss = make_sp_sft_loss(
+        _, sp_loss = make_sp_sft_loss(
             cfg, mesh, dtype=compute_dtype, remat=gradient_checkpointing,
             valid_vocab=live_vocab,
         )
+        base_loss = lambda p, batch: (sp_loss(p, batch), {})
     elif pipeline_parallel > 1:
         from genrec_tpu.models.pp_sft import make_pp_sft_loss
         from genrec_tpu.parallel.shardings import qwen_rules as _qr
 
-        base_loss = make_pp_sft_loss(
+        pp_loss = make_pp_sft_loss(
             cfg, mesh, n_micro=pp_microbatches, dtype=compute_dtype,
             remat=gradient_checkpointing, valid_vocab=live_vocab,
             tp_rules=_qr() if tp_pp_combo else None, log_fn=logger.info,
         )
+        base_loss = lambda p, batch: (pp_loss(p, batch), {})
     else:
-        def _dense_sft_loss(fused: bool):
-            return lambda p, batch: sft_loss(
-                model, p, batch["input_ids"], batch["attention_mask"],
-                batch["labels"], valid_vocab=live_vocab, use_fused_ce=fused,
-            )
-
         if tensor_parallel > 1:
             # Vocab-sharded head: the dense fused kernel cannot be
             # GSPMD-partitioned over the vocab dim, so fused CE routes
@@ -520,38 +592,36 @@ def train(
                     make_tp_sharded_fused_sft_loss,
                 )
 
-                base_loss = make_tp_sharded_fused_sft_loss(
+                tp_loss = make_tp_sharded_fused_sft_loss(
                     model, mesh, valid_vocab=live_vocab
                 )
+                base_loss = lambda p, batch: (tp_loss(p, batch), {})
             else:
-                base_loss = _dense_sft_loss(False)
+                base_loss = make_dense_sft_loss(model, live_vocab, False)
         else:
             if use_fused_ce == "auto":
                 from genrec_tpu.kernels.policy import auto_fused_ce
 
                 use_fused_ce = auto_fused_ce(tensor_parallel)
-            base_loss = _dense_sft_loss(bool(use_fused_ce))
+            base_loss = make_dense_sft_loss(model, live_vocab, bool(use_fused_ce))
 
     if use_lora:
         lora = lora_init(params, jax.random.fold_in(rng, 7), lora_rank, tuple(lora_targets))
         logger.info(f"LoRA: {lora_param_count(lora)} trainable params")
         base_params = params
 
-        def loss_fn(lp, batch, step_rng):
-            merged = lora_merge(base_params, lp, lora_alpha, lora_rank)
-            return base_loss(merged, batch), {}
+        def loss_fn(lp, batch):
+            return base_loss(
+                lora_merge(base_params, lp, lora_alpha, lora_rank), batch)
 
         trainable = lora
         params_of = lambda tp: lora_merge(base_params, tp, lora_alpha, lora_rank)
     else:
-        def loss_fn(p, batch, step_rng):
-            return base_loss(p, batch), {}
-
+        loss_fn = base_loss
         trainable = params
         params_of = lambda tp: tp
 
-    step_fn = jit_train_step(make_train_step(
-        loss_fn, optimizer, clip_norm=1.0, name="lcrec_train_step"))
+    step_fn = make_sft_step(loss_fn, optimizer)
     from genrec_tpu.parallel.shardings import make_place_state, moe_rules, qwen_rules
 
     rules = (

@@ -77,6 +77,15 @@ from genrec_tpu.obs.memory import device_memory_stats
 from genrec_tpu.obs.spans import NULL_TRACER
 
 
+#: Counters a model's step may hand out beside ``real_tokens`` (models/
+#: backbones/qwen.collect_counters). With a tracer on, the loop writes them
+#: as attributes of the step's ``train_step`` span at its log interval,
+#: where it already waits for the loss; with the tracer off they are never
+#: read.
+STEP_COUNTERS = ("expert_load_max_over_mean", "expert_picks_here_share",
+                 "sparse_keys_kept_share")
+
+
 def _peak_device_bytes() -> int:
     """What the fullest moment held on the device: live buffers plus what
     compiled programs reserve for their temporaries (0 where the backend
@@ -431,6 +440,7 @@ class PackedTrainLoop:
             self.prof.tick(global_step, tracer=self.tracer)
             if c_n1 > c_n0:
                 self._note_compile(c_n1 - c_n0, c_s1 - c_s0, global_step)
+            counters = {}
             if global_step % self.wandb_log_interval == 0:
                 self.tracker.log(
                     self.step_log(m, global_step)
@@ -438,6 +448,8 @@ class PackedTrainLoop:
                     else {"global_step": global_step,
                           "train/loss": float(m["loss"])}
                 )
+                if self.tracer.enabled:
+                    counters = {k: float(m[k]) for k in STEP_COUNTERS if k in m}
             # Deferred non-finite policy: checks the PREVIOUS step's flag.
             self.monitor.observe(global_step, epoch, m, sharded)
             # Step section closes here: observe() synced on the previous
@@ -453,7 +465,8 @@ class PackedTrainLoop:
                 # `train_step` first: a reader that names an idle gap by
                 # the last span committed over it gets the phase.
                 rec = self.tracer.record_span
-                rec("train_step", trace_id, t_step, t_done, step=global_step)
+                rec("train_step", trace_id, t_step, t_done, step=global_step,
+                    **counters)
                 rec("train.data_wait", trace_id, t_wait, t_step,
                     step=global_step)
                 rec("train.dispatch", trace_id, t_step, t_dispatched,
