@@ -16,7 +16,8 @@ random weights from a seed, synthetic data from a seed:
 2. **serve** — `ServingEngine([TigerGenerativeHead(...)], params,
    paged=True, ...)` on the params the trainer just saved: AOT warmup,
    requests of mixed history lengths with one repeated (a warm prefix
-   admit), drain, stop.
+   admit), then the compiled text of every paged executable read for a
+   whole-pool copy (there must be none), drain, stop.
 3. **kernels** — the `kernels.preflight` legs compiled (interpret=False):
    the paged kernel at this engine's shapes in fp32, int8 and the pool's
    own dtype; the other default-on kernels at their preflight shapes.
@@ -364,6 +365,41 @@ def _check_responses(responses, item_sem_ids) -> None:
         check((np.diff(scores) <= 1e-6).all(), scores)
 
 
+def _check_pool_stays_put(runner) -> dict:
+    """The page pool lives in ONE layout between launches: print what the
+    runtime gave a leaf, and fail if the compiled text of any executable
+    of the engine produces a pool-sized value by a copy, a transpose or
+    a convert (the in-place page scatter and the kernel's custom call
+    are what may touch one). The first check to fail if a later JAX or
+    libtpu changes its mind about the pool's layout."""
+    import jax
+
+    from genrec_tpu.analysis.ir import hlo_ops_of_size
+
+    leaf = jax.tree_util.tree_leaves(runner.pool.k_pools)[0]
+    print(f"chip_smoke: pool leaf {leaf.dtype}{list(leaf.shape)} "
+          f"format={leaf.format}", flush=True)
+    executables = {
+        **{f"prefill_b{b}_l{l}": e for (b, l), e in runner._prefill.items()},
+        **{f"decode_s{s}": e for s, e in runner._decode.items()},
+    }
+    check(executables, "the engine holds no paged executable")
+    # The rehearsal's CPU backend widens a bf16 scatter to float32 and
+    # back (a `convert` each way); the chip scatters bf16 in place. A
+    # `copy-done` is not a relayout: it ends the asynchronous prefetch
+    # into fast memory that the compiler gives a pool as small as the
+    # smoke's, in the layout the pool has.
+    moving = ("copy", "transpose") + (
+        ("convert",) if jax.default_backend() == "tpu" else ())
+    pool_ops = {}
+    for name, exe in executables.items():
+        ops = hlo_ops_of_size(exe.as_text(), math.prod(leaf.shape))
+        moved = [line for op, line in ops if op in moving]
+        check(not moved, f"{name} relays a whole pool", moved[:2])
+        pool_ops[name] = sorted({op for op, _ in ops})
+    return pool_ops
+
+
 def phase_serve(model, params, item_sem_ids, cfg: dict) -> dict:
     """Build, warm, query, drain and stop one paged engine."""
     import numpy as np
@@ -402,6 +438,7 @@ def phase_serve(model, params, item_sem_ids, cfg: dict) -> dict:
         _check_responses([*cold, warm], item_sem_ids)
         np.testing.assert_array_equal(warm.items, cold[1].items)
         np.testing.assert_array_equal(warm.scores, cold[1].scores)
+        pool_ops = _check_pool_stays_put(engine._runners[head.name])
     finally:
         stats = engine.stop()
     check(stats["completed"] == len(requests) + 1, stats["completed"])
@@ -416,6 +453,7 @@ def phase_serve(model, params, item_sem_ids, cfg: dict) -> dict:
         "answered": stats["completed"], "recompilations": 0,
         "warm_prefix_hits": prefix["hits"],
         "pool_pages_total": pool["pages_in_use"] + pool["pages_free"],
+        "pool_sized_ops": pool_ops,
         "paged_config": [paged_config.max_slots, BEAMS, model.num_heads,
                          model.attn_dim // model.num_heads,
                          paged_config.page_size, paged_config.pages_per_slot],

@@ -144,6 +144,30 @@ def hlo_constants(hlo: str) -> list[dict]:
     return out
 
 
+_INSTR_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(\w+)\[([\d,]*)\](?:\{[^}]*\})?\s+([\w\-]+)\("
+)
+
+
+def hlo_ops_of_size(hlo: str, n_elements: int) -> list[tuple[str, str]]:
+    """(opcode, line) of every HLO instruction whose RESULT is an array
+    of exactly ``n_elements`` — in the entry computation and in every
+    fused or called one, since the text lists them all. Over a serving
+    executable and the page pool's element count this is the relayout
+    check: a pool may come back out of a ``scatter`` (or the fusion or
+    loop the compiler wrapped it in), never out of a ``copy``,
+    ``transpose`` or ``convert``."""
+    out = []
+    for line in hlo.splitlines():
+        m = _INSTR_RE.match(line)
+        if not m:
+            continue
+        dims = [int(d) for d in m.group(2).split(",") if d]
+        if dims and math.prod(dims) == n_elements:
+            out.append((m.group(3), line.strip()))
+    return out
+
+
 # ---------------------------------------------------------------------------
 # jaxpr walking
 # ---------------------------------------------------------------------------
@@ -187,6 +211,31 @@ def host_ops_in_loops(jaxpr) -> list[dict]:
                 walk(sub, child_in_loop)
 
     walk(jaxpr, False)
+    return hits
+
+
+def primitives_of_size(jaxpr, n_elements: int) -> set[str]:
+    """Names of the primitives with an operand or a result of exactly
+    ``n_elements``. Call-like equations (pjit, scan, while, cond, custom
+    derivatives) are walked into, not reported; a ``pallas_call`` is a
+    leaf. What a traced program ASKS for of a value of that size, before
+    any compiler has chosen a layout for it."""
+    hits: set[str] = set()
+
+    def sized(v) -> bool:
+        shape = getattr(getattr(v, "aval", None), "shape", None)
+        return bool(shape) and math.prod(shape) == n_elements
+
+    def walk(jx):
+        for eqn in jx.eqns:
+            name = eqn.primitive.name
+            subs = [] if name == "pallas_call" else list(_subjaxprs(eqn.params))
+            for sub in subs:
+                walk(sub)
+            if not subs and any(map(sized, (*eqn.invars, *eqn.outvars))):
+                hits.add(name)
+
+    walk(jaxpr)
     return hits
 
 

@@ -48,7 +48,13 @@ from genrec_tpu.serving.types import ServingError
 #: ``k_scale{i}``/``v_scale{i}`` fp32 per-page-row scale planes beside
 #: the int8 page content (the 2-4x wire shrink the quantized pool buys
 #: travels the wire too). v2 payloads are refused typed.
-WIRE_VERSION = 3
+#: v4: the page arrays ``k{i}``/``v{i}`` are recorded
+#: ``(n_pages_used, page_size, n_heads * head_dim)``, the pool's own
+#: shape, where v3 recorded ``(..., n_heads, head_dim)``. The BYTES are
+#: the same row-major page rows; only the shape in each array's npy
+#: header changed, and a v3 frame would not fit a v4 pool's compiled
+#: scatter, so it is refused typed here instead.
+WIRE_VERSION = 4
 
 
 class DisaggError(ServingError):
@@ -136,7 +142,8 @@ def layout_of(head) -> tuple:
 def pack_handoff(handoff: KVHandoff, k_content, v_content) -> bytes:
     """Serialize one handoff + its page content to the pinned wire
     format. ``k_content``/``v_content`` are per-layer host arrays shaped
-    ``(n_pages_used, page_size, n_heads, head_dim)`` — exactly the pages
+    ``(n_pages_used, page_size, n_heads * head_dim)`` (the pool's own page
+    shape, head-major features, as gathered) — exactly the pages
     the run covers, no padding (the receiving side re-pads to its own
     fixed scatter shape). For an int8 handoff (``handoff.kv_dtype ==
     "int8"``) each layer entry is a ``(data, scale)`` pair — int8 page

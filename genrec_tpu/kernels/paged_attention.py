@@ -2,7 +2,7 @@
 arxiv 2604.15464).
 
 One decode step reads K/V straight from the page pool through the
-per-slot block table — the gathered (S, pages*page_size, H, hd)
+per-slot block table — the gathered (S, pages*page_size, H*hd)
 contiguous copy the pure-JAX fallback materializes (ops/paged.py) never
 exists in HBM. The grid walks (slot, page); the block table and sequence
 lengths ride as SCALAR-PREFETCH operands so each page's index_map can
@@ -11,14 +11,13 @@ accumulates flash-style across the sequentially-executed page axis
 (running max / sum / unnormalized accumulator in revisited output
 blocks, the same accumulation discipline as the HSTU backward kernel).
 
-Layout. The pool is stored ``(P, page, H, hd)``; the kernel reads it as
-``(P, page, H*hd)`` — a reshape of the two contiguous minor axes, no pad
-and no transpose — so one K/V block is a whole page of every head,
-``(page, H*hd)``: sublanes carry the page, lanes carry head-major
-features, and both block dims equal the array's (Mosaic's rule for the
-last two block dims). A per-head block ``(1, page, 1, hd)`` over the
-4-D pool is NOT legal: it puts a 1 in the second-to-last block dim where
-the array has H. All heads then share one MXU call per page through a
+Layout. The pool is stored ``(P, page, H*hd)`` (``ops.paged.zero_pool``)
+and the kernel reads it AS STORED, with no reshape: one K/V block is a
+whole page of every head, ``(page, H*hd)``: sublanes carry the page,
+lanes carry head-major features, and both block dims equal the array's
+(Mosaic's rule for the last two block dims). H and hd come from the
+query; why the pool has no separate head axis is in ``ops/paged.py``'s
+module docstring. All heads share one MXU call per page through a
 BLOCK-DIAGONAL query: row ``h*Kp + k`` of the ``(H*Kp, H*hd)`` query
 holds beam k's head-h query in lanes ``[h*hd, (h+1)*hd)`` and zeros
 elsewhere, so ``q_bd @ k_page.T`` is exactly the per-head scores. The PV
@@ -199,21 +198,21 @@ _page_index = lambda s, p, bt, sl: (bt[s, p], 0, 0)  # noqa: E731
 def paged_attention_stats_pallas_quantized(q, k_pool, v_pool, block_tables,
                                            seq_lens, interpret: bool = False):
     """Quantized-pool kernel path: pools are ``ops.quant.QuantizedKVPool``
-    (int8 data (P, page, H, hd) + fp32 scale (P, page)); the per-page-row
+    (int8 data (P, page, H*hd) + fp32 scale (P, page)); the per-page-row
     scales ride as their own (1, page) blocks resolved through the same
     block-table index_map, and dequantization happens inside the kernel
     body. Same (acc, m, l) contract as the fp32 twin, pinned against the
     dequant-after-gather fallback in tests/test_quantized.py.
     """
-    P, page, H, hd = k_pool.data.shape
+    _, page, HD = k_pool.data.shape
     _check_page(page)
     interpret = resolve_interpret(interpret, "paged_attention[int8]")
-    data_spec = pl.BlockSpec((1, page, H * hd), _page_index)
+    data_spec = pl.BlockSpec((1, page, HD), _page_index)
     scale_spec = pl.BlockSpec((1, 1, page), _page_index)
     return _paged_call(
         _kernel_quant, q,
-        (k_pool.data.reshape(P, page, H * hd), k_pool.scale[:, None, :],
-         v_pool.data.reshape(P, page, H * hd), v_pool.scale[:, None, :]),
+        (k_pool.data, k_pool.scale[:, None, :],
+         v_pool.data, v_pool.scale[:, None, :]),
         (data_spec, scale_spec, data_spec, scale_spec),
         block_tables, seq_lens, page, interpret, "paged_attention_int8",
     )
@@ -223,16 +222,15 @@ def paged_attention_stats_pallas(q, k_pool, v_pool, block_tables, seq_lens,
                                  interpret: bool = False):
     """Kernel twin of ops/paged.py `_stats_fallback`: (acc, m, l) fp32.
 
-    q (S, K, H, hd); pools (P, page, H, hd); block_tables (S, Pm) int32;
+    q (S, K, H, hd); pools (P, page, H*hd); block_tables (S, Pm) int32;
     seq_lens (S,) int32.
     """
-    P, page, H, hd = k_pool.shape
+    _, page, HD = k_pool.shape
     _check_page(page)
     interpret = resolve_interpret(interpret, "paged_attention")
-    spec = pl.BlockSpec((1, page, H * hd), _page_index)
+    spec = pl.BlockSpec((1, page, HD), _page_index)
     return _paged_call(
-        _kernel, q,
-        (k_pool.reshape(P, page, H * hd), v_pool.reshape(P, page, H * hd)),
+        _kernel, q, (k_pool, v_pool),
         (spec, spec), block_tables, seq_lens, page, interpret,
         "paged_attention",
     )

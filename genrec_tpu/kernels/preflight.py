@@ -338,8 +338,8 @@ def _paged_inputs(rng, S, Kb, H, hd, page, Pm, dtype):
 
     P = 1 + S * Pm
     q = jnp.asarray(rng.normal(size=(S, Kb, H, hd)), dtype)
-    kp = jnp.asarray(rng.normal(size=(P, page, H, hd)), dtype)
-    vp = jnp.asarray(rng.normal(size=(P, page, H, hd)), dtype)
+    kp = jnp.asarray(rng.normal(size=(P, page, H * hd)), dtype)
+    vp = jnp.asarray(rng.normal(size=(P, page, H * hd)), dtype)
     # Scattered page ids: consecutive slots must not read consecutive rows.
     bt = jnp.asarray(1 + rng.permutation(S * Pm).reshape(S, Pm), jnp.int32)
     sl = jnp.asarray(rng.integers(1, Pm * page + 1, (S,)), jnp.int32)
@@ -395,8 +395,8 @@ def leg_paged_attention_int8(rng, interpret, timing, shape=None):
 
     shape = shape or (_PAGED_SHAPE_INTERPRET if interpret else _PAGED_SHAPE)
     q, kp, vp, bt, sl = _paged_inputs(rng, *shape, jnp.float32)
-    kq = QuantizedKVPool(*quantize_symmetric(kp, (-2, -1)))
-    vq = QuantizedKVPool(*quantize_symmetric(vp, (-2, -1)))
+    kq = QuantizedKVPool(*quantize_symmetric(kp, (-1,)))
+    vq = QuantizedKVPool(*quantize_symmetric(vp, (-1,)))
     got = jax.jit(
         lambda q: paged_attention_stats_pallas_quantized(
             q, kq, vq, bt, sl, interpret=interpret

@@ -1363,18 +1363,12 @@ def cobra_generate_paged(
     block_tables = jnp.asarray(
         1 + jnp.arange(B * pages_per_slot).reshape(B, pages_per_slot), jnp.int32
     )
-    if kv_dtype == "int8":
-        from genrec_tpu.ops.quant import QuantizedKVPool
+    from genrec_tpu.ops.paged import zero_pool
 
-        zeros = lambda: tuple(
-            QuantizedKVPool.zeros((num_pages, page_size, H, hd))
-            for _ in range(nl)
-        )
-    else:
-        zeros = lambda: tuple(
-            jnp.zeros((num_pages, page_size, H, hd), model.dtype)
-            for _ in range(nl)
-        )
+    zeros = lambda: tuple(
+        zero_pool(num_pages, page_size, H, hd, model.dtype, kv_dtype)
+        for _ in range(nl)
+    )
     k_pools, v_pools, init = cobra_prefill_paged(
         model, params, input_ids, vecs, block_tables, zeros(), zeros(),
         trie, n_candidates, temperature,

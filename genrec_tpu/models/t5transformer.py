@@ -479,7 +479,7 @@ class TransformerDecoder(nn.Module):
     def decode_step_paged(self, x, caches, k_pools, v_pools, block_tables,
                           seq_lens, steps):
         """Advance all layers one per-row position against the paged
-        cross-attention pools (one (pages, page, H, hd) K and V pool per
+        cross-attention pools (one (pages, page, H*hd) K and V pool per
         layer)."""
         new_caches = []
         for layer, cache, kp, vp in zip(self.layers, caches, k_pools, v_pools):

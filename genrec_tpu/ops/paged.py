@@ -2,9 +2,21 @@
 
 Ragged Paged Attention (PAPERS.md, arxiv 2604.15464) decouples decode KV
 memory from the serving bucket a request landed in: K/V live in a global
-page pool ``(num_pages, page_size, heads, head_dim)`` and each decode
-slot names its pages through a block-table row, so HBM scales with the
-tokens actually resident, not with ``max_slots x max_history``.
+page pool and each decode slot names its pages through a block-table
+row, so HBM scales with the tokens actually resident, not with
+``max_slots x max_history``.
+
+ONE pool shape, made by ``zero_pool`` and nowhere else: ``(num_pages,
+page_size, heads * head_dim)``, head-major features in the last axis.
+It is the shape both device-side consumers already want — the kernel
+reads a ``(1, page, H*hd)`` block a page, ``write_pages`` scatters whole
+``(page, H*hd)`` pages — so a pool leaf passes from launch to launch in
+the layout it is read and written in. The 4-D ``(..., heads, head_dim)``
+form it replaced had minor axes ``(6, 64)``, which the TPU runtime stored
+page-minor to avoid padding them to an ``(8, 128)`` tile; every prefill
+and decode launch then paid whole-pool relayout copies in and out
+(PERF.md section 6, PR 31). Nothing here reshapes a pool: heads and
+head_dim come from the query.
 
 This module is the gather/segment fallback (and the numerics contract)
 for the Pallas kernel in ``kernels/paged_attention.py``: non-TPU
@@ -40,8 +52,19 @@ from genrec_tpu.ops.quant import QuantizedKVPool, quantize_symmetric
 NEG = -1e9
 
 
+def zero_pool(num_pages: int, page_size: int, n_heads: int, head_dim: int,
+              dtype, kv_dtype: str = "float32"):
+    """One layer's all-zero K or V pool: the one place the pool's shape
+    is written down (module docstring). ``kv_dtype="int8"`` gives the
+    quantized container, whose ``data`` leaf has the same shape."""
+    shape = (num_pages, page_size, n_heads * head_dim)
+    if kv_dtype == "int8":
+        return QuantizedKVPool.zeros(shape)
+    return jnp.zeros(shape, dtype)
+
+
 def gather_pages(pool, block_tables: jax.Array) -> jax.Array:
-    """(P, page, H, hd) pool + (S, Pm) block tables -> (S, Pm*page, H, hd)
+    """(P, page, H*hd) pool + (S, Pm) block tables -> (S, Pm*page, H*hd)
     contiguous per-slot K or V (the fallback's materialized view).
 
     A ``QuantizedKVPool`` dequantizes AFTER the gather — only the
@@ -49,13 +72,13 @@ def gather_pages(pool, block_tables: jax.Array) -> jax.Array:
     (the HLO property scripts/check_quant_hlo.py pins).
     """
     S, Pm = block_tables.shape
-    page = pool.shape[1]
+    _, page, HD = pool.shape
     if isinstance(pool, QuantizedKVPool):
-        rows = pool.data[block_tables].astype(jnp.float32)  # (S, Pm, page, H, hd)
-        out = rows * pool.scale[block_tables][..., None, None]
+        rows = pool.data[block_tables].astype(jnp.float32)  # (S, Pm, page, H*hd)
+        out = rows * pool.scale[block_tables][..., None]
     else:
-        out = pool[block_tables]  # (S, Pm, page, H, hd)
-    return out.reshape(S, Pm * page, *pool.shape[2:])
+        out = pool[block_tables]  # (S, Pm, page, H*hd)
+    return out.reshape(S, Pm * page, HD)
 
 
 def write_pages(pool, block_tables: jax.Array, kv: jax.Array):
@@ -67,6 +90,10 @@ def write_pages(pool, block_tables: jax.Array, kv: jax.Array):
     absorbs the padded-tail writes harmlessly (never read unmasked).
     Requires L <= Pm * page_size (the engine sizes pages_per_slot off the
     largest history bucket, so this is a config invariant, asserted).
+
+    The rows land head-major in the pool's merged last axis, ``(B, Pm,
+    page, H*hd)``: ONE scatter of whole pages into the (donated) pool,
+    and the only operation that touches it.
 
     A ``QuantizedKVPool`` quantizes HERE — per (page, position) row over
     heads x head_dim — so pages land already-int8 and their scales land
@@ -83,17 +110,16 @@ def write_pages(pool, block_tables: jax.Array, kv: jax.Array):
             f"capacity of a slot's block-table row"
         )
     with jax.named_scope("kv_write"):
-        rows = jnp.moveaxis(kv, 1, 2)  # (B, L, H, hd)
-        rows = jnp.pad(rows, ((0, 0), (0, cap - L), (0, 0), (0, 0)))
+        rows = jnp.moveaxis(kv, 1, 2).reshape(B, L, H * hd)
+        rows = jnp.pad(rows, ((0, 0), (0, cap - L), (0, 0)))
+        rows = rows.reshape(B, Pm, page, H * hd)
         if isinstance(pool, QuantizedKVPool):
-            rows = rows.reshape(B, Pm, page, H, hd)
-            data, scale = quantize_symmetric(rows, (-2, -1))  # scale (B, Pm, page)
+            data, scale = quantize_symmetric(rows, (-1,))  # scale (B, Pm, page)
             return QuantizedKVPool(
                 pool.data.at[block_tables].set(data),
                 pool.scale.at[block_tables].set(scale),
             )
-        rows = rows.reshape(B, Pm, page, H, hd).astype(pool.dtype)
-        return pool.at[block_tables].set(rows)
+        return pool.at[block_tables].set(rows.astype(pool.dtype))
 
 
 def paged_attention_stats(
@@ -108,7 +134,7 @@ def paged_attention_stats(
 
     q: (S, K, H, hd) — K beams per slot, all sharing the slot's pages
     (beam-sharing: a beam reorder never remaps pages, only the tiny
-    dense suffix caches). Pools: (P, page, H, hd). block_tables: (S, Pm)
+    dense suffix caches). Pools: (P, page, H*hd). block_tables: (S, Pm)
     int32. seq_lens: (S,) int32 valid-token counts.
 
     Returns (acc, m, l) all fp32: acc (S, K, H, hd) = sum_j exp(s_j - m)
@@ -143,8 +169,8 @@ def paged_attention_stats(
 
 def _stats_fallback(q, k_pool, v_pool, block_tables, seq_lens):
     S, K, H, hd = q.shape
-    k = gather_pages(k_pool, block_tables)  # (S, M, H, hd)
-    v = gather_pages(v_pool, block_tables)
+    k = gather_pages(k_pool, block_tables).reshape(S, -1, H, hd)  # (S, M, H, hd)
+    v = gather_pages(v_pool, block_tables).reshape(S, -1, H, hd)
     M = k.shape[1]
     s = jnp.einsum("skhd,smhd->skhm", q, k).astype(jnp.float32) * (hd**-0.5)
     tok = jnp.arange(M)

@@ -10,9 +10,9 @@ Two containers, both REGISTERED PYTREES so they flow through every
 existing compile/donate/ledger surface unchanged:
 
 - ``QuantizedKVPool``: one decode layer's K or V page pool as int8
-  ``data`` (num_pages, page_size, heads, head_dim) plus fp32 per-
+  ``data`` (num_pages, page_size, heads * head_dim) plus fp32 per-
   page-row ``scale`` (num_pages, page_size) — one scale per resident
-  token position, reduced over (heads x head_dim). Page granularity
+  token position, reduced over the merged feature axis. Page granularity
   means a COW page share carries its scales for free (they live at the
   same page index), and the disagg gather/scatter moves (data, scale)
   rows together.
@@ -52,9 +52,9 @@ KV_DTYPES = ("float32", "int8")
 def quantize_symmetric(x: jax.Array, reduce_axes) -> tuple[jax.Array, jax.Array]:
     """int8-quantize ``x`` with one scale per kept index.
 
-    ``reduce_axes``: the axes folded into each scale (e.g. ``(-2, -1)``
-    for per-token KV rows over heads x head_dim, ``(-1,)`` for per-row
-    table quantization). Returns (data int8, scale fp32) where scale's
+    ``reduce_axes``: the axes folded into each scale (``(-1,)`` for
+    per-token KV rows over the merged heads * head_dim axis and for
+    per-row table quantization). Returns (data int8, scale fp32) where scale's
     shape is ``x`` with the reduced axes removed.
     """
     x = x.astype(jnp.float32)
@@ -69,10 +69,10 @@ def quantize_symmetric(x: jax.Array, reduce_axes) -> tuple[jax.Array, jax.Array]
 class QuantizedKVPool:
     """One layer's K or V page pool, int8 data + per-page-row scales.
 
-    Drop-in pytree replacement for the fp32 ``(P, page, H, hd)`` pool
-    array inside ``KVPagePool.k_pools`` / ``v_pools``; ``ops/paged.py``
+    Drop-in pytree replacement for the ``(P, page, H*hd)`` pool array
+    inside ``KVPagePool.k_pools`` / ``v_pools``; ``ops/paged.py``
     dispatches on it (quantize on write, dequant after gather / inside
-    the Pallas kernel). Leaves: ``data`` int8 (P, page, H, hd),
+    the Pallas kernel). Leaves: ``data`` int8 (P, page, H*hd),
     ``scale`` fp32 (P, page).
     """
 
@@ -92,14 +92,13 @@ class QuantizedKVPool:
         return cls(*children)
 
     @classmethod
-    def zeros(cls, shape, page_size: int | None = None) -> "QuantizedKVPool":
-        """Fresh all-zero pool of geometry ``shape`` = (P, page, H, hd).
+    def zeros(cls, shape) -> "QuantizedKVPool":
+        """Fresh all-zero pool of geometry ``shape`` = (P, page, H*hd).
         Scales init to 1 so a never-written page dequantizes to zeros
         (page 0, the reserved null page, is read masked anyway)."""
-        P, page = shape[0], shape[1]
         return cls(
             jnp.zeros(shape, jnp.int8),
-            jnp.ones((P, page), jnp.float32),
+            jnp.ones(shape[:2], jnp.float32),
         )
 
     # -- geometry mirrors (the few array attributes pool consumers read)
@@ -119,7 +118,7 @@ class QuantizedKVPool:
     def dequantize(self) -> jax.Array:
         """Full fp32 pool — test/debug only; runtime consumers dequant
         AFTER gathering (see module docstring)."""
-        return self.data.astype(jnp.float32) * self.scale[:, :, None, None]
+        return self.data.astype(jnp.float32) * self.scale[:, :, None]
 
     # -- row movement (disagg transport gather/scatter, COW shares) ----
     def take_rows(self, pages: jax.Array) -> tuple[jax.Array, jax.Array]:

@@ -94,11 +94,13 @@ def serve_rules(model_axis: str = "model") -> Sequence[Rule]:
 
 def kv_pool_sharding(mesh: Mesh, n_heads: int, model_axis: str = "model"):
     """Per-leaf placement for a KV page bank's pools: (num_pages,
-    page_size, n_heads, head_dim) leaves shard the HEAD axis (dim 2)
-    over ``model_axis`` — paged attention is embarrassingly parallel
-    across heads, so the bank splits n-fold with zero cross-device
-    traffic inside the attention read — and every other leaf (int8
-    per-row scale planes, which span heads) replicates.
+    page_size, n_heads * head_dim) leaves shard their merged LAST axis
+    over ``model_axis``. The axis is head-major, so under the
+    divisibility required below a shard holds whole heads — paged
+    attention is embarrassingly parallel across heads, so the bank
+    splits n-fold with zero cross-device traffic inside the attention
+    read — and every other leaf (int8 per-row scale planes, which span
+    heads) replicates.
 
     Returns None when the mesh cannot shard the head axis (no such axis,
     degree 1, or non-divisible n_heads): the caller keeps the pool
@@ -110,8 +112,8 @@ def kv_pool_sharding(mesh: Mesh, n_heads: int, model_axis: str = "model"):
         return None
 
     def place(leaf):
-        if getattr(leaf, "ndim", 0) == 4 and leaf.shape[2] == n_heads:
-            return NamedSharding(mesh, P(None, None, model_axis, None))
+        if getattr(leaf, "ndim", 0) == 3:
+            return NamedSharding(mesh, P(None, None, model_axis))
         return NamedSharding(mesh, P())
 
     return place

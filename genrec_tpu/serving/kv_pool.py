@@ -3,7 +3,8 @@
 PR 5's engine AOT-compiled a dense (batch x history) KV cache per bucket,
 so decode memory scaled with the BUCKET a request landed in. Here the
 history K/V of every in-flight request lives in ONE pool per decoder
-layer, shaped (num_pages, page_size, heads, head_dim), and each decode
+layer, shaped (num_pages, page_size, heads * head_dim) — the one shape
+its writer and its reader share (``ops.paged.zero_pool``) — and each decode
 slot names its pages through a block-table row (Ragged Paged Attention,
 arxiv 2604.15464): HBM is a fixed budget, occupancy tracks the tokens
 actually resident, and admission is denied (never over-allocated) when
@@ -227,8 +228,7 @@ class KVPagePool:
         self._bank = bank
         self._placement = None  # sharding_of, once place() has run
         if bank is None:
-            self._shape = (cfg.num_pages, cfg.page_size, n_heads, head_dim)
-            self._dtype = dtype
+            self._layout = (n_heads, head_dim, dtype)
             self._k_pools = self._zero_pools()
             self._v_pools = self._zero_pools()
             self.allocator = PageAllocator(cfg.num_pages)
@@ -255,14 +255,12 @@ class KVPagePool:
         heapq.heapify(self._free_slots)
 
     def _zero_pools(self) -> tuple:
-        if self.cfg.kv_dtype == "int8":
-            from genrec_tpu.ops.quant import QuantizedKVPool
+        from genrec_tpu.ops.paged import zero_pool
 
-            return tuple(
-                QuantizedKVPool.zeros(self._shape) for _ in range(self.n_layers)
-            )
+        cfg = self.cfg
         return tuple(
-            jnp.zeros(self._shape, self._dtype) for _ in range(self.n_layers)
+            zero_pool(cfg.num_pages, cfg.page_size, *self._layout, cfg.kv_dtype)
+            for _ in range(self.n_layers)
         )
 
     def device_pools_consumed(self) -> bool:

@@ -102,7 +102,7 @@ def _check_pool_hlo() -> dict:
 
     P, page, H, hd, S, K, Pm = 37, 8, 2, 16, 3, 4, 5
     pool_sds = QuantizedKVPool(
-        jax.ShapeDtypeStruct((P, page, H, hd), np.int8),
+        jax.ShapeDtypeStruct((P, page, H * hd), np.int8),
         jax.ShapeDtypeStruct((P, page), np.float32),
     )
     args = (
@@ -117,8 +117,8 @@ def _check_pool_hlo() -> dict:
         ),
         *args,
     )
-    full_pool_f32 = f"f32[{P},{page},{H},{hd}]"
-    pool_s8 = f"s8[{P},{page},{H},{hd}]"
+    full_pool_f32 = f"f32[{P},{page},{H * hd}]"
+    pool_s8 = f"s8[{P},{page},{H * hd}]"
     big_consts = [c for c in ir.hlo_constants(hlo) if c["bytes"] > 64 * 1024]
     rec = {
         "pool_param_s8": pool_s8 in hlo,

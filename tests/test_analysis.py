@@ -122,6 +122,28 @@ class TestIRRules:
         found, _ = ir.analyze_entry("fix/f32", built)
         assert not found, found
 
+    def test_hlo_ops_of_size_tells_a_relayout_from_a_scatter(self):
+        """What chip_smoke.py's whole-pool check rests on: of the values
+        of one element count, a relaying program shows a copy or a
+        transpose, an in-place page write only parameter, scatter and
+        the fusion around it."""
+        import jax.numpy as jnp
+
+        from genrec_tpu.analysis import ir
+
+        pool = jnp.zeros((11, 8, 24), jnp.float32)
+        n = pool.size
+        relay = ir.optimized_hlo(
+            lambda p: p.reshape(11, 8, 2, 12).transpose(0, 2, 1, 3) * 2.0, pool)
+        assert {"copy", "transpose"} & {
+            op for op, _ in ir.hlo_ops_of_size(relay, n)}
+        stay = ir.optimized_hlo(
+            lambda p, rows: p.at[jnp.array([3, 5])].set(rows),
+            pool, jnp.ones((2, 8, 24)), donate_argnums=(0,))
+        ops = {op for op, _ in ir.hlo_ops_of_size(stay, n)}
+        assert "scatter" in ops and not {"copy", "transpose"} & ops, ops
+        assert not ir.hlo_ops_of_size(stay, n + 1)
+
     def test_host_transfer_in_loop_flagged(self):
         import jax
         import jax.numpy as jnp

@@ -357,7 +357,7 @@ def _kernel_calls():
 
     f32 = jnp.float32
     q = jnp.zeros((1, 1, 2, 8), f32)
-    pool = jnp.zeros((2, 8, 2, 8), f32)
+    pool = jnp.zeros((2, 8, 2 * 8), f32)
     bt, sl = jnp.zeros((1, 1), jnp.int32), jnp.ones((1,), jnp.int32)
     qkv = jnp.zeros((1, 1, 8, 8), f32)
     return {

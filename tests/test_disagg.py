@@ -122,8 +122,9 @@ def test_handoff_wire_roundtrip_and_version_refusal():
         layout=(1, 4, 8, "float32"), init=init, params_step=5,
         catalog_version="abc123", prefill_worker_id="tiger:p0", warm=True,
     )
-    k = (np.arange(3 * 8 * 4 * 8, dtype=np.float32).reshape(3, 8, 4, 8),)
-    v = (np.ones((3, 8, 4, 8), np.float32),)
+    # Page content in the pool's own shape: (pages, page, heads * head_dim).
+    k = (np.arange(3 * 8 * 4 * 8, dtype=np.float32).reshape(3, 8, 4 * 8),)
+    v = (np.ones((3, 8, 4 * 8), np.float32),)
     data = pack_handoff(h, k, v)
     assert isinstance(data, bytes) and len(data) > 0
     back, k2, v2 = unpack_handoff(data)
@@ -144,12 +145,12 @@ def test_handoff_wire_roundtrip_and_version_refusal():
     h.trace = ctx
     traced, _k3, _v3 = unpack_handoff(pack_handoff(h, k, v))
     assert traced.trace == ctx
-    # Version skew must be REFUSED typed, not misread — both a FUTURE
-    # layout and the pre-lineage v1 layout.
+    # Version skew must be REFUSED typed, not misread — a FUTURE layout,
+    # the pre-lineage v1 layout, and v3, whose page arrays were 4-D.
     import io
     import json
 
-    for bad_version in (99, 1):
+    for bad_version in (99, 1, 3):
         bad_header = json.dumps({"wire_version": bad_version}).encode()
         buf = io.BytesIO()
         np.savez(buf, __header__=np.frombuffer(bad_header, np.uint8))

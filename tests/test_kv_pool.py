@@ -107,7 +107,7 @@ def test_pool_reset_after_a_donating_call_consumed_the_pages():
     pool.reset_device_pools()
     assert not pool.device_pools_consumed()
     assert len(pool.k_pools) == len(pool.v_pools) == 2
-    assert pool.k_pools[1].shape == (cfg.num_pages, 8, 2, 4)
+    assert pool.k_pools[1].shape == (cfg.num_pages, 8, 2 * 4)
     assert not np.asarray(pool.k_pools[1]).any()
     assert pool.seq_lens[slot] == 9  # bookkeeping untouched
     pool.evict(slot)
@@ -118,6 +118,57 @@ def test_pool_reset_after_a_donating_call_consumed_the_pages():
     assert view.device_pools_consumed()
     view.reset_device_pools()
     assert not pool.device_pools_consumed()
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+def test_pool_leaves_have_the_one_shape(kv_dtype):
+    """Every pool leaf is (pages, page, heads * head_dim): the shape the
+    kernel's block and a page write share (ops.paged.zero_pool). Owner
+    pools, bank views and a reset hand out the same; an int8 pool's
+    scale plane is (pages, page)."""
+    import jax
+
+    from genrec_tpu.ops.quant import QuantizedKVPool
+
+    cfg = PagedConfig(max_slots=2, page_size=8, pages_per_slot=2,
+                      kv_dtype=kv_dtype)
+    bank = KVPagePool(cfg, n_layers=2, n_heads=3, head_dim=4)
+    view = KVPagePool(cfg, 2, 3, 4, bank=bank)
+    bank.reset_device_pools()
+    want = (cfg.num_pages, 8, 3 * 4)
+    for pool in (bank, view):
+        for leaf in (*pool.k_pools, *pool.v_pools):
+            assert leaf.shape == want
+            data, *scale = jax.tree_util.tree_leaves(leaf)
+            assert data.shape == want
+            assert [s.shape for s in scale] == (
+                [want[:2]] if kv_dtype == "int8" else [])
+    assert view.k_pools[0] is bank.k_pools[0]
+    q = QuantizedKVPool.zeros(want)
+    assert q.data.shape == want and q.scale.shape == want[:2]
+    assert q.dequantize().shape == want
+
+
+def test_kv_pool_sharding_shards_the_merged_axis():
+    """The bank splits over `model` along the head-major last axis (whole
+    heads a shard under the divisibility it requires); an int8 pool's
+    scale plane spans heads and replicates."""
+    import jax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from genrec_tpu.parallel.shardings import kv_pool_sharding
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("model",))
+    assert kv_pool_sharding(mesh, n_heads=3) is None  # 3 heads over 2
+    place = kv_pool_sharding(mesh, n_heads=4)
+    cfg = PagedConfig(max_slots=2, page_size=8, pages_per_slot=2,
+                      kv_dtype="int8")
+    pool = KVPagePool(cfg, n_layers=1, n_heads=4, head_dim=4)
+    pool.place(place)
+    leaf = pool.k_pools[0]
+    assert leaf.data.sharding.spec == P(None, None, "model")
+    assert leaf.data.addressable_shards[0].data.shape == (cfg.num_pages, 8, 8)
+    assert leaf.scale.sharding.spec == P()
 
 
 def test_pool_exhaustion_defers_cleanly():
@@ -329,8 +380,8 @@ def test_paged_attention_kernel_matches_fallback(rng):
 
     S, K, H, hd, page, P = 4, 5, 3, 8, 8, 12
     q = jnp.asarray(rng.normal(size=(S, K, H, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, page, H, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, page, H, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P, page, H * hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, page, H * hd)), jnp.float32)
     bt = jnp.asarray([[1, 2, 3], [4, 0, 0], [5, 6, 0], [7, 8, 9]], jnp.int32)
     sl = jnp.asarray([24, 3, 0, 17], jnp.int32)  # incl. a fully-masked slot
 
@@ -351,14 +402,15 @@ def test_paged_attention_matches_dense_softmax(rng):
 
     S, K, H, hd, page, P, Pm = 2, 3, 2, 8, 8, 8, 2
     q = jnp.asarray(rng.normal(size=(S, K, H, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, page, H, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, page, H, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P, page, H * hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, page, H * hd)), jnp.float32)
     bt = jnp.asarray([[1, 2], [3, 0]], jnp.int32)
     sl = jnp.asarray([11, 8], jnp.int32)
 
     out = np.asarray(paged_attention(q, kp, vp, bt, sl, use_kernel=False))
-    k = np.asarray(gather_pages(kp, bt))
-    v = np.asarray(gather_pages(vp, bt))
+    # Head-major features in the merged axis: (..., H*hd) reads as (H, hd).
+    k = np.asarray(gather_pages(kp, bt)).reshape(S, Pm * page, H, hd)
+    v = np.asarray(gather_pages(vp, bt)).reshape(S, Pm * page, H, hd)
     s = np.einsum("skhd,smhd->skhm", np.asarray(q), k) * hd**-0.5
     tok = np.arange(Pm * page)
     s = np.where(tok[None, None, None, :] >= np.asarray(sl)[:, None, None, None],

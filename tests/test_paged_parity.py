@@ -134,6 +134,87 @@ def test_cobra_paged_matches_dense(cobra_setup, constrained):
         assert bool(np.asarray(tuples_are_valid(trie, paged.sem_ids)).all())
 
 
+# ---- the programs ask for no pool relayout ----------------------------------
+
+# 11 pages: the pool's element count then has a factor nothing else in
+# these tiny programs has, so "a value of the pool's size" IS a pool.
+_POOL_PAGES, _PAGE = 11, 8
+
+
+def _tiger_paged_programs(model, params, valid, b):
+    from genrec_tpu.models.tiger import (
+        init_tiger_paged_state, tiger_paged_decode_step, tiger_prefill_paged,
+    )
+
+    B = b["items"].shape[0]
+    trie = DenseTrie.build(valid, K_CB)
+    bt = jnp.asarray(1 + np.arange(B * 2).reshape(B, 2), jnp.int32)
+    state = init_tiger_paged_state(model, B, 5)
+    steps = jnp.ones((B,), jnp.int32)
+    sl = jnp.full((B,), 9, jnp.int32)
+    geo = (model.n_layers // 2, model.num_heads,
+           model.attn_dim // model.num_heads, model.dtype)
+    return geo, {
+        "prefill": lambda kp, vp: tiger_prefill_paged(
+            model, params, b["user"], b["items"], b["types"], b["mask"],
+            bt, kp, vp),
+        "decode": lambda kp, vp: tiger_paged_decode_step(
+            model, params, trie, state, steps, bt, sl, kp, vp),
+    }
+
+
+def _cobra_paged_programs(model, params, ids, txt, valid):
+    from genrec_tpu.models.cobra import (
+        cobra_paged_decode_step, cobra_prefill_paged, init_cobra_paged_state,
+    )
+
+    B = ids.shape[0]
+    trie = DenseTrie.build(valid, K_CB)
+    vecs = model.apply({"params": params}, txt, method=Cobra.encode_items)
+    bt = jnp.asarray(1 + np.arange(B * 2).reshape(B, 2), jnp.int32)
+    state = init_cobra_paged_state(model, B, 4)
+    steps = jnp.ones((B,), jnp.int32)
+    sl = jnp.full((B,), 9, jnp.int32)
+    geo = (model.decoder_n_layers, model.decoder_num_heads,
+           model.d_model // model.decoder_num_heads, model.dtype)
+    return geo, {
+        "prefill": lambda kp, vp: cobra_prefill_paged(
+            model, params, ids, vecs, bt, kp, vp, trie, 4, 1.0),
+        "decode": lambda kp, vp: cobra_paged_decode_step(
+            model, params, trie, state, steps, bt, sl, kp, vp),
+    }
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+@pytest.mark.parametrize("kernel", [False, True], ids=["gather", "kernel"])
+@pytest.mark.parametrize("program", ["tiger_prefill", "tiger_decode",
+                                     "cobra_prefill", "cobra_decode"])
+def test_paged_programs_ask_for_no_pool_relayout(
+        program, kernel, kv_dtype, request, monkeypatch):
+    """A pool leaf is consumed by the page scatter, the fallback's page
+    gather or the Pallas call, and by nothing else: no reshape,
+    transpose or convert of a pool-sized value is ever traced, so no
+    launch can owe a whole-pool copy to what the PROGRAM asks for (what
+    the chip's compiler then does is chip_smoke.py's check)."""
+    from genrec_tpu.analysis.ir import primitives_of_size
+    from genrec_tpu.kernels import policy
+    from genrec_tpu.ops.paged import zero_pool
+
+    head, which = program.split("_")
+    setup = request.getfixturevalue(f"{head}_setup")
+    (nl, H, hd, dtype), programs = (
+        _tiger_paged_programs if head == "tiger" else _cobra_paged_programs
+    )(*setup)
+    monkeypatch.setattr(policy, "auto_paged_attention", lambda: kernel)
+    pools = tuple(zero_pool(_POOL_PAGES, _PAGE, H, hd, dtype, kv_dtype)
+                  for _ in range(nl))
+    jaxpr = jax.make_jaxpr(programs[which])(pools, pools)
+    prims = primitives_of_size(jaxpr.jaxpr, _POOL_PAGES * _PAGE * H * hd)
+    read = "pallas_call" if kernel else "gather"
+    want = {"scatter"} if which == "prefill" else {read}
+    assert prims == want, prims
+
+
 # ---- ragged primitives at MIXED steps ---------------------------------------
 
 

@@ -48,6 +48,7 @@ from genrec_tpu.models.tiger import (
     tiger_prefill_paged,
     tiger_spec_tree_step,
 )
+from genrec_tpu.ops.paged import zero_pool
 from genrec_tpu.ops.spec_tree import TreeTopology
 from genrec_tpu.ops.trie import legal_topk_ragged, tuples_are_valid
 
@@ -81,7 +82,7 @@ def _tiger_setup(D: int):
     pps = -(-(L + 1) // page)
     bt = jnp.asarray(1 + jnp.arange(B * pps).reshape(B, pps), jnp.int32)
     zeros = lambda: tuple(
-        jnp.zeros((1 + B * pps, page, H, hd), model.dtype) for _ in range(nl)
+        zero_pool(1 + B * pps, page, H, hd, model.dtype) for _ in range(nl)
     )
     k_pools, v_pools, seq_lens, _ = tiger_prefill_paged(
         model, params, user, items, types, maskj, bt, zeros(), zeros(),
@@ -161,7 +162,7 @@ def _cobra_setup(with_trie: bool):
     pps = -(-(T * (C + 1)) // page)
     bt = jnp.asarray(1 + jnp.arange(B * pps).reshape(B, pps), jnp.int32)
     zeros = lambda: tuple(
-        jnp.zeros((1 + B * pps, page, H, hd), model.dtype) for _ in range(nl)
+        zero_pool(1 + B * pps, page, H, hd, model.dtype) for _ in range(nl)
     )
     k_pools, v_pools, init = cobra_prefill_paged(
         model, params, jnp.asarray(ids), vecs, bt, zeros(), zeros(),
