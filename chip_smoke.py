@@ -88,7 +88,7 @@ KEYE_REHEARSE_BINDINGS = {
 }
 #: Not run, and why. `ServingEngine(mesh=...)` at model axis 2 was tried on
 #: a four-chip host (PR 21): params and pools shard, then warmup() dies in
-#: `_compile_decode` — GSPMD meets the paged pallas_call and jax raises
+#: `SlotTable.compile` — GSPMD meets the paged pallas_call and jax raises
 #: "NotImplementedError: Mosaic kernels cannot be automatically
 #: partitioned. Please wrap the call in a shard_map." ROADMAP S6 owns it.
 LEFT_OUT = {
@@ -381,7 +381,7 @@ def _check_pool_stays_put(runner) -> dict:
           f"format={leaf.format}", flush=True)
     executables = {
         **{f"prefill_b{b}_l{l}": e for (b, l), e in runner._prefill.items()},
-        **{f"decode_s{s}": e for s, e in runner._decode.items()},
+        **{f"decode_s{s}": e for s, e in runner.slots.executables.items()},
     }
     check(executables, "the engine holds no paged executable")
     # The rehearsal's CPU backend widens a bf16 scatter to float32 and

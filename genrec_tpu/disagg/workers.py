@@ -53,32 +53,14 @@ from genrec_tpu.disagg.handoff import (
 )
 from genrec_tpu.obs.memory import MemoryLedger, tree_nbytes
 from genrec_tpu.obs.spans import NULL_TRACER
-from genrec_tpu.serving.aot import (
-    donate_argnums as _donate,
-    paged_decode_donate_argnums,
-    sds_tree as _sds,
-)
+from genrec_tpu.serving.aot import donate_argnums as _donate, sds_tree as _sds
 from genrec_tpu.serving.kv_pool import (
     KVPagePool,
     PoolExhausted,
     PrefixIndex,
 )
+from genrec_tpu.serving.slots import SlotTable, stage as _stage
 from genrec_tpu.serving.types import HBMBudgetError, Response
-
-
-def _stage(tree, mesh):
-    """Per-call operands (batch arrays, slot state, block tables) on
-    their way into a compiled executable. Single device: device arrays,
-    as always. Under a mesh: HOST arrays — the mesh-lowered executable
-    places them to its expected (replicated) sharding at dispatch,
-    whereas a device-0-committed jnp array would be rejected as a
-    sharding mismatch (the engine's ``ServingEngine._stage``, shared by
-    both role workers)."""
-    import jax
-    import jax.numpy as jnp
-
-    f = np.asarray if mesh is not None else jnp.asarray
-    return jax.tree_util.tree_map(f, tree)
 
 
 def _place_worker(worker, mesh, model_axis: str) -> None:
@@ -625,8 +607,6 @@ class DecodeWorker:
         if mesh is not None:
             _place_worker(self, mesh, self._model_axis)
         cfg = pool.cfg
-        self.spec_topology = spec_topology
-        self.spec_fanout = spec_fanout
         if spec_topology is not None:
             # Scratch-page reservation (the engine's discipline, per
             # worker): the pool/bank the front built at CONSTRUCTION
@@ -659,23 +639,17 @@ class DecodeWorker:
                 "with no room for its speculative scratch reservation — "
                 "proceeding unreserved (CPU fallback unaffected)"
             )
-        self.state = head.paged_state_zeros(cfg.max_slots)
-        self.steps = np.zeros(cfg.max_slots, np.int32)
-        self.active = np.zeros(cfg.max_slots, bool)
+        # The decode side of the slot set, shared with the co-located
+        # engine (serving/slots.py): state rows, step counters, the rung
+        # ladder (max_slots halving down to slot_floor) with its
+        # executables, and the step itself.
+        self.slots = SlotTable(
+            head, pool, floor=slot_floor, mesh=mesh,
+            spec_topology=spec_topology, spec_fanout=spec_fanout,
+        )
         # (flight, handoff, t_admit, span_ctx) per slot; span_ctx is
         # (trace_id, slot_residency_span_id, parent_span_id) or None.
         self.entries: list = [None] * cfg.max_slots
-        shapes = []
-        s = cfg.max_slots
-        floor = max(int(slot_floor), 1)
-        while True:
-            shapes.append(s)
-            if s <= floor:
-                break
-            s = max(s // 2, floor)
-        self.slot_shapes = sorted(set(shapes))
-        self._decode: dict[int, object] = {}
-        self._spec: dict[int, object] = {}
         self._transport_execs: list = []
         self.warmup_compiles = 0
         self.recompilations = 0
@@ -704,53 +678,13 @@ class DecodeWorker:
         if compiled is not None:
             self._transport_execs.append(compiled)
 
-    def _compile_decode(self, S: int):
-        import jax
-
-        fn = self.head.make_decode_paged_fn()
-        ops = self.head.runtime_operands()
-        return self._compile_step_fn(fn, ops, S, jax)
-
-    def _compile_spec(self, S: int):
-        """The tree-verify executable at rung S (engine's
-        _PagedRunner._compile_spec, per worker): identical operand
-        surface to the plain step, returns (state, accept_len)."""
-        import jax
-
-        fn = self.head.make_spec_decode_paged_fn(self.spec_fanout)
-        ops = self.head.runtime_operands()
-        return self._compile_step_fn(fn, ops, S, jax)
-
-    def _compile_step_fn(self, fn, ops, S: int, jax):
-        args = (
-            self.params,
-            *(_sds(op) for op in ops),
-            _sds({k: v[:S] for k, v in self.state.items()}),
-            jax.ShapeDtypeStruct((S,), np.int32),
-            jax.ShapeDtypeStruct((S, self.pool.cfg.pages_per_slot), np.int32),
-            jax.ShapeDtypeStruct((S,), np.int32),
-            _sds(self.pool.k_pools),
-            _sds(self.pool.v_pools),
-        )
-        # Donate the slot-state operand — the engine's own rule
-        # (graftlint audits the production entry).
-        donate = _donate(*paged_decode_donate_argnums(len(ops)))
-        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
-        self._count_compile()
-        return compiled
-
     def warmup(self) -> None:
         # Operands-first (see PrefillWorker.warmup): an impossible
-        # decode-side budget refuses before any compile is paid. A
-        # speculative worker compiles the tree-verify step INSTEAD of
-        # the plain step at every rung (the verified-rejection worst
-        # case IS the plain step — the engine's discipline).
+        # decode-side budget refuses before any compile is paid.
         self._ledger(operands_only=True)
-        for S in self.slot_shapes:
-            if self.spec_topology is not None:
-                self._spec[S] = self._compile_spec(S)
-            else:
-                self._decode[S] = self._compile_decode(S)
+        for S in self.slots.rungs:
+            self.slots.executables[S] = self.slots.compile(S, self.params)
+            self._count_compile()
         self.transport.prepare_admit(self.pool, self._count_transport_compile)
         self._ledger()
         self._warm = True
@@ -776,12 +710,7 @@ class DecodeWorker:
                 self.worker_id, "kv_page_bank_shared",
                 tree_nbytes((self.pool.k_pools, self.pool.v_pools)),
             )
-        led.record_operand(self.worker_id, "paged_slot_state",
-                           tree_nbytes(self.state))
-        for S, ex in self._decode.items():
-            led.record_executable(self.worker_id, f"decode/S{S}", ex)
-        for S, ex in self._spec.items():
-            led.record_executable(self.worker_id, f"spec_decode/S{S}", ex)
+        self.slots.record_memory(led, self.worker_id)
         for i, ex in enumerate(self._transport_execs):
             led.record_executable(self.worker_id, f"transport/{i}", ex)
         if self._hbm_budget is not None:
@@ -802,7 +731,7 @@ class DecodeWorker:
 
     @property
     def idle(self) -> bool:
-        return not self.active.any()
+        return self.slots.idle
 
     @property
     def free_slots(self) -> int:
@@ -864,15 +793,13 @@ class DecodeWorker:
         except PoolExhausted:
             return False
         try:
-            for key in self.state:
-                self.state[key][slot] = 0
+            patched = None
             if handoff.init:
                 own_L = self.ladder.history_bucket(
                     max(self.head.natural_len(flight.req), 1))
                 patched = self.head.paged_warm_state(
                     dict(handoff.init), handoff.n_tokens, own_L)
-                for key, val in patched.items():
-                    self.state[key][slot] = val
+            self.slots.bind(slot, patched)
         except Exception as e:  # noqa: BLE001 — unbind, then refuse typed
             # The transport already bound the slot: a state snapshot
             # that does not fit this head (skewed peer) must not leak
@@ -883,8 +810,6 @@ class DecodeWorker:
                 f"handoff state snapshot does not fit this worker's "
                 f"slot state: {e!r}"
             ) from e
-        self.steps[slot] = self.head.paged_init_step
-        self.active[slot] = True
         # Slot-residency span: pre-allocate its id so the decode/spec
         # step spans recorded BEFORE the slot finishes can parent onto
         # it (the engine's allocate-before-record discipline). The
@@ -908,115 +833,33 @@ class DecodeWorker:
     def _decode_span_ident(self) -> dict:
         return {"component": "decode_worker", "worker": self.worker_id}
 
+    def _trace_of(self, slot):
+        return self.entries[slot][3]
+
     def step(self) -> bool:
-        """Advance every active slot — one decode position through the
-        plain step, or 1..(1 + spec_depth) positions through the
-        tree-verify step when this worker speculates (the engine's
-        fixed-shape step, per worker)."""
-        if self.idle:
+        """Advance every active slot through the shared step
+        (serving/slots.py), the engine's fixed-shape step per worker."""
+        res = self.slots.step(self.params, self.tracer,
+                              self._decode_span_ident, self._trace_of)
+        if res is None:
             return False
-        spec = self.spec_topology is not None
-        hi = int(np.nonzero(self.active)[0][-1]) + 1
-        S = next(s for s in self.slot_shapes if s >= hi)
-        t_stage = time.monotonic()
-        mesh = self._mesh
-        args = (
-            self.params,
-            *self.head.runtime_operands(),
-            _stage({k: v[:S] for k, v in self.state.items()}, mesh),
-            _stage(np.where(self.active[:S], self.steps[:S], 0)
-                   .astype(np.int32), mesh),
-            _stage(self.pool.block_tables[:S], mesh),
-            _stage(self.pool.seq_lens[:S], mesh),
-            self.pool.k_pools,
-            self.pool.v_pools,
-        )
-        t0 = time.monotonic()
-        if spec:
-            out, accept = self._spec[S](*args)
-        else:
-            out = self._decode[S](*args)
-        for k, v in out.items():
-            self.state[k][:S] = np.asarray(v)
-        active_idx = np.nonzero(self.active)[0]
-        if spec:
-            # Accept-length clamp: exactly the engine's (root level is
-            # always exact, never overshoot a slot's remaining codes).
-            total = self.head.paged_total_steps
-            adv = np.minimum(
-                np.asarray(accept)[active_idx],
-                total - self.steps[active_idx],
-            ).astype(np.int32)
-            adv = np.maximum(adv, 1)
-        t1 = time.monotonic()
-        if self.tracer.enabled:
-            ident = self._decode_span_ident()
-            for i, slot in enumerate(active_idx):
-                span_ctx = self.entries[slot][3]
-                if span_ctx is None:
-                    continue
-                tid, sid = span_ctx[0], span_ctx[1]
-                if spec:
-                    self.tracer.record_span(
-                        "draft", tid, t_stage, t0, parent_id=sid,
-                        step=int(self.steps[slot]),
-                        drafted=int(self.spec_topology.n_nodes
-                                    - self.spec_topology.beams),
-                        **ident,
-                    )
-                    self.tracer.record_span(
-                        "tree_verify", tid, t0, t1, parent_id=sid,
-                        step=int(self.steps[slot]), slots=S,
-                        accept_len=int(adv[i]), **ident,
-                    )
-                else:
-                    self.tracer.record_span(
-                        "decode_step", tid, t0, t1, parent_id=sid,
-                        step=int(self.steps[slot]), slots=S, **ident,
-                    )
-        if spec:
-            self.steps[active_idx] += adv
-            self.metrics.record_decode_step(
-                S, len(active_idx),
-                int(self.pool.seq_lens[active_idx].sum()))
+        self.metrics.record_decode_step(res.slots, res.live, res.kv_tokens)
+        if res.accept is not None:
             self.metrics.record_spec(
-                self.head.name,
-                drafted=len(active_idx)
-                * (self.spec_topology.n_nodes - self.spec_topology.beams),
-                accept_lens=adv,
+                self.head.name, drafted=res.drafted, accept_lens=res.accept
             )
-            if self.tracer.enabled:
-                t2 = time.monotonic()
-                ident = self._decode_span_ident()
-                for i, slot in enumerate(active_idx):
-                    span_ctx = self.entries[slot][3]
-                    if span_ctx is not None:
-                        self.tracer.record_span(
-                            "accept", span_ctx[0], t1, t2,
-                            parent_id=span_ctx[1],
-                            accept_len=int(adv[i]), **ident,
-                        )
-        else:
-            self.steps[self.active] += 1
-            self.metrics.record_decode_step(
-                S, len(active_idx),
-                int(self.pool.seq_lens[active_idx].sum()))
         self.decode_steps += 1
         self.sweep_finished()
         return True
 
     def sweep_finished(self) -> None:
         head = self.head
-        done = np.nonzero(self.active
-                          & (self.steps >= head.paged_total_steps))[0]
-        for slot in done:
+        for slot in self.slots.finished():
             flight, handoff, t_admit, span_ctx = self.entries[slot]
             now = time.monotonic()
             try:
-                payload = head.paged_finalize(
-                    {k: np.array(v[slot]) for k, v in self.state.items()},
-                    flight.req,
-                )
+                payload = head.paged_finalize(self.slots.row(slot),
+                                              flight.req)
                 resp = Response(
                     head=head.name,
                     items=payload["items"],
@@ -1063,7 +906,7 @@ class DecodeWorker:
                 if not flight.fut.done():
                     flight.fut.set_result(resp)
             self.pool.evict(int(slot))
-            self.active[slot] = False
+            self.slots.release(slot)
             self.entries[slot] = None
             self.metrics.record_evict(1)
 
@@ -1077,7 +920,7 @@ class DecodeWorker:
         is shared and must not leak the casualty's refs."""
         self.dead = True
         stranded = []
-        for slot in np.nonzero(self.active)[0]:
+        for slot in self.slots.active_slots():
             flight, _handoff, t_admit, span_ctx = self.entries[slot]
             if not flight.fut.done():
                 stranded.append(flight)
@@ -1092,7 +935,7 @@ class DecodeWorker:
                     outcome="worker_killed", **self._decode_span_ident(),
                 )
             self.pool.evict(int(slot))
-            self.active[slot] = False
+            self.slots.release(slot)
             self.entries[slot] = None
         # The emulated device dies with the worker: drop the scratch
         # reservation's refs too, or the shared bank would leak the

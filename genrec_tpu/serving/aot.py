@@ -9,10 +9,22 @@ one place:
 - ``donate_argnums``: the backend donation policy — CPU has no buffer
   donation, and donating there only emits a per-call warning;
 - ``paged_decode_donate_argnums``: which operand of the paged decode
-  step is dead after the call.
+  step is dead after the call;
+- ``named``: the name an executable carries into a device profile.
 """
 
 from __future__ import annotations
+
+
+def named(fn, name: str):
+    """``fn`` under a stable name of its own, so that the compiled
+    executable is `jit_<name>` on a device profile's `XLA Modules` line
+    (and in every op's scope path) and not `jit_fn`. The name must not
+    hold the substring `paged`: a profile reader finds the paged-attention
+    kernel's custom calls by that word in their scope path, and an
+    executable's name is in every path."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 def donate_argnums(*argnums):

@@ -114,7 +114,6 @@ from concurrent.futures import Future
 from typing import Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from genrec_tpu.core import chaos
@@ -142,8 +141,9 @@ from genrec_tpu.serving.types import (
 
 
 from genrec_tpu.serving.aot import donate_argnums as _donate_argnums
-from genrec_tpu.serving.aot import paged_decode_donate_argnums
+from genrec_tpu.serving.aot import named as _named
 from genrec_tpu.serving.aot import sds_tree as _sds
+from genrec_tpu.serving.slots import SlotTable, stage as _stage
 
 
 def _operand_avals(operands) -> tuple:
@@ -155,17 +155,6 @@ def _operand_avals(operands) -> tuple:
         (tuple(int(s) for s in leaf.shape), str(leaf.dtype))
         for leaf in jax.tree_util.tree_leaves(operands)
     )
-
-
-def _named(fn, name: str):
-    """``fn`` under a stable name of its own, so that the compiled
-    executable is `jit_<name>` on a device profile's `XLA Modules` line
-    (and in every op's scope path) and not `jit_fn`. The name must not
-    hold the substring `paged`: a profile reader finds the paged-attention
-    kernel's custom calls by that word in their scope path, and an
-    executable's name is in every path."""
-    fn.__name__ = fn.__qualname__ = name
-    return fn
 
 
 def is_transient_fs_error(e: BaseException) -> bool:
@@ -220,7 +209,6 @@ class _PagedRunner:
             else bool(spec_cfg)
         )
         self.spec_topology = None
-        self._spec: dict[int, object] = {}
         if (want_spec and getattr(head, "supports_spec", False)
                 and head.spec_depth >= 1):
             from genrec_tpu.ops.spec_tree import TreeTopology
@@ -263,28 +251,18 @@ class _PagedRunner:
             if place is not None:
                 self.pool.place(place)
         self._scratch_tables = self.pool.reserve_scratch(self._scratch_demand)
-        self.state = head.paged_state_zeros(cfg.max_slots)
-        self.steps = np.zeros(cfg.max_slots, np.int32)
-        self.active = np.zeros(cfg.max_slots, bool)
+        # The decode side of the slot set (serving/slots.py): state rows,
+        # step counters, the rung ladder (max_slots halving down to
+        # max_batch) with its executables, and the step itself.
+        self.slots = SlotTable(
+            head, self.pool, floor=engine._max_batch, mesh=engine._mesh,
+            spec_topology=self.spec_topology, spec_fanout=engine._spec_fanout,
+        )
         # (req, fut, t_enq, trace_ctx, t_admit); trace_ctx is the
         # (trace_id, request_span_id, upstream_parent_span_id) adopted/
         # minted at submit(), or None (tracing off, no incoming trace).
         self.entries: list = [None] * cfg.max_slots
         self.buckets: list = [None] * cfg.max_slots  # prefill (B, L) per slot
-        # The collapsed decode-side ladder: a handful of slot-count
-        # shapes (max_slots halving down to max_batch). Slots fill
-        # lowest-index-first (kv_pool heap), so the step runs at the
-        # smallest shape covering the highest active slot — a lightly
-        # loaded engine doesn't pay max_slots of decode compute.
-        shapes = []
-        s = cfg.max_slots
-        while True:
-            shapes.append(s)
-            if s <= engine._max_batch:
-                break
-            s = max(s // 2, engine._max_batch)
-        self.slot_shapes = sorted(set(shapes))
-        self._decode: dict[int, object] = {}
         self._prefill: dict[tuple[int, int], object] = {}
         # The batcher's own lane of the span ring (docs/OBSERVABILITY.md
         # "The batcher lane"): each host phase of an iteration ONCE, as
@@ -317,78 +295,30 @@ class _PagedRunner:
 
     @property
     def idle(self) -> bool:
-        return not self.active.any()
+        return self.slots.idle
 
     # -- compilation ---------------------------------------------------------
 
     def warmup(self) -> None:
-        """Decode executables at the handful of (slot-count,
-        pages_per_slot) shapes + the prefill bucket grid. Everything else
-        the dense path compiled per bucket (the whole generate loop) is
-        gone from the decode side. A speculative runner compiles the
-        tree-verify step INSTEAD of the plain step at every rung (same
-        signature, returns (state, accept); accept >= 1 always — the
-        root level is exact — so no plain-step fallback executable is
-        needed: the verified-rejection worst case IS the plain step)."""
-        for S in self.slot_shapes:
-            if self.spec_topology is not None:
-                self._spec[S] = self._compile_spec(S)
-            else:
-                self._decode[S] = self._compile_decode(S)
+        """One decode executable per slot rung + the prefill bucket grid.
+        Everything else the dense path compiled per bucket (the whole
+        generate loop) is gone from the decode side. A speculative
+        runner's rungs hold the tree-verify step INSTEAD of the plain
+        step (accept >= 1 always, so no plain-step fallback executable
+        is needed: the verified-rejection worst case IS the plain step)."""
+        self.slots.executables = self._compile_rungs()
         for B, L in self.engine._ladder.combos():
             self._prefill[(B, L)] = self._compile_prefill(B, L)
 
-    def _donate(self, *argnums):
-        return _donate_argnums(*argnums)
-
-    def _compile_decode(self, S: int, operands=None, catalog_compile=False):
+    def _compile_rungs(self, operands=None, catalog_compile=False) -> dict:
         eng = self.engine
-        fn = _named(self.head.make_decode_paged_fn(),
-                    f"{self.head.name}_decode_s{S}")
-        ops = operands if operands is not None else self.head.runtime_operands()
-        args = (
-            eng._select(self.head, eng._params),
-            *(_sds(op) for op in ops),  # trie operand: threaded, not baked
-            _sds({k: v[:S] for k, v in self.state.items()}),
-            jax.ShapeDtypeStruct((S,), np.int32),
-            jax.ShapeDtypeStruct((S, self.cfg.pages_per_slot), np.int32),
-            jax.ShapeDtypeStruct((S,), np.int32),
-            _sds(self.pool.k_pools),
-            _sds(self.pool.v_pools),
-        )
-        # Donate the slot-state operand: the write-back in step()
-        # overwrites every row, so the input tree is dead after the call —
-        # undonated, XLA would double-buffer the whole slot ladder's
-        # decode state (graftlint missing_donation; docs/PERF.md note).
-        donate = self._donate(*paged_decode_donate_argnums(len(ops)))
-        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
-        eng.metrics.record_compile(catalog=catalog_compile)
-        return compiled
-
-    def _compile_spec(self, S: int, operands=None, catalog_compile=False):
-        """The tree-verify executable at slot rung S: identical operand
-        surface to the plain decode step (slot state donated the same
-        way), returning (state, accept_len). The tree topology is a
-        static constant of the trace — one topology per rung, the
-        check_spec_hlo pin."""
-        eng = self.engine
-        fn = _named(self.head.make_spec_decode_paged_fn(eng._spec_fanout),
-                    f"{self.head.name}_spec_s{S}")
-        ops = operands if operands is not None else self.head.runtime_operands()
-        args = (
-            eng._select(self.head, eng._params),
-            *(_sds(op) for op in ops),
-            _sds({k: v[:S] for k, v in self.state.items()}),
-            jax.ShapeDtypeStruct((S,), np.int32),
-            jax.ShapeDtypeStruct((S, self.cfg.pages_per_slot), np.int32),
-            jax.ShapeDtypeStruct((S,), np.int32),
-            _sds(self.pool.k_pools),
-            _sds(self.pool.v_pools),
-        )
-        donate = self._donate(*paged_decode_donate_argnums(len(ops)))
-        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
-        eng.metrics.record_compile(catalog=catalog_compile)
-        return compiled
+        out = {}
+        for S in self.slots.rungs:
+            out[S] = self.slots.compile(
+                S, eng._select(self.head, eng._params), operands
+            )
+            eng.metrics.record_compile(catalog=catalog_compile)
+        return out
 
     def _compile_prefill(self, B: int, L: int, operands=None,
                          catalog_compile=False):
@@ -407,7 +337,7 @@ class _PagedRunner:
             _sds(self.pool.v_pools),
         )
         compiled = jax.jit(
-            fn, donate_argnums=self._donate(n + 1, n + 2)  # k_pools, v_pools
+            fn, donate_argnums=_donate_argnums(n + 1, n + 2)  # k_pools, v_pools
         ).lower(*args).compile()
         eng.metrics.record_compile(catalog=catalog_compile)
         return compiled
@@ -512,7 +442,7 @@ class _PagedRunner:
                         self.pool.evict(slot)
                         # Undo any slot bookkeeping a partial prefill set,
                         # or step() would decode an entry-less slot.
-                        self.active[slot] = False
+                        self.slots.release(slot)
                         self.entries[slot] = None
                         self.buckets[slot] = None
                     if self.pool.device_pools_consumed():
@@ -536,11 +466,11 @@ class _PagedRunner:
         keeps admitting. Fail the resident requests with the cause, drop
         the prefix cache, and carry on from fresh zero pools."""
         eng = self.engine
-        lost = [int(s) for s in np.nonzero(self.active)[0]]
+        lost = [int(s) for s in self.slots.active_slots()]
         for slot in lost:
             fut = self.entries[slot][1]
             self.pool.evict(slot)
-            self.active[slot] = False
+            self.slots.release(slot)
             self.entries[slot] = None
             self.buckets[slot] = None
             if not fut.done():
@@ -640,15 +570,12 @@ class _PagedRunner:
         slot = self.pool.admit_shared(centry.pages, centry.n_tokens)
         self.prefix.touch(centry.key)
         centry.hits += 1
-        for key in self.state:
-            self.state[key][slot] = 0
-        if centry.init is not None:
-            init = head.paged_warm_state(centry.init, centry.n_tokens, own_L)
-            for key, val in init.items():
-                self.state[key][slot] = val
+        self.slots.bind(
+            slot,
+            head.paged_warm_state(centry.init, centry.n_tokens, own_L)
+            if centry.init is not None else None,
+        )
         t_admit = time.monotonic()
-        self.steps[slot] = head.paged_init_step
-        self.active[slot] = True
         self.entries[slot] = (*e, t_admit)
         self.buckets[slot] = centry.bucket
         tr = e[3]
@@ -748,10 +675,10 @@ class _PagedRunner:
         compiled = self._prefill.get((B, L))
         if compiled is None:  # off-grid (should not happen): counted
             compiled = self._prefill[(B, L)] = self._compile_prefill(B, L)
-        args = eng._stage(head.make_batch(reqs, B, L))
+        args = _stage(head.make_batch(reqs, B, L), eng._mesh)
         bt = np.zeros((B, self.cfg.pages_per_slot), np.int32)
         bt[: len(slots)] = self.pool.block_tables[slots]
-        bt = eng._stage(bt)
+        bt = _stage(bt, eng._mesh)
         t_launch = time.monotonic()
         k_pools, v_pools, init = compiled(
             eng._select(head, eng._params), *head.runtime_operands(), *args,
@@ -760,10 +687,7 @@ class _PagedRunner:
         t_launched = time.monotonic()
         self.pool.k_pools, self.pool.v_pools = k_pools, v_pools
         n = len(slots)
-        for key in self.state:
-            self.state[key][slots] = 0
-        for key, val in init.items():
-            self.state[key][slots] = np.asarray(val)[:n]
+        self.slots.bind(slots, {k: np.asarray(v)[:n] for k, v in init.items()})
         t_prefilled = time.monotonic()
         inserted = 0
         if self.prefix is not None and keys is not None:
@@ -776,10 +700,7 @@ class _PagedRunner:
             for key, slot in zip(keys, slots):
                 if key is None:
                     continue
-                snapshot = (
-                    {k: np.array(self.state[k][slot]) for k in init}
-                    if init else None
-                )
+                snapshot = self.slots.row(slot, init) if init else None
                 self.prefix.insert(
                     key, n_tokens=int(self.pool.seq_lens[slot]),
                     pages=self.pool.slot_pages(slot),
@@ -799,8 +720,6 @@ class _PagedRunner:
                 ("prefill.retain", t_prefilled, time.monotonic(),
                  {"inserted": inserted}),
             ]
-        self.steps[slots] = head.paged_init_step
-        self.active[slots] = True
         for e, slot in zip(entries, slots):
             self.entries[slot] = (*e, t_admit)
             self.buckets[slot] = (B, L)
@@ -825,120 +744,37 @@ class _PagedRunner:
 
     # -- decode (one fixed-shape step over all slots) ------------------------
 
+    def _trace_of(self, slot):
+        return self.entries[slot][3]
+
     def step(self) -> bool:
-        """Advance every active slot — one decode position through the
-        plain step, or 1..(1 + spec_depth) positions through the
-        tree-verify step when speculation is on. Finished slots resolve
-        their futures and free their pages immediately, so the NEXT
-        admit() can reuse them — eviction mid-decode, no batch barrier."""
-        if self.idle:
-            return False
+        """Advance every active slot through the shared step
+        (serving/slots.py). Finished slots resolve their futures and free
+        their pages immediately, so the NEXT admit() can reuse them —
+        eviction mid-decode, no batch barrier."""
         eng = self.engine
-        spec = self.spec_topology is not None
-        # Smallest compiled slot shape covering the highest active slot
-        # (slots fill lowest-first, so this tracks the active count).
-        hi = int(np.nonzero(self.active)[0][-1]) + 1
-        S = next(s for s in self.slot_shapes if s >= hi)
-        # Host-side operand staging. On spec iterations this interval is
-        # the `draft` span: the drafter's trie expansion executes inside
-        # the verify call, so staging is the only host-visible slice of
-        # the draft phase.
-        t_stage = time.monotonic()
-        args = (
-            eng._select(self.head, eng._params),
-            *self.head.runtime_operands(),
-            eng._stage({k: v[:S] for k, v in self.state.items()}),
-            eng._stage(np.where(self.active[:S], self.steps[:S], 0).astype(np.int32)),
-            eng._stage(self.pool.block_tables[:S]),
-            eng._stage(self.pool.seq_lens[:S]),
-            self.pool.k_pools,
-            self.pool.v_pools,
+        res = self.slots.step(
+            eng._select(self.head, eng._params), eng._tracer,
+            eng._span_ident, self._trace_of,
         )
-        t0 = time.monotonic()
-        if spec:
-            out, accept = self._spec[S](*args)
-        else:
-            out = self._decode[S](*args)
-        t_launched = time.monotonic()
-        for k, v in out.items():  # write back into the host rows
-            self.state[k][:S] = np.asarray(v)
-        active_idx = np.nonzero(self.active)[0]
-        if spec:
-            # Accept lengths ride the same fetch as the state write-back
-            # (device-side bookkeeping — no extra host<->device sync on
-            # the decode step); clamp against remaining codes so a
-            # garbage row can never overshoot a slot's total.
-            total = self.head.paged_total_steps
-            adv = np.minimum(
-                np.asarray(accept)[active_idx],
-                total - self.steps[active_idx],
-            ).astype(np.int32)
-            adv = np.maximum(adv, 1)  # root level is always exact
-        t1 = time.monotonic()
-        live = len(active_idx)
-        kv_tokens = int(self.pool.seq_lens[active_idx].sum())
-        tracing = eng._tracer.enabled
-        if tracing:
-            # One fixed-shape step advances EVERY active slot: each
-            # resident request gets the same interval(s), tagged with its
-            # own position so the span tree reads per-request. Spec
-            # iterations replace the per-code `decode_step` span with
-            # draft -> tree_verify -> accept (scripts/check_obs.py
-            # accepts both shapes).
-            ident = eng._span_ident()
-            for i, slot in enumerate(active_idx):
-                tr = self.entries[slot][3]
-                if tr is None:
-                    continue
-                if spec:
-                    tid, root = tr[0], tr[1]
-                    eng._tracer.record_span(
-                        "draft", tid, t_stage, t0, parent_id=root,
-                        step=int(self.steps[slot]),
-                        drafted=int(self.spec_topology.n_nodes
-                                    - self.spec_topology.beams),
-                        **ident,
-                    )
-                    eng._tracer.record_span(
-                        "tree_verify", tid, t0, t1, parent_id=root,
-                        step=int(self.steps[slot]), slots=S,
-                        accept_len=int(adv[i]), **ident,
-                    )
-                else:
-                    eng._tracer.record_span(
-                        "decode_step", tr[0], t0, t1, parent_id=tr[1],
-                        step=int(self.steps[slot]), slots=S, **ident,
-                    )
-        if spec:
-            self.steps[active_idx] += adv
-            eng.metrics.record_decode_step(S, live, kv_tokens)
+        if res is None:
+            return False
+        eng.metrics.record_decode_step(res.slots, res.live, res.kv_tokens)
+        if res.accept is not None:
             eng.metrics.record_spec(
-                self.head.name,
-                drafted=len(active_idx)
-                * (self.spec_topology.n_nodes - self.spec_topology.beams),
-                accept_lens=adv,
+                self.head.name, drafted=res.drafted, accept_lens=res.accept
             )
-            if tracing:
-                t2 = time.monotonic()
-                ident = eng._span_ident()
-                for i, slot in enumerate(active_idx):
-                    tr = self.entries[slot][3]
-                    if tr is not None:
-                        eng._tracer.record_span(
-                            "accept", tr[0], t1, t2, parent_id=tr[1],
-                            accept_len=int(adv[i]), **ident,
-                        )
-        else:
-            self.steps[self.active] += 1
-            eng.metrics.record_decode_step(S, live, kv_tokens)
         t_sweep = time.monotonic()
         finished = self._sweep_finished()
-        if tracing:
+        if eng._tracer.enabled:
             self._phases += [
-                ("decode.stage", t_stage, t0,
-                 {"slots": S, "live": live, "kv_tokens": kv_tokens}),
-                ("decode.launch", t0, t_launched, {"slots": S}),
-                ("decode.pull", t_launched, t1, {"leaves": len(out)}),
+                ("decode.stage", res.t_stage, res.t0,
+                 {"slots": res.slots, "live": res.live,
+                  "kv_tokens": res.kv_tokens}),
+                ("decode.launch", res.t0, res.t_launched,
+                 {"slots": res.slots}),
+                ("decode.pull", res.t_launched, res.t1,
+                 {"leaves": res.leaves}),
                 ("decode.sweep", t_sweep, time.monotonic(),
                  {"finished": finished}),
             ]
@@ -962,8 +798,7 @@ class _PagedRunner:
     def _sweep_finished(self) -> int:
         eng = self.engine
         head = self.head
-        total = head.paged_total_steps
-        done = np.nonzero(self.active & (self.steps >= total))[0]
+        done = self.slots.finished()
         step_id = eng._step
         # Stable while any slot is active: catalog swaps barrier on slot
         # drain, so every finished request decoded under THIS version.
@@ -972,14 +807,7 @@ class _PagedRunner:
             req, fut, t_enq, tr, t_admit = self.entries[slot]
             t_done = time.monotonic()
             try:
-                # COPY the slot's state row: a bare v[slot] is a numpy
-                # VIEW into the live slot buffer, and the payload arrays
-                # built from it would silently change when the slot is
-                # reused by a later admission (observed as responses
-                # "mixing" catalog versions after a hot swap).
-                payload = head.paged_finalize(
-                    {k: np.array(v[slot]) for k, v in self.state.items()}, req
-                )
+                payload = head.paged_finalize(self.slots.row(slot), req)
                 now = time.monotonic()
                 resp = Response(
                     head=head.name,
@@ -1032,7 +860,7 @@ class _PagedRunner:
                 if not fut.done():
                     fut.set_result(resp)
             self.pool.evict(int(slot))
-            self.active[slot] = False
+            self.slots.release(slot)
             self.entries[slot] = None
             self.buckets[slot] = None
             eng.metrics.record_evict(1)
@@ -1359,17 +1187,7 @@ class ServingEngine:
                 head.name, "prefix_cache_pages",
                 runner.prefix_stats().get("retained_bytes", 0),
             )
-            # Slot state is host-resident numpy between steps but lives
-            # on device during every decode call (and the decode
-            # executable double-buffers what it cannot donate) — budget
-            # it as resident.
-            led.record_operand(
-                head.name, "paged_slot_state", tree_nbytes(runner.state)
-            )
-            for S, ex in runner._decode.items():
-                led.record_executable(head.name, f"decode/S{S}", ex)
-            for S, ex in runner._spec.items():
-                led.record_executable(head.name, f"spec_decode/S{S}", ex)
+            runner.slots.record_memory(led, head.name)
             for (B, L), ex in runner._prefill.items():
                 led.record_executable(head.name, f"prefill/B{B}/L{L}", ex)
         else:
@@ -1668,7 +1486,7 @@ class ServingEngine:
                             self._tracer.record_span(
                                 "batcher.idle_wait", runner.lane, t_wait,
                                 t_woke, seq=self._seq, queued=queued,
-                                live=int(runner.active.sum()), **ident,
+                                live=runner.slots.live, **ident,
                             )
                     if done:
                         # Drained: release every retained prefix page —
@@ -1749,7 +1567,7 @@ class ServingEngine:
         B = self._ladder.batch_bucket(len(reqs))
         cat_version = head.catalog_version  # stable: swaps apply on this thread
         try:
-            args = self._stage(head.make_batch(reqs, B, L))
+            args = _stage(head.make_batch(reqs, B, L), self._mesh)
             compiled = self._get_executable(head, B, L)
             out = compiled(
                 self._select(head, self._params), *head.runtime_operands(), *args
@@ -1816,17 +1634,6 @@ class ServingEngine:
 
     def _select(self, head, params):
         return params[head.name] if self._params_by_head else params
-
-    def _stage(self, tree):
-        """Per-call operands (batch arrays, slot state, step vectors) on
-        their way into a compiled executable. Single device: device
-        arrays, as always. Under a mesh: HOST arrays — the executable
-        places them to its expected (replicated) sharding at dispatch,
-        whereas a device-0-committed jnp array would be rejected as a
-        sharding mismatch by the mesh-lowered executable."""
-        if self._mesh is None:
-            return jax.tree_util.tree_map(jnp.asarray, tree)
-        return jax.tree_util.tree_map(np.asarray, tree)
 
     def _get_executable(self, head, B: int, L: int):
         key = (head.name, B, L)
@@ -2075,26 +1882,14 @@ class ServingEngine:
         these)."""
         runner = self._runners.get(head.name)
         if runner is not None:
-            if runner.spec_topology is not None:
-                decode = {}
-                spec = {
-                    S: runner._compile_spec(S, operands=operands,
-                                            catalog_compile=True)
-                    for S in runner.slot_shapes
-                }
-            else:
-                decode = {
-                    S: runner._compile_decode(S, operands=operands,
-                                              catalog_compile=True)
-                    for S in runner.slot_shapes
-                }
-                spec = {}
+            decode = runner._compile_rungs(operands=operands,
+                                           catalog_compile=True)
             prefill = {
                 (B, L): runner._compile_prefill(B, L, operands=operands,
                                                 catalog_compile=True)
                 for B, L in self._ladder.combos()
             }
-            return None, (decode, prefill, spec)
+            return None, (decode, prefill)
         dense = {
             (head.name, B, L): self._compile(
                 head, B, L, operands=operands, install=False,
@@ -2130,7 +1925,7 @@ class ServingEngine:
                 self._exec.update(dense_exec)
             runner = self._runners.get(name)
             if runner is not None and runner_exec is not None:
-                runner._decode, runner._prefill, runner._spec = runner_exec
+                runner.slots.executables, runner._prefill = runner_exec
             self.metrics.record_catalog_swap()
             # Re-ledger the swapped head: the trie operand changed size
             # and a rung growth installed new executables. Post-warmup

@@ -1304,7 +1304,7 @@ def _graftlint_dense_entry() -> BuiltEntry:
 @register_entry("serve/tiger_paged_decode_step", tags=("serving", "paged"))
 def _graftlint_paged_decode_entry() -> BuiltEntry:
     """The collapsed-shape paged decode step, jitted like
-    _PagedRunner._compile_decode on TPU (donation on; the engine only
+    serving/slots.SlotTable.compile on TPU (donation on; production only
     disables it on CPU to silence the no-op warning). The slot-state
     operand is overwritten by the write-back every step — undonated it
     would double-buffer the whole slot ladder. The trie rides as a

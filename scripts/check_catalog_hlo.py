@@ -52,7 +52,7 @@ def _executable_hlos(engine, head_name):
     texts = []
     runner = engine._runners.get(head_name)
     if runner is not None:
-        texts += [c.as_text() for c in runner._decode.values()]
+        texts += [c.as_text() for c in runner.slots.executables.values()]
         texts += [c.as_text() for c in runner._prefill.values()]
     texts += [
         c.as_text() for (h, _b, _l), c in engine._exec.items() if h == head_name

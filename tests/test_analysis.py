@@ -673,22 +673,38 @@ class TestRepoInvariants:
                 )
 
     def test_paged_decode_compile_donates_slot_state(self):
-        """The engine's decode jit donates the slot-state operand (the
-        PR-8 donation-audit fix) — checked at the source level so the
-        fix cannot silently regress on CPU where _donate() disables
-        donation."""
-        src = open(os.path.join(REPO, "genrec_tpu", "serving", "engine.py")).read()
+        """The ONE function that jits a paged decode rung
+        (`SlotTable.compile`, for the engine and the disagg worker alike)
+        donates the slot-state operand (the PR-8 donation-audit fix) —
+        checked at the source level so the fix cannot silently regress on
+        CPU where donate_argnums() disables donation."""
+        src = open(os.path.join(REPO, "genrec_tpu", "serving", "slots.py")).read()
         tree = ast.parse(src)
         fn = next(
             node for node in ast.walk(tree)
-            if isinstance(node, ast.FunctionDef) and node.name == "_compile_decode"
+            if isinstance(node, ast.FunctionDef) and node.name == "compile"
         )
         jit_calls = [
             node for node in ast.walk(fn)
             if isinstance(node, ast.Call) and lint._dotted(node.func) == "jax.jit"
         ]
-        assert jit_calls, "_compile_decode no longer jits directly"
+        assert jit_calls, "SlotTable.compile no longer jits directly"
         assert any(
             any(kw.arg == "donate_argnums" for kw in call.keywords)
             for call in jit_calls
-        ), "_compile_decode lost its donate_argnums"
+        ), "SlotTable.compile lost its donate_argnums"
+
+    @pytest.mark.parametrize("rel", ["serving/engine.py", "disagg/workers.py"])
+    def test_slot_state_lives_in_the_slot_table_only(self, rel):
+        """Where the slot state lives between steps and what a step pulls
+        is serving/slots.py's to decide: neither holder of a `SlotTable`
+        reads a state tree, a step vector or an active mask, or builds a
+        decode step function, of its own."""
+        tree = ast.parse(open(os.path.join(REPO, "genrec_tpu", rel)).read())
+        own = {"state", "_state", "steps", "_steps", "active", "_active"}
+        factories = {"make_decode_paged_fn", "make_spec_decode_paged_fn"}
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Attribute):
+                assert node.attr not in own | factories, (
+                    f"{rel}:{node.lineno} reaches for .{node.attr}"
+                )

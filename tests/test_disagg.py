@@ -525,6 +525,9 @@ def test_decode_worker_hbm_budget_refuses_at_warmup(
             front.pump_once()
             if fut.done():
                 break
+            # A lone request (1 < max_batch) waits out the 1 ms coalescing
+            # deadline; 200 idle pumps of a warm process take less.
+            time.sleep(0.002)
         fut.result(1)
         st = front.stats()
         roles = st["disagg"]["roles"]["tiger"]

@@ -254,7 +254,7 @@ def test_every_executable_has_a_name_of_its_own(engine):
     from genrec_tpu.serving import BucketLadder, RetrievalHead, ServingEngine
 
     runner = engine._runners["tiger"]
-    names = {f"decode_s{S}": _module_name(c) for S, c in runner._decode.items()}
+    names = {f"decode_s{S}": _module_name(c) for S, c in runner.slots.executables.items()}
     names.update({f"prefill_b{B}_l{L}": _module_name(c)
                   for (B, L), c in runner._prefill.items()})
     assert len(names) == 4
@@ -314,7 +314,7 @@ def test_speculative_engine_leaves_the_same_lane():
                            history=np.array([0, 1, 2]))).result(120)
         runner = eng._runners["tiger"]
         assert runner.spec_topology is not None
-        assert {_module_name(c) for c in runner._spec.values()} == {
+        assert {_module_name(c) for c in runner.slots.executables.values()} == {
             "jit_tiger_spec_s1"}
         st = eng.stats()
         assert st["decode_slot_steps"] == st["decode_steps"] > 0
