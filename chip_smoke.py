@@ -400,6 +400,38 @@ def _check_pool_stays_put(runner) -> dict:
     return pool_ops
 
 
+def _check_slot_state_stays_put(runner) -> dict:
+    """The slot state lives on the device between steps: a rung below the
+    whole table takes the table donated and writes its rows back in
+    place, so its compiled text must produce no value of the largest
+    state leaf's size (a suffix cache) by a copy, a transpose or a
+    convert. (At the top rung the step's own output IS the whole leaf.)
+    Judged on the chip only: the CPU backend has no donation, so there
+    the in-place write is a copy by construction."""
+    import jax
+
+    from genrec_tpu.analysis.ir import hlo_ops_of_size
+
+    table = runner.slots
+    shapes = jax.eval_shape(
+        lambda: runner.head.paged_state_zeros(runner.cfg.max_slots))
+    name, leaf = max(shapes.items(), key=lambda kv: math.prod(kv[1].shape))
+    print(f"chip_smoke: largest slot-state leaf {name} "
+          f"{leaf.dtype}{list(leaf.shape)}", flush=True)
+    check(table.writer is not None, "the slot table holds no row-write program")
+    state_ops = {}
+    for S, exe in table.executables.items():
+        if S == runner.cfg.max_slots:
+            continue
+        ops = hlo_ops_of_size(exe.as_text(), math.prod(leaf.shape))
+        moved = [line for op, line in ops
+                 if op in ("copy", "transpose", "convert")]
+        if jax.default_backend() == "tpu":
+            check(not moved, f"decode_s{S} moves a whole {name}", moved[:2])
+        state_ops[f"decode_s{S}"] = sorted({op for op, _ in ops})
+    return state_ops
+
+
 def phase_serve(model, params, item_sem_ids, cfg: dict) -> dict:
     """Build, warm, query, drain and stop one paged engine."""
     import numpy as np
@@ -439,6 +471,7 @@ def phase_serve(model, params, item_sem_ids, cfg: dict) -> dict:
         np.testing.assert_array_equal(warm.items, cold[1].items)
         np.testing.assert_array_equal(warm.scores, cold[1].scores)
         pool_ops = _check_pool_stays_put(engine._runners[head.name])
+        state_ops = _check_slot_state_stays_put(engine._runners[head.name])
     finally:
         stats = engine.stop()
     check(stats["completed"] == len(requests) + 1, stats["completed"])
@@ -454,6 +487,7 @@ def phase_serve(model, params, item_sem_ids, cfg: dict) -> dict:
         "warm_prefix_hits": prefix["hits"],
         "pool_pages_total": pool["pages_in_use"] + pool["pages_free"],
         "pool_sized_ops": pool_ops,
+        "slot_state_sized_ops": state_ops,
         "paged_config": [paged_config.max_slots, BEAMS, model.num_heads,
                          model.attn_dim // model.num_heads,
                          paged_config.page_size, paged_config.pages_per_slot],

@@ -23,7 +23,7 @@ itself instead of the production path.
 
 ``expect_donated`` lists the argnums whose buffers are dead after the
 call in production (train state consumed by the step, decode slot state
-overwritten by the write-back). The donation audit reports any of these
+replaced by the step's output). The donation audit reports any of these
 that the jit does NOT donate as wasted HBM (one dead copy of the buffer
 kept alive across the call).
 

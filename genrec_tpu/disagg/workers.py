@@ -685,6 +685,8 @@ class DecodeWorker:
         for S in self.slots.rungs:
             self.slots.executables[S] = self.slots.compile(S, self.params)
             self._count_compile()
+        self.slots.compile_writer()
+        self._count_compile()
         self.transport.prepare_admit(self.pool, self._count_transport_compile)
         self._ledger()
         self._warm = True

@@ -36,8 +36,8 @@ def donate_argnums(*argnums):
 
 def paged_decode_donate_argnums(n_operands: int) -> tuple:
     """Argnums the paged decode step donates: the slot state, which is
-    dead after every call (step() overwrites it from the executable's
-    output). The paged signature is (params, *catalog operands, state,
+    dead after every call (the table the executable returns takes its
+    place on the device). The paged signature is (params, *catalog operands, state,
     ...), so the state's index follows the head's operand count — a fixed
     index would donate params or a trie for a head with none or two. The
     operands (catalog.TensorTrie) are threaded, NOT donated: they survive

@@ -184,8 +184,8 @@ class _PagedRunner:
     (slot-count, pages_per_slot) shapes.
 
     All methods run on the batcher thread (same single-writer discipline
-    as the executable cache); slot state is host-resident numpy between
-    steps, pools stay device-resident.
+    as the executable cache); slot state (serving/slots.py) and pools
+    stay device-resident between steps.
     """
 
     def __init__(self, engine: "ServingEngine", head, cfg: PagedConfig):
@@ -307,6 +307,8 @@ class _PagedRunner:
         step (accept >= 1 always, so no plain-step fallback executable
         is needed: the verified-rejection worst case IS the plain step)."""
         self.slots.executables = self._compile_rungs()
+        self.slots.compile_writer()
+        self.engine.metrics.record_compile()
         for B, L in self.engine._ladder.combos():
             self._prefill[(B, L)] = self._compile_prefill(B, L)
 
@@ -687,7 +689,11 @@ class _PagedRunner:
         t_launched = time.monotonic()
         self.pool.k_pools, self.pool.v_pools = k_pools, v_pools
         n = len(slots)
-        self.slots.bind(slots, {k: np.asarray(v)[:n] for k, v in init.items()})
+        # The init rows come to the host (one fetch): the table stages
+        # them with its next row write, and the prefix cache snapshots
+        # them below.
+        init = {k: v[:n] for k, v in jax.device_get(init).items()}
+        self.slots.bind(slots, init)
         t_prefilled = time.monotonic()
         inserted = 0
         if self.prefix is not None and keys is not None:
@@ -697,10 +703,13 @@ class _PagedRunner:
             # — the rest are zeroed again at warm admit), so the run
             # outlives its donor slot and a repeat request skips
             # prefill. Replacing a same-key entry drops the old refs.
-            for key, slot in zip(keys, slots):
+            for i, (key, slot) in enumerate(zip(keys, slots)):
                 if key is None:
                     continue
-                snapshot = self.slots.row(slot, init) if init else None
+                snapshot = (
+                    {k: np.array(v[i]) for k, v in init.items()}
+                    if init else None
+                )
                 self.prefix.insert(
                     key, n_tokens=int(self.pool.seq_lens[slot]),
                     pages=self.pool.slot_pages(slot),
@@ -770,11 +779,12 @@ class _PagedRunner:
             self._phases += [
                 ("decode.stage", res.t_stage, res.t0,
                  {"slots": res.slots, "live": res.live,
-                  "kv_tokens": res.kv_tokens}),
+                  "kv_tokens": res.kv_tokens,
+                  "staged_bytes": res.staged_bytes}),
                 ("decode.launch", res.t0, res.t_launched,
                  {"slots": res.slots}),
                 ("decode.pull", res.t_launched, res.t1,
-                 {"leaves": res.leaves}),
+                 {"leaves": res.leaves, "pulled_bytes": res.pulled_bytes}),
                 ("decode.sweep", t_sweep, time.monotonic(),
                  {"finished": finished}),
             ]

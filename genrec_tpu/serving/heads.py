@@ -57,7 +57,15 @@ class Head:
       seq_lens);
     - ``paged_init_step`` / ``paged_total_steps``: a slot enters decode at
       init_step and finishes when its step counter reaches total_steps;
-    - ``paged_state_zeros(n_slots)``: the slot-major decode-state dict;
+    - ``paged_state_zeros(n_slots)``: the slot-major decode-state dict,
+      zeroed; `serving/slots.SlotTable` places it on the device and keeps
+      it there between steps;
+    - ``paged_result_leaves``: the state leaves ``paged_finalize`` reads,
+      the only ones a decode step brings back to the host;
+    - ``paged_init_leaves``: the state leaves the prefill's ``init`` (and
+      so a warm admit or a handoff) may carry, the only ones a bind
+      stages from the host; every other leaf of a bound row is zeroed on
+      the device;
     - ``make_prefill_paged_fn(B, L)``: compiled per (batch, history)
       bucket — signature (params, *runtime_operands, *batch,
       block_tables, k_pools, v_pools): runs the encoder/prefill, WRITES
@@ -392,6 +400,14 @@ class TigerGenerativeHead(Head):
     def paged_total_steps(self) -> int:
         return self.model.sem_id_dim
 
+    paged_result_leaves = ("beam_seqs", "beam_logps")
+
+    @property
+    def paged_init_leaves(self) -> tuple:
+        # The plain prefill leaves the state zeroed; the speculative one
+        # hands the drafter its step-0 logit window.
+        return ("logits0",) if getattr(self, "_spec_draft_hint", False) else ()
+
     def paged_layout(self):
         m = self.model
         return m.n_layers // 2, m.num_heads, m.attn_dim // m.num_heads, m.dtype
@@ -403,15 +419,10 @@ class TigerGenerativeHead(Head):
     def paged_state_zeros(self, n_slots: int) -> dict:
         from genrec_tpu.models.tiger import init_tiger_paged_state
 
-        # np.array (copy): the runner mutates these rows in place, and a
-        # numpy view of a jax buffer is read-only.
-        return {
-            k: np.array(v)
-            for k, v in init_tiger_paged_state(
-                self.model, n_slots, self.top_k,
-                draft_hint=getattr(self, "_spec_draft_hint", False),
-            ).items()
-        }
+        return init_tiger_paged_state(
+            self.model, n_slots, self.top_k,
+            draft_hint=getattr(self, "_spec_draft_hint", False),
+        )
 
     def make_prefill_paged_fn(self, B: int, L: int):
         from genrec_tpu.models.tiger import tiger_prefill_paged
@@ -701,6 +712,11 @@ class CobraGenerativeHead(Head):
     def paged_total_steps(self) -> int:
         return self.model.n_codebooks
 
+    paged_result_leaves = ("beam_tokens", "beam_scores")
+    #: What `cobra_prefill_paged` returns as ``init``.
+    paged_init_leaves = ("beam_tokens", "beam_scores", "prefix_idx",
+                         "tail_hidden", "full", "base_pos", "h_last")
+
     def paged_layout(self):
         m = self.model
         return (
@@ -715,10 +731,7 @@ class CobraGenerativeHead(Head):
     def paged_state_zeros(self, n_slots: int) -> dict:
         from genrec_tpu.models.cobra import init_cobra_paged_state
 
-        return {
-            k: np.array(v)  # copy: the runner mutates rows in place
-            for k, v in init_cobra_paged_state(self.model, n_slots, self.top_k).items()
-        }
+        return init_cobra_paged_state(self.model, n_slots, self.top_k)
 
     def make_prefill_paged_fn(self, B: int, L: int):
         from genrec_tpu.models.cobra import cobra_prefill_paged
@@ -1306,8 +1319,8 @@ def _graftlint_paged_decode_entry() -> BuiltEntry:
     """The collapsed-shape paged decode step, jitted like
     serving/slots.SlotTable.compile on TPU (donation on; production only
     disables it on CPU to silence the no-op warning). The slot-state
-    operand is overwritten by the write-back every step — undonated it
-    would double-buffer the whole slot ladder. The trie rides as a
+    operand is replaced by the step's output every step — undonated it
+    would double-buffer the whole slot table. The trie rides as a
     runtime operand at argnum 1 (catalog.TensorTrie) — NOT donated, it
     survives across every step — and the 256 B constant threshold now
     asserts the old baked-table debt stays retired."""
@@ -1318,7 +1331,7 @@ def _graftlint_paged_decode_entry() -> BuiltEntry:
     cfg = PagedConfig(max_slots=4, page_size=8, pages_per_slot=2)
     pool = KVPagePool(cfg, *head.paged_layout())
     S = cfg.max_slots
-    state = {k: jnp.asarray(v) for k, v in head.paged_state_zeros(S).items()}
+    state = head.paged_state_zeros(S)
     # Same donate argnums production compiles (engine shares the
     # function); donation is requested unconditionally here because the
     # audit reads the declaration, which CPU lowering preserves.
@@ -1333,9 +1346,9 @@ def _graftlint_paged_decode_entry() -> BuiltEntry:
         pool.k_pools, pool.v_pools,
     )
     # expect_donated stays a LITERAL, independent of the shared function:
-    # it states which buffers are dead (a fact about step()'s write-back:
-    # params 0, trie 1, slot state 2), so a paged_decode_donate_argnums
-    # that stops naming the state fails the audit instead of both sides
-    # silently agreeing on "no donation".
+    # it states which buffers are dead (a fact about step(), which keeps
+    # the output in the input's place: params 0, trie 1, slot state 2),
+    # so a paged_decode_donate_argnums that stops naming the state fails
+    # the audit instead of both sides silently agreeing on "no donation".
     return BuiltEntry(fn=fn, args=args, expect_donated=(2,),
                       max_const_bytes=256)

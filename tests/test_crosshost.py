@@ -392,10 +392,16 @@ def test_tp_item_topk_parity_and_row_sharding():
             assert emb.sharding.spec == P("model", None)
 
 
-def test_tp_paged_decode_parity_and_kv_sharding():
+@pytest.mark.parametrize("replay", [False, True],
+                         ids=["cold", "warm_admit_and_reused_slot"])
+def test_tp_paged_decode_parity_and_kv_sharding(replay):
     """mesh= on the paged TIGER engine: sem-ids bit-identical to
     single-device, the KV page bank sharded over the head axis (spec
-    pin — JAX normalizes trailing Nones, so compare the prefix)."""
+    pin — JAX normalizes trailing Nones, so compare the prefix). With
+    ``replay`` the first two requests come again once all four have
+    answered: warm admits into slots already used (two slots), so the
+    replicated slot table is written through `bind` on both paths as
+    well as advanced by `step`."""
     from jax.sharding import NamedSharding
 
     from genrec_tpu.serving import ServingEngine
@@ -412,6 +418,8 @@ def test_tp_paged_decode_parity_and_kv_sharding():
         )
         eng.start()
         out = [f.result(120) for f in [eng.submit(r) for r in reqs]]
+        if replay:
+            out += [f.result(120) for f in [eng.submit(r) for r in reqs[:2]]]
         stats = eng.stats()
         return out, stats, eng
 
@@ -422,10 +430,22 @@ def test_tp_paged_decode_parity_and_kv_sharding():
         assert np.array_equal(np.asarray(b.sem_ids), np.asarray(t.sem_ids))
         np.testing.assert_allclose(np.asarray(b.scores),
                                    np.asarray(t.scores), rtol=0, atol=1e-5)
+    if replay:
+        for first, again in zip(tp[:2], tp[4:]):
+            assert np.array_equal(np.asarray(first.sem_ids),
+                                  np.asarray(again.sem_ids))
+        # The default pool is the slots' own pages, so retained runs are
+        # reclaimed under pressure: at least one replay still lands warm.
+        assert tstats["prefix_cache"]["tiger"]["hits"] >= 1
+        assert tstats["admits"] == 6  # through CFG's two slots
     assert tstats["recompilations"] == 0
     ksh = eng._runners["tiger"].pool.k_pools[0].sharding
     assert isinstance(ksh, NamedSharding)
     assert tuple(ksh.spec)[:3] == (None, None, "model"), ksh.spec
+    table = eng._runners["tiger"].slots
+    assert all(leaf.sharding.is_fully_replicated and
+               len(leaf.sharding.device_set) == 4
+               for leaf in table._state.values())
     eng.stop()
 
 

@@ -338,6 +338,95 @@ def test_paged_continuous_batching_churn_under_pool_pressure(zoo, corpus, rng):
         eng.stop()
 
 
+@pytest.mark.parametrize("name,drafting", [
+    ("tiger", False), ("tiger", True), ("cobra", False)])
+def test_paged_heads_name_the_leaves_prefill_writes_and_finalize_reads(
+        zoo, corpus, name, drafting):
+    """The slot table stages the head's `paged_init_leaves` and fetches its
+    `paged_result_leaves` and nothing else, so the two must be what the
+    prefill's ``init`` carries and what `paged_finalize` reads."""
+    from genrec_tpu.serving.kv_pool import KVPagePool
+
+    models, params = zoo
+    valid, item_text = corpus
+    head = (TigerGenerativeHead(models[name], valid, top_k=4, name=name)
+            if name == "tiger" else
+            CobraGenerativeHead(models[name], valid, item_text_tokens=item_text,
+                                top_k=4, name=name))
+    if drafting:
+        head.enable_spec_drafting()
+    head.on_params(params[name])  # as the engine does before it compiles
+    pool = KVPagePool(PagedConfig(max_slots=2, page_size=8, pages_per_slot=4),
+                      *head.paged_layout())
+    _, _, init = jax.eval_shape(
+        head.make_prefill_paged_fn(1, 8), params[name],
+        *head.runtime_operands(),
+        *head.make_batch([head.dummy_request()], 1, 8),
+        np.zeros((1, 4), np.int32), pool.k_pools, pool.v_pools)
+    assert set(init) == set(head.paged_init_leaves)
+    state = head.paged_state_zeros(2)
+    assert set(init) | set(head.paged_result_leaves) <= set(state)
+    row = {k: np.asarray(state[k][0]) for k in head.paged_result_leaves}
+    assert head.paged_finalize(row, head.dummy_request())["items"].shape == (4,)
+
+
+@pytest.mark.serving_smoke
+@pytest.mark.parametrize("name", ["tiger", "cobra"])
+def test_paged_answers_are_the_dense_path_s_under_cold_warm_and_reused_slots(
+        zoo, corpus, name):
+    """A fixed churn through the device-resident slot table: six cold
+    requests through four slots (so slots and both rungs are reused),
+    then every one again (warm admits racing two more cold ones). Each
+    answer, whichever slot and admission path it took, is the dense
+    whole-batch path's answer to the same request served alone."""
+    models, params = zoo
+    valid, item_text = corpus
+
+    def head():
+        if name == "tiger":
+            return TigerGenerativeHead(models[name], valid, top_k=4, name=name)
+        return CobraGenerativeHead(models[name], valid, item_text_tokens=item_text,
+                                   top_k=4, name=name)
+
+    draw = np.random.default_rng(11)
+    reqs = [Request(head=name, history=draw.integers(0, len(valid), n),
+                    user_id=3 + i)
+            for i, n in enumerate((5, 2, 8, 3, 7, 4, 6, 1))]
+    ladder = dict(ladder=BucketLadder((1, 2), (8,)), max_batch=2,
+                  max_wait_ms=1.0, handle_signals=False)
+    dense = ServingEngine([head()], params[name], paged=False, **ladder).start()
+    try:
+        want = [dense.serve(r, timeout=120) for r in reqs]
+    finally:
+        dense.stop()
+    eng = ServingEngine(
+        [head()], params[name], **ladder,
+        paged_config=PagedConfig(max_slots=4, page_size=8, pages_per_slot=4,
+                                 num_pages=60),
+    ).start()
+    try:
+        cold = [f.result(120) for f in [eng.submit(r) for r in reqs[:6]]]
+        again = [f.result(120) for f in
+                 [eng.submit(r) for r in (*reqs[:3], reqs[6], *reqs[3:6], reqs[7])]]
+        got = dict(zip((0, 1, 2, 6, 3, 4, 5, 7), again))
+        for i, r in enumerate(cold):
+            got.setdefault(("cold", i), r)
+        for key, r in got.items():
+            ref = want[key[1] if isinstance(key, tuple) else key]
+            np.testing.assert_array_equal(r.sem_ids, ref.sem_ids, err_msg=str(key))
+            np.testing.assert_array_equal(r.items, ref.items, err_msg=str(key))
+            np.testing.assert_allclose(r.scores, ref.scores, atol=1e-5,
+                                       err_msg=str(key))
+        st = eng.stats()
+        assert st["recompilations"] == 0
+        assert st["admits"] == 14 and st["evictions"] == 14  # 4 slots: reused
+        assert st["prefix_cache"][name]["hits"] == 6  # the replays landed warm
+    finally:
+        final = eng.stop()
+    pool = final["kv_pool"][name]
+    assert pool["pages_in_use"] == 0 and pool["slots_active"] == 0
+
+
 @pytest.mark.serving_smoke
 def test_paged_drain_chaos_sigterm_midchurn(zoo, corpus, rng):
     """SIGTERM lands mid decode-churn (chaos fires after the 2nd decode
