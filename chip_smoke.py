@@ -27,7 +27,14 @@ random weights from a seed, synthetic data from a seed:
    attention over 8,192-slot rows, dropless experts on the 16 held of
    128), then the trainer's constrained-beam evaluate through the cache
    that holds the indexer's keys.
-5. **four_chip** — with four or more chips: the trainer data parallel
+5. **lcrec_kimi_linear** — the same on
+   `config/lcrec/kimi_linear_48b_a3b.gin`: two optimizer steps of the cut
+   Kimi-Linear language model at its published widths (the chunked KDA
+   scan, NoPE latent attention, sigmoid-routed experts on the 8 held of
+   256 beside the shared one; rows of 2,048 slots, which is traffic and
+   not a width), then the evaluate through the cache that holds a
+   recurrent state or a latent row by layer kind.
+6. **four_chip** — with four or more chips: the trainer data parallel
    over four and at tensor_parallel=2, against a one-chip step of the
    same seed. On fewer chips the leg prints that it did not run. The
    engine with ``mesh=`` is left out of it, by name: see `LEFT_OUT`.
@@ -86,6 +93,27 @@ KEYE_REHEARSE_BINDINGS = {
     "moe_experts_held": 4, "codebook_size": 8, "num_codebooks": 3,
     "vocab_rows": 0, "max_text_len": 96, "amp": False,
 }
+#: The second LCRec backbone: the cut Kimi-Linear language model at its
+#: published widths (config/lcrec/kimi_linear_48b_a3b.gin). One row a step is
+#: what fits; rows of 2,048 slots keep the leg short (the benchmark cell
+#: kimi_linear_sft_lifelong runs the 8,192).
+KIMI_GIN = os.path.join(REPO, "config", "lcrec", "kimi_linear_48b_a3b.gin")
+KIMI_BINDINGS = {"epochs": 1, "max_eval_samples": 2, "eval_every_epoch": 1,
+                 "max_text_len": 2048}
+KIMI_REHEARSE_BINDINGS = {
+    "hidden_size": 32, "intermediate_size": 64, "num_heads": 4,
+    "num_kv_heads": 4, "head_dim": 8, "kda_heads": 2, "kda_head_dim": 8,
+    "kv_lora_rank": 16, "qk_nope_head_dim": 8,
+    "qk_rope_head_dim": 4, "v_head_dim": 8, "sparse_chunk": 32,
+    "num_experts": 8, "num_experts_per_tok": 2, "moe_intermediate_size": 16,
+    "moe_experts_held": 4, "codebook_size": 8, "num_codebooks": 3,
+    "vocab_rows": 0, "max_text_len": 96, "amp": False,
+}
+KEYE_WIDTHS = ("hidden_size", "n_layers", "num_experts", "moe_experts_held",
+               "sparse_topk", "max_text_len", "vocab_rows")
+KIMI_WIDTHS = ("hidden_size", "n_layers", "kda_layers", "mla_layers",
+               "num_experts", "moe_experts_held", "n_shared_experts",
+               "max_text_len", "vocab_rows")
 #: Not run, and why. `ServingEngine(mesh=...)` at model axis 2 was tried on
 #: a four-chip host (PR 21): params and pools shard, then warmup() dies in
 #: `SlotTable.compile` — GSPMD meets the paged pallas_call and jax raises
@@ -311,25 +339,24 @@ def phase_train(entry: dict, save_dir: str, bindings: dict, on_tpu: bool):
     return model, data, params, cfg
 
 
-def phase_lcrec_keye(entry: dict, save_dir: str, rehearse: bool) -> None:
-    """`lcrec_trainer.train()` on the Keye gin the way a user runs it: two
-    optimizer steps of the cut (indexer-selected attention, dropless
-    experts on the 16 held of 128), then the trainer's evaluate, whose
-    constrained beam runs through the cache that holds the indexer's keys."""
+def phase_lcrec(entry: dict, save_dir: str, gin: str, bindings: dict,
+                widths: tuple, min_rows: int) -> None:
+    """`lcrec_trainer.train()` on a backbone's gin the way a user runs it:
+    two optimizer steps of the cut, then the trainer's evaluate, whose
+    constrained beam runs through the backbone's cache (the indexer's keys
+    for Keye; a recurrent state or a latent row by layer kind for Kimi)."""
     import jax
 
     from genrec_tpu import configlib
     from genrec_tpu.configlib.parser import clear_macros
     from genrec_tpu.trainers import lcrec_trainer
 
-    rows = max(2, jax.device_count())
-    bindings = {**KEYE_BINDINGS, "batch_size": rows, "eval_batch_size": rows,
+    rows = max(min_rows, jax.device_count())
+    bindings = {**bindings, "batch_size": rows, "eval_batch_size": rows,
                 "max_train_samples": 2 * rows, "save_dir_root": save_dir}
-    if rehearse:
-        bindings.update(KEYE_REHEARSE_BINDINGS)
     configlib.clear_bindings()
     clear_macros()
-    argv = [KEYE_GIN]
+    argv = [gin]
     for key, value in bindings.items():
         argv += ["--gin", f"train.{key}={value!r}"]
     configlib.parse_config(argv)
@@ -345,10 +372,23 @@ def phase_lcrec_keye(entry: dict, save_dir: str, rehearse: bool) -> None:
     entry.update(
         steps=len(losses), loss_first=round(losses[0], 4),
         loss_last=round(losses[-1], 4),
-        widths={k: cfg[k] for k in ("hidden_size", "n_layers", "num_experts",
-                                    "moe_experts_held", "sparse_topk",
-                                    "max_text_len", "vocab_rows")},
+        widths={k: cfg[k] for k in widths},
     )
+
+
+def phase_lcrec_keye(entry: dict, save_dir: str, rehearse: bool) -> None:
+    """Indexer-selected attention, dropless experts on the 16 held of 128."""
+    phase_lcrec(entry, save_dir, KEYE_GIN,
+                {**KEYE_BINDINGS, **(KEYE_REHEARSE_BINDINGS if rehearse else {})},
+                KEYE_WIDTHS, min_rows=2)
+
+
+def phase_lcrec_kimi_linear(entry: dict, save_dir: str, rehearse: bool) -> None:
+    """The chunked KDA scan, NoPE latent attention, sigmoid-routed experts on
+    the 8 held of 256 beside the shared one; one row a step is what fits."""
+    phase_lcrec(entry, save_dir, KIMI_GIN,
+                {**KIMI_BINDINGS, **(KIMI_REHEARSE_BINDINGS if rehearse else {})},
+                KIMI_WIDTHS, min_rows=1)
 
 
 def _check_responses(responses, item_sem_ids) -> None:
@@ -629,6 +669,9 @@ def main(argv=None) -> int:
         with phases.phase("lcrec_keye") as entry:
             phase_lcrec_keye(entry, os.path.join(out, "lcrec_keye"),
                              rehearse=args.rehearse)
+        with phases.phase("lcrec_kimi_linear") as entry:
+            phase_lcrec_kimi_linear(entry, os.path.join(out, "lcrec_kimi_linear"),
+                                    rehearse=args.rehearse)
         if device["count"] >= 4:
             with phases.phase("four_chip") as entry:
                 phase_four_chip(entry, out, bindings)

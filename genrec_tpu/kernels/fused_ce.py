@@ -130,6 +130,15 @@ def _dw_kernel(v_ref, x_ref, w_ref, tgt_ref, lse_ref, g_ref, dw_ref,
     )
 
 
+def _vocab_block(d: int) -> int:
+    """Vocabulary rows a tile: 512 while a (tile, d) float32 block is at
+    most 4 MiB (d <= 2,048), else 256. The dw kernel holds its output block
+    twice beside the head's tile and the product: at d = 2,304 and 512 rows
+    that is 16.26 MB of the 16 MB a kernel may scope (the chip's compiler
+    refused it; PERF.md section 6, PR 34)."""
+    return 512 if _round_up(d, 128) <= 2048 else 256
+
+
 def _prep(x, w, targets, blk_r, blk_v):
     R, d = x.shape
     V = w.shape[0]
@@ -151,7 +160,7 @@ def _vlim_operand(V, vlim):
 _VLIM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def fused_linear_ce_fwd(x, w, targets, ignore_index=0, blk_r=128, blk_v=512,
+def fused_linear_ce_fwd(x, w, targets, ignore_index=0, blk_r=128, blk_v=None,
                         interpret: bool = False, vlim=None):
     """Per-row CE losses (0 at ignored rows) and per-row logsumexp.
 
@@ -160,6 +169,7 @@ def fused_linear_ce_fwd(x, w, targets, ignore_index=0, blk_r=128, blk_v=512,
     cols at/past it are excluded from the softmax (head pad rows under TP).
     Returns (loss (R,) f32, lse (R,) f32)."""
     interpret = resolve_interpret(interpret, "fused_linear_ce[fwd]")
+    blk_v = blk_v or _vocab_block(x.shape[1])
     xf, wf, tf, R, V, Rp, Vp, dp = _prep(x, w, targets, blk_r, blk_v)
     n_rb, n_vb = Rp // blk_r, Vp // blk_v
 
@@ -193,11 +203,12 @@ def fused_linear_ce_fwd(x, w, targets, ignore_index=0, blk_r=128, blk_v=512,
 
 
 def fused_linear_ce_bwd(x, w, targets, lse, g, ignore_index=0, blk_r=128,
-                        blk_v=512, interpret: bool = False, vlim=None):
+                        blk_v=None, interpret: bool = False, vlim=None):
     """(dx, dw) for the fused CE. g: (R,) cotangent of the per-row losses.
     Ignored rows must carry g=0 (the forward zeroed their losses, so any
     upstream reduction gives them zero cotangent through the where)."""
     interpret = resolve_interpret(interpret, "fused_linear_ce[bwd]")
+    blk_v = blk_v or _vocab_block(x.shape[1])
     xf, wf, tf, R, V, Rp, Vp, dp = _prep(x, w, targets, blk_r, blk_v)
     n_rb, n_vb = Rp // blk_r, Vp // blk_v
     vf = _vlim_operand(V, vlim)

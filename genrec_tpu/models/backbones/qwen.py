@@ -81,6 +81,34 @@ class QwenConfig:
     indexer_heads: int = 0
     indexer_head_dim: int = 0
     sparse_chunk: int = 512
+    # Layer kinds, a layer at a time. Mixer: the 1-based layer numbers (as a
+    # published ``linear_attn_config`` lists them) that run Kimi Delta
+    # Attention (backbones/kda.py) or NoPE latent attention
+    # (backbones/mla.py); every other layer runs the attention above. MLP:
+    # with ``num_experts > 0`` the first ``first_k_dense_replace`` layers
+    # keep the dense SwiGLU of ``intermediate_size``. The defaults mean
+    # what every config meant before there were kinds: one mixer, one MLP.
+    kda_layers: tuple = ()
+    mla_layers: tuple = ()
+    first_k_dense_replace: int = 0
+    # KDA: heads x head size (keys and values alike).
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    # Latent attention: the latent's rank, the key's two parts (the
+    # "rope" part is carried and never rotated) and the value's width.
+    # Its query tile is ``sparse_chunk``.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Router: ``softmax`` over the experts, or ``sigmoid`` scores with a
+    # selection bias (enters the choice of experts, never their gates; zero
+    # from the seed, no gradient) and the chosen gates scaled by
+    # ``routed_scaling_factor``. ``n_shared_experts`` x the expert width is
+    # one more SwiGLU every token passes through, beside the routed ones.
+    moe_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    n_shared_experts: int = 0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -93,6 +121,18 @@ class QwenConfig:
                 "path: set moe_capacity_factor=None")
         if self.sparse_topk and not (self.indexer_heads and self.indexer_head_dim):
             raise ValueError("sparse_topk > 0 needs indexer_heads and indexer_head_dim")
+        object.__setattr__(self, "kda_layers", tuple(self.kda_layers))
+        object.__setattr__(self, "mla_layers", tuple(self.mla_layers))
+        if set(self.kda_layers) & set(self.mla_layers):
+            raise ValueError("a layer runs one mixer: kda_layers and mla_layers overlap")
+        if self.kda_layers and not (self.kda_heads and self.kda_head_dim):
+            raise ValueError("kda_layers needs kda_heads and kda_head_dim")
+        if self.mla_layers and not (self.kv_lora_rank and self.qk_nope_head_dim
+                                    and self.v_head_dim):
+            raise ValueError("mla_layers needs kv_lora_rank, qk_nope_head_dim "
+                             "and v_head_dim")
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_scoring {self.moe_scoring!r}: softmax or sigmoid")
 
     @property
     def expert_width(self) -> int:
@@ -101,6 +141,23 @@ class QwenConfig:
     @property
     def experts_here(self) -> int:
         return self.num_experts if self.moe_experts_held is None else self.moe_experts_held
+
+    def mixer_kind(self, layer: int) -> str:
+        """``attention`` | ``kda`` | ``mla`` of the 0-based ``layer``."""
+        if layer + 1 in self.kda_layers:
+            return "kda"
+        return "mla" if layer + 1 in self.mla_layers else "attention"
+
+    def mlp_kind(self, layer: int) -> str:
+        """``dense`` | ``moe`` of the 0-based ``layer``."""
+        dense = self.num_experts == 0 or layer < self.first_k_dense_replace
+        return "dense" if dense else "moe"
+
+    @property
+    def dense_attention_layers(self) -> bool:
+        """Some layer scores every key at once and needs the (L, L) bias."""
+        return self.sparse_topk == 0 and any(
+            self.mixer_kind(i) == "attention" for i in range(self.num_hidden_layers))
 
 
 def causal_pad_bias(L: int, attention_mask=None):
@@ -276,6 +333,30 @@ def sparse_attention(q, k, v, q_idx, w_idx, k_idx, key_valid, topk: int,
     return out, (None if query_valid is None else (kept, seen))
 
 
+def causal_attention(q, k, v, key_valid, chunk: int, q_slot=None):
+    """Attention over every real key at or before the query, a query tile
+    at a time through the tiled running-max softmax above: the largest
+    temporary is the (heads, chunk, keys) scores of ONE row. The key width
+    may differ from the value width.
+
+    q (B, L, KV, rep, hd); k (B, N, KV, hd); v (B, N, KV, vd); key_valid
+    (B, N) bool. ``q_slot`` as in `sparse_attention`. A tile's backward pass
+    keeps the whole K and V of its row (one buffer for all tiles) and
+    slices them inside the rematerialised row."""
+    L, N = q.shape[1], k.shape[1]
+    outs = []
+    for lo in range(0, L, chunk):
+        hi = min(lo + chunk, L)
+        n = min(hi, N) if q_slot is None else N
+        slots = jnp.arange(lo, hi) if q_slot is None else q_slot[lo:hi]
+        allowed = (key_valid[:, None, :n]
+                   & (jnp.arange(n)[None, None, :] <= slots[None, :, None]))
+        row = jax.checkpoint(
+            lambda qr, kr, vr, sel, n=n: _attend_row(qr, kr[:n], vr[:n], sel))
+        outs.append(jax.lax.map(lambda a: row(*a), (q[:, lo:hi], k, v, allowed)))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+
 class QwenAttention(nn.Module):
     cfg: QwenConfig
     dtype: jnp.dtype = jnp.float32
@@ -380,12 +461,14 @@ class QwenAttention(nn.Module):
 class QwenMLP(nn.Module):
     cfg: QwenConfig
     dtype: jnp.dtype = jnp.float32
+    width: Optional[int] = None  # None: cfg.intermediate_size
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        gate = nn.Dense(cfg.intermediate_size, use_bias=False, dtype=self.dtype, name="gate_proj")(x)
-        up = nn.Dense(cfg.intermediate_size, use_bias=False, dtype=self.dtype, name="up_proj")(x)
+        width = self.width or cfg.intermediate_size
+        gate = nn.Dense(width, use_bias=False, dtype=self.dtype, name="gate_proj")(x)
+        up = nn.Dense(width, use_bias=False, dtype=self.dtype, name="up_proj")(x)
         return nn.Dense(cfg.hidden_size, use_bias=False, dtype=self.dtype, name="down_proj")(
             nn.silu(gate) * up
         )
@@ -449,10 +532,21 @@ class QwenMoEMLP(nn.Module):
                               precision=_HIGHEST, name="router")(
                 xf.astype(jnp.float32)
             )
-            probs = jax.nn.softmax(logits, axis=-1)  # (S, E)
-            gates, eidx = jax.lax.top_k(probs, K)  # (S, K)
+            if cfg.moe_scoring == "sigmoid":
+                # The bias moves which experts are chosen, never their
+                # gates; a buffer the gradient step leaves alone.
+                probs = jax.nn.sigmoid(logits)
+                bias = jax.lax.stop_gradient(self.param(
+                    "selection_bias", nn.initializers.zeros, (E,)))
+                _, eidx = jax.lax.top_k(probs + bias, K)
+                gates = jnp.take_along_axis(probs, eidx, axis=-1)
+            else:
+                probs = jax.nn.softmax(logits, axis=-1)  # (S, E)
+                gates, eidx = jax.lax.top_k(probs, K)  # (S, K)
             if cfg.norm_topk_prob:
                 gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+            if cfg.routed_scaling_factor != 1.0:
+                gates = gates * cfg.routed_scaling_factor
 
         # Padding tokens must not claim capacity slots (at tight capacity
         # factors they would evict REAL tokens' primary experts with
@@ -477,9 +571,18 @@ class QwenMoEMLP(nn.Module):
         aux = E * jnp.sum((probs * vf[:, None]).sum(0) / nv * (top1.sum(0) / nv))
         self.sow("losses", "router_aux", cfg.router_aux_coef * aux)
 
+        def with_shared(y):
+            """The routed result plus what every token gets alike."""
+            y = y.reshape(B, L, D)
+            if not cfg.n_shared_experts:
+                return y
+            with jax.named_scope("moe_shared"):
+                return y + QwenMLP(cfg, self.dtype, cfg.n_shared_experts * F,
+                                   name="shared_expert")(x)
+
         if cfg.moe_capacity_factor is None:
-            y = self._dropless(xf, gates, eidx, valid, w_gate, w_up, w_down)
-            return y.reshape(B, L, D)
+            return with_shared(
+                self._dropless(xf, gates, eidx, valid, w_gate, w_up, w_down))
 
         C = max(1, int(-(-S // E) * cfg.moe_capacity_factor))
         expert_mask = (
@@ -521,8 +624,7 @@ class QwenMoEMLP(nn.Module):
             jnp.einsum("ecd,edf->ecf", expert_in, w_gate.astype(self.dtype))
         ) * jnp.einsum("ecd,edf->ecf", expert_in, w_up.astype(self.dtype))
         expert_out = jnp.einsum("ecf,efd->ecd", h, w_down.astype(self.dtype))
-        y = jnp.einsum("sec,ecd->sd", combine, expert_out)
-        return y.reshape(B, L, D)
+        return with_shared(jnp.einsum("sec,ecd->sd", combine, expert_out))
 
     def _dropless(self, xf, gates, eidx, valid, w_gate, w_up, w_down):
         """Every routed (token, expert) pair whose expert is held here,
@@ -548,6 +650,8 @@ class QwenMoEMLP(nn.Module):
                      sizes.max() * held / jnp.maximum(n_here, 1).astype(jnp.float32))
             self.sow("counters", "expert_picks_here_share",
                      100.0 * n_here / (n_valid * K).astype(jnp.float32))
+            self.sow("counters", "expert_pairs_per_held_expert",
+                     n_here.astype(jnp.float32) / held)
         with jax.named_scope("moe_experts"):
             xs = _dispatch_rows(xf, order, inv, n_here, K)  # (S*K, D) by expert
             dot = lambda a, w: jax.lax.ragged_dot(a, w.astype(self.dtype), sizes)
@@ -639,7 +743,8 @@ def collect_counters(mutables) -> dict:
     """Mean over the layers of every value sown into the ``counters``
     collection during an ``apply(..., mutable=["counters"])`` forward:
     ``expert_load_max_over_mean``, ``expert_picks_here_share`` (%),
-    ``sparse_keys_kept_share`` (%). Empty for a dense model."""
+    ``expert_pairs_per_held_expert``, ``sparse_keys_kept_share`` (%),
+    ``kda_state_keep_share`` (%). Empty for a dense model."""
     from collections.abc import Mapping
 
     found: dict = {}
@@ -663,23 +768,41 @@ class QwenBlock(nn.Module):
     ring_axis: Optional[str] = None
     ring_size: int = 1
     expert_axis: Optional[str] = None
+    # 0-based index in the stack: the block asks the config for its kinds
+    # (`QwenConfig.mixer_kind` / `mlp_kind`).
+    layer: int = 0
 
     @nn.compact
     def __call__(self, x, positions, attn_bias, cache=None, ring_kv_valid=None,
                  token_mask=None, key_valid=None):
-        """Mixer then MLP, each composed from the config: dense or
-        learned-sparse attention (``cfg.sparse_topk``); SwiGLU, capacity
-        MoE or dropless MoE (``cfg.num_experts``, ``moe_capacity_factor``).
-        ``key_valid`` (B, keys) marks the real keys for sparse attention,
-        which builds no additive bias (``attn_bias`` is unused there)."""
+        """Mixer then MLP, each composed from the layer's kinds and the
+        config: dense or learned-sparse attention (``cfg.sparse_topk``),
+        Kimi Delta Attention or NoPE latent attention; SwiGLU, capacity MoE
+        or dropless MoE (``cfg.num_experts``, ``moe_capacity_factor``).
+        ``key_valid`` (B, keys) marks the real keys for sparse and latent
+        attention, which build no additive bias (``attn_bias`` is unused
+        there); ``token_mask`` (B, L) the block's real tokens, for the
+        experts and for the recurrent state."""
         h = RMSNorm(self.cfg.hidden_size, self.cfg.rms_norm_eps, name="input_layernorm")(x)
-        h, new_cache = QwenAttention(
-            self.cfg, self.dtype, self.ring_axis, self.ring_size, name="self_attn"
-        )(h.astype(self.dtype), positions, attn_bias, cache, ring_kv_valid,
-          key_valid)
+        h = h.astype(self.dtype)
+        mixer = self.cfg.mixer_kind(self.layer)
+        if mixer == "kda":
+            from genrec_tpu.models.backbones.kda import KimiDeltaAttention
+
+            h, new_cache = KimiDeltaAttention(self.cfg, self.dtype, name="kda")(
+                h, token_mask, cache)
+        elif mixer == "mla":
+            from genrec_tpu.models.backbones.mla import LatentAttention
+
+            h, new_cache = LatentAttention(self.cfg, self.dtype, name="mla")(
+                h, key_valid, cache)
+        else:
+            h, new_cache = QwenAttention(
+                self.cfg, self.dtype, self.ring_axis, self.ring_size, name="self_attn"
+            )(h, positions, attn_bias, cache, ring_kv_valid, key_valid)
         x = x + h
         h = RMSNorm(self.cfg.hidden_size, self.cfg.rms_norm_eps, name="post_attention_layernorm")(x)
-        if self.cfg.num_experts > 0:
+        if self.cfg.mlp_kind(self.layer) == "moe":
             x = x + QwenMoEMLP(self.cfg, self.dtype, self.expert_axis, name="moe")(
                 h.astype(self.dtype), token_mask
             )
@@ -721,7 +844,7 @@ class QwenLM(nn.Module):
         self.blocks = [
             block_cls(
                 self.cfg, self.dtype, self.ring_axis, self.ring_size,
-                self.expert_axis, name=f"layer_{i}",
+                self.expert_axis, i, name=f"layer_{i}",
             )
             for i in range(self.cfg.num_hidden_layers)
         ]
@@ -758,9 +881,10 @@ class QwenLM(nn.Module):
             ring_valid = (
                 None if attention_mask is None else attention_mask.astype(bool)
             )
-        elif self.cfg.sparse_topk > 0:
+        elif not self.cfg.dense_attention_layers:
             # Selection, causality and padding are masks of a query tile
-            # inside sparse_attention: no (L, L) bias either.
+            # inside sparse_attention / causal_attention, and a recurrent
+            # layer reads the token mask: no (L, L) bias either.
             bias = None
             ring_valid = None
         else:
@@ -782,14 +906,23 @@ class QwenLM(nn.Module):
     # ---- KV-cache decode ---------------------------------------------------
 
     def init_cache(self, batch_size: int, max_len: int):
-        """Per layer: K and V (and, under sparse attention, the indexer's
-        keys ``ki`` beside them: the selection runs over the cache's
-        slots), with ``idx`` the next slot to write. Every entry but
-        ``idx`` has the batch in front."""
+        """Per layer, by its mixer: K and V (and, under sparse attention,
+        the indexer's keys ``ki`` beside them: the selection runs over the
+        cache's slots); a latent row a token (``latent``); or a
+        constant-size recurrent state (``s``, ``conv``). Every layer keeps
+        ``idx``, the next slot to write, and every other entry has the
+        batch in front (the beam's reorder relies on it)."""
+        from genrec_tpu.models.backbones.kda import init_kda_cache
+        from genrec_tpu.models.backbones.mla import init_mla_cache
+
         cfg = self.cfg
         kv = (batch_size, max_len, cfg.num_key_value_heads, cfg.head_dim)
 
-        def layer():
+        def layer(kind):
+            if kind == "kda":
+                return init_kda_cache(cfg, batch_size)
+            if kind == "mla":
+                return init_mla_cache(cfg, batch_size, max_len, self.dtype)
             c = {"k": jnp.zeros(kv, self.dtype), "v": jnp.zeros(kv, self.dtype),
                  "idx": jnp.asarray(0, jnp.int32)}
             if cfg.sparse_topk > 0:
@@ -797,7 +930,7 @@ class QwenLM(nn.Module):
                     (batch_size, max_len, cfg.indexer_head_dim), jnp.float32)
             return c
 
-        return [layer() for _ in range(cfg.num_hidden_layers)]
+        return [layer(cfg.mixer_kind(i)) for i in range(cfg.num_hidden_layers)]
 
     def decode_step(self, input_ids, positions, caches, pad_mask):
         """Advance by input_ids.shape[1] tokens against a static cache.
@@ -806,13 +939,15 @@ class QwenLM(nn.Module):
         Returns (logits_at_last, new_caches).
         """
         B, L = input_ids.shape
-        S = caches[0]["k"].shape[1]
-        # Bias over cache slots: mask invalid slots; also causal within the
-        # newly-written block.
-        slot = jnp.arange(S)[None, None, None, :]
-        write_pos = caches[0]["idx"] + jnp.arange(L)
-        causal = jnp.where(slot > write_pos[None, None, :, None], -1e9, 0.0)
-        bias = causal + jnp.where(pad_mask[:, None, None, :] == 0, -1e9, 0.0)
+        S = pad_mask.shape[1]
+        bias = None
+        if self.cfg.dense_attention_layers:
+            # Bias over cache slots: mask invalid slots; also causal within
+            # the newly-written block.
+            slot = jnp.arange(S)[None, None, None, :]
+            write_pos = caches[0]["idx"] + jnp.arange(L)
+            causal = jnp.where(slot > write_pos[None, None, :, None], -1e9, 0.0)
+            bias = causal + jnp.where(pad_mask[:, None, None, :] == 0, -1e9, 0.0)
 
         x = self.embed_tokens[input_ids].astype(self.dtype)
         # Validity of the CURRENT block's tokens (pad_mask covers cache

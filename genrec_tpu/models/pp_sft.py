@@ -58,6 +58,10 @@ def make_pp_sft_loss(
         raise ValueError(
             f"n_layers {cfg.num_hidden_layers} not divisible by pipe={S}"
         )
+    if len({(cfg.mixer_kind(i), cfg.mlp_kind(i))
+            for i in range(cfg.num_hidden_layers)}) > 1:
+        raise ValueError("the pipeline stacks ONE block over every layer: a "
+                         "config whose layers differ in kind is not wired")
     M = n_micro or S
     batch_axis = "data" if "data" in mesh.axis_names else None
     block = QwenBlock(cfg, dtype)

@@ -242,6 +242,26 @@ def train(
     indexer_heads=0,
     indexer_head_dim=0,
     sparse_chunk=512,
+    rms_norm_eps=1e-6,
+    # Layer kinds (1-based layer numbers, as a published
+    # ``linear_attn_config`` lists them): layers that run Kimi Delta
+    # Attention (backbones/kda.py: heads x head size) or NoPE latent attention
+    # (backbones/mla.py); every other layer runs the attention above. With
+    # experts, the first ``first_k_dense_replace`` layers keep the dense MLP.
+    kda_layers=(),
+    mla_layers=(),
+    first_k_dense_replace=0,
+    kda_heads=0,
+    kda_head_dim=0,
+    kv_lora_rank=0,
+    qk_nope_head_dim=0,
+    qk_rope_head_dim=0,
+    v_head_dim=0,
+    # Router scores (``softmax`` | ``sigmoid`` with a selection bias), the
+    # scale on the chosen gates, and shared experts beside the routed ones.
+    moe_scoring="softmax",
+    routed_scaling_factor=1.0,
+    n_shared_experts=0,
     # >0: rows of the embedding and the head held here (a vocabulary slice
     # plus the codebook tokens); rows past the live vocabulary are inert.
     vocab_rows=0,
@@ -356,6 +376,21 @@ def train(
         raise ValueError("sparse_topk>0 (indexer-selected attention) is wired "
                          "for data-parallel runs only, not sequence_parallel / "
                          "pipeline_parallel / tensor_parallel")
+    if (kda_layers or mla_layers) and (
+            sequence_parallel > 1 or pipeline_parallel > 1 or tensor_parallel > 1):
+        # Ring attention and the pipeline stage body build their own
+        # attention; qwen_rules know nothing of these mixers' leaves; a
+        # recurrent state does not cross a sequence shard.
+        raise ValueError("kda_layers / mla_layers (Kimi Delta Attention, latent "
+                         "attention) are wired for data-parallel runs only, not "
+                         "sequence_parallel / pipeline_parallel / tensor_parallel")
+    if (kda_layers or mla_layers) and use_lora:
+        raise ValueError("LoRA on a backbone with kda_layers / mla_layers is "
+                         "not wired (its targets would match the new mixers' "
+                         "q_proj / k_proj / v_proj leaves)")
+    if n_shared_experts and expert_parallel > 1:
+        raise ValueError("n_shared_experts with expert_parallel is not wired "
+                         "(moe_rules shard the routed stacks only)")
     if moe_experts_held is not None and (expert_parallel > 1 or not moe_dropless):
         raise ValueError("moe_experts_held (one chip's share of the experts) "
                          "needs moe_dropless=True and runs without an exchange: "
@@ -412,7 +447,15 @@ def train(
             moe_experts_held=moe_experts_held,
             router_aux_coef=router_aux_coef, sparse_topk=sparse_topk,
             indexer_heads=indexer_heads, indexer_head_dim=indexer_head_dim,
-            sparse_chunk=sparse_chunk,
+            sparse_chunk=sparse_chunk, rms_norm_eps=rms_norm_eps,
+            kda_layers=tuple(kda_layers), mla_layers=tuple(mla_layers),
+            first_k_dense_replace=first_k_dense_replace, kda_heads=kda_heads,
+            kda_head_dim=kda_head_dim, kv_lora_rank=kv_lora_rank,
+            qk_nope_head_dim=qk_nope_head_dim,
+            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+            moe_scoring=moe_scoring,
+            routed_scaling_factor=routed_scaling_factor,
+            n_shared_experts=n_shared_experts,
         )
 
     # None = each data source's default mix.

@@ -83,7 +83,8 @@ from genrec_tpu.obs.spans import NULL_TRACER
 #: where it already waits for the loss; with the tracer off they are never
 #: read.
 STEP_COUNTERS = ("expert_load_max_over_mean", "expert_picks_here_share",
-                 "sparse_keys_kept_share")
+                 "expert_pairs_per_held_expert", "sparse_keys_kept_share",
+                 "kda_state_keep_share")
 
 
 def _peak_device_bytes() -> int:

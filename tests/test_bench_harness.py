@@ -156,6 +156,7 @@ def test_chip_smoke_last_line_is_the_drivers_object(monkeypatch, tmp_path, capsy
                         lambda *a: {"paged_config": [8, 10, 6, 64, 16, 4]})
     monkeypatch.setattr(chip_smoke, "phase_kernels", lambda *a, **k: {})
     monkeypatch.setattr(chip_smoke, "phase_lcrec_keye", lambda *a, **k: None)
+    monkeypatch.setattr(chip_smoke, "phase_lcrec_kimi_linear", lambda *a, **k: None)
     assert chip_smoke.main(["--out", str(tmp_path / "out")]) == 0
     lines = capsys.readouterr().out.splitlines()
     assert json.loads(lines[-1]) == {"ok": True, "device": V5E}
@@ -163,7 +164,7 @@ def test_chip_smoke_last_line_is_the_drivers_object(monkeypatch, tmp_path, capsy
     summary = json.loads(summary)
     assert head == "" and list(summary)[-1] == "claim" and summary["claim"] is None
     assert set(summary["phases"]) == {"train", "serve", "kernels", "lcrec_keye",
-                                      "four_chip"}
+                                      "lcrec_kimi_linear", "four_chip"}
     assert summary["phases"]["four_chip"]["ran"] is False  # one chip found
 
 

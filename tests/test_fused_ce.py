@@ -195,3 +195,64 @@ def test_bf16_inputs():
         tgt,
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-2, rtol=1e-2)
+
+
+# -- the vocabulary tile follows the width (PR 34) ------------------------------
+
+
+def test_vocab_tile_halves_past_width_2048():
+    from genrec_tpu.kernels.fused_ce import _vocab_block
+
+    assert _vocab_block(1536) == _vocab_block(2048) == 512
+    assert _vocab_block(2304) == _vocab_block(4096) == 256
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A v5e chip that is described and not attached (inside a fixture,
+    never while a module is imported)."""
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu here, or it is taken
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("d, rows_v", [(2048, 20272), (2304, 21760)])
+def test_backward_kernels_fit_the_chips_scoped_memory(one_chip, monkeypatch, d, rows_v):
+    """The dw kernel holds its (tile, d) float32 output block twice beside
+    the head's tile: at d = 2,304 a 512-row tile is 16.26 MB of the 16 MB a
+    kernel may scope, and the chip's compiler refuses it. Compiled here at
+    the two language models' head shapes, a row of 8,192 tokens."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from genrec_tpu.kernels import policy
+    from genrec_tpu.kernels.fused_ce import fused_linear_ce_bwd
+
+    # the suite's interpreter off and the chip's branch taken: the Mosaic
+    # kernel itself is what the described chip's compiler gets
+    monkeypatch.setattr(policy, "_interpret_requested", False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    R = 8192
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        compiled = jax.jit(
+            lambda x, w, t, lse, g: fused_linear_ce_bwd(x, w, t, lse, g, -100)
+        ).lower(sds((R, d), jnp.bfloat16), sds((rows_v, d), jnp.bfloat16),
+                sds((R,), jnp.int32), sds((R,), jnp.float32),
+                sds((R,), jnp.float32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+    assert "tpu_custom_call" in compiled.as_text()
