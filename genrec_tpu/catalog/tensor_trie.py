@@ -18,17 +18,21 @@ Encoding — a rank-based child CSR, one row per depth:
   above every real key so binary search ignores the padding);
 - ``offsets`` (D, C+1) int32 — the CSR row index: node p's children at
   step t occupy ``keys[t, offsets[t, p]:offsets[t, p+1]]``. Derived
-  from ``keys`` at build time; carried for segment reads and stats
-  (``n_nodes`` per step is ``offsets[t, -1]``).
+  from ``keys`` at build time; the legal mask and the draft weights
+  read a node's children through it (``_children``), and ``n_nodes``
+  per step is ``offsets[t, -1]``.
 
 A prefix is represented by its RANK among the sorted valid prefixes of
 that length (exactly PackedTrie's representation, so the two agree
 rank-for-rank along every valid path); the dead-prefix sentinel is the
-static capacity C, whose candidate keys exceed every storable key.
-``legal_mask``/``advance`` are vmapped ``searchsorted`` gathers — no
-host sync, no Python loops — and the ragged variants gather the PER-ROW
-key row directly (``keys[steps]``) instead of the compute-all-depths
-row-select the heterogeneous-shape tries need.
+static capacity C, which has no children and whose candidate keys
+exceed every storable key. ``legal_mask`` reads each prefix node's
+child segment (at most K keys from ``offsets[t, p]``: one gather of
+nodes x K values, no search per candidate code); ``advance`` is one
+``searchsorted`` probe a node. No host sync, no Python loops, and the
+ragged variants index the PER-ROW step directly (``keys[steps, ...]``)
+instead of the compute-all-depths row-select the heterogeneous-shape
+tries need.
 
 Capacity ladder: C is padded UP to a static rung (geometric, x4 from
 ``MIN_CAPACITY``) so catalog snapshots of similar size share an aval —
@@ -51,7 +55,8 @@ import jax.numpy as jnp
 import numpy as np
 
 #: Padding key: int32 max sorts above every real key (< (C+1) * K, checked
-#: at build), so searchsorted over a padded row never lands on padding.
+#: at build), so searchsorted over a padded row never lands on padding,
+#: and no segment reaches it (every ``offsets`` entry is <= n_keys).
 PAD_KEY = np.iinfo(np.int32).max
 
 #: Smallest capacity rung. Rungs grow geometrically (x4): snapshots whose
@@ -66,6 +71,11 @@ def capacity_for(n_nodes: int) -> int:
     while c < n_nodes:
         c *= CAPACITY_GROWTH
     return c
+
+
+def _per_row(steps: jax.Array, prefix_idx: jax.Array) -> jax.Array:
+    """(S,) steps shaped to broadcast against (S, ...) prefixes."""
+    return steps.reshape(steps.shape + (1,) * (prefix_idx.ndim - 1))
 
 
 @jax.tree_util.register_pytree_node_class
@@ -199,7 +209,8 @@ class TensorTrie:
     def legal_mask(self, prefix_idx: jax.Array, step: int) -> jax.Array:
         """prefix_idx: (...,) ranks -> (..., K) bool of legal next codes."""
         with jax.named_scope("trie_legal_mask"):
-            return self._mask_row(self.keys[step], prefix_idx)
+            _, hit = self._children(prefix_idx, step)
+            return hit.any(axis=-2)
 
     def advance(self, prefix_idx: jax.Array, token: jax.Array, step: int) -> jax.Array:
         """Rank of the extended prefix; dead/illegal -> sentinel capacity."""
@@ -207,12 +218,12 @@ class TensorTrie:
 
     def legal_mask_ragged(self, prefix_idx: jax.Array, steps: jax.Array) -> jax.Array:
         """Per-row step operand: prefix_idx (S, ...) + steps (S,) ->
-        (S, ..., K). The uniform (D, C) layout lets the row gather
-        ``keys[steps]`` replace the compute-all-depths select that
+        (S, ..., K). The uniform (D, C) layout lets ``steps`` index the
+        tensors directly, in place of the compute-all-depths select that
         `ops/trie.legal_mask_ragged` needs for heterogeneous tables."""
         with jax.named_scope("trie_legal_mask_ragged"):
-            row_keys = self.keys[steps]  # (S, C)
-            return jax.vmap(self._mask_row)(row_keys, prefix_idx)
+            _, hit = self._children(prefix_idx, _per_row(steps, prefix_idx))
+            return hit.any(axis=-2)
 
     def advance_ragged(self, prefix_idx: jax.Array, token: jax.Array,
                        steps: jax.Array) -> jax.Array:
@@ -226,28 +237,41 @@ class TensorTrie:
         prefix_idx (S, ...) + steps (S,) -> (S, ..., K) float32 — the
         node weight of the extended prefix where it is legal, 0 where it
         is not (the speculative drafter masks illegal codes itself).
-        Same searchsorted gather as `legal_mask_ragged`, one extra
-        weight-row read."""
+        The same segment read as `legal_mask_ragged`, plus the weights
+        at the children's positions."""
         with jax.named_scope("trie_child_weights_ragged"):
-            row_keys = self.keys[steps]     # (S, C)
-            row_w = self.weights[steps]     # (S, C)
+            step = _per_row(steps, prefix_idx)
+            pos, hit = self._children(prefix_idx, step)
+            w = jnp.asarray(self.weights)[step[..., None], pos]  # (S, ..., K)
+            # A code has at most one child, so the sum holds one term.
+            return jnp.where(hit, w[..., None], 0.0).sum(axis=-2)
 
-            def one_row(keys_row, w_row, prefix):
-                K = self.codebook_size
-                cand = prefix[..., None] * K + jnp.arange(K, dtype=jnp.int32)
-                pos = jnp.clip(jnp.searchsorted(keys_row, cand), 0,
-                               keys_row.shape[0] - 1)
-                return jnp.where(keys_row[pos] == cand, w_row[pos], 0.0)
+    # -- a node's children, through the CSR offsets --------------------------
 
-            return jax.vmap(one_row)(row_keys, row_w, prefix_idx)
+    def _children(self, prefix_idx: jax.Array, step):
+        """The child segment of every prefix node: ``step`` is a static
+        int or an int32 array that broadcasts against ``prefix_idx``.
+        Returns ``pos`` (..., K) int32, positions in the step's key row,
+        and ``hit`` (..., K, K) bool: ``hit[..., j, c]`` is True where
+        the node has a j-th child AND that child carries code c (a node
+        has at most K children, one a code). A rank outside [0, C) — the
+        dead sentinel C, a free slot's leftover — has no children.
 
-    # -- shared row kernels (sorted-gather binary search) --------------------
-
-    def _mask_row(self, row_keys: jax.Array, prefix_idx: jax.Array) -> jax.Array:
-        K = self.codebook_size
-        cand = prefix_idx[..., None] * K + jnp.arange(K, dtype=jnp.int32)
-        pos = jnp.clip(jnp.searchsorted(row_keys, cand), 0, row_keys.shape[0] - 1)
-        return row_keys[pos] == cand
+        The K positions are computed indices, clipped to the row: a
+        K-wide slice from ``lo`` would clamp its START near the row's
+        end and shift silently. Which of them are the node's is decided
+        by ``j < n``, never by the key's value."""
+        K, C = self.codebook_size, self.capacity
+        keys, offsets = jnp.asarray(self.keys), jnp.asarray(self.offsets)
+        live = (prefix_idx >= 0) & (prefix_idx < C)
+        p = jnp.where(live, prefix_idx, 0)
+        lo = offsets[step, p]
+        n = jnp.where(live, offsets[step, p + 1] - lo, 0)
+        j = jnp.arange(K, dtype=jnp.int32)
+        pos = jnp.minimum(lo[..., None] + j, C - 1)
+        code = keys[jnp.expand_dims(step, -1), pos] - p[..., None] * K
+        hit = (j < n[..., None])[..., None] & (code[..., None] == j)
+        return pos, hit
 
     def _advance_row(self, row_keys: jax.Array, prefix_idx: jax.Array,
                      token: jax.Array) -> jax.Array:

@@ -108,6 +108,144 @@ def test_tensor_trie_ragged_matches_batch_and_dispatches(rng):
         )
 
 
+def _corpus_weights(valid, item_w, k):
+    """An independent numpy walk of the corpus: per step, the (n_prefixes,
+    K) table of summed item weights below each (prefix, code). A prefix's
+    rank is its place among the sorted unique prefixes of its length."""
+    tables = []
+    for t in range(valid.shape[1]):
+        prefixes, rank = np.unique(valid[:, :t], axis=0, return_inverse=True)
+        table = np.zeros((len(prefixes), k), np.float64)
+        np.add.at(table, (rank.reshape(-1), valid[:, t]), item_w)
+        tables.append(table)
+    return tables
+
+
+@pytest.mark.parametrize(
+    "k,n,depth,fill",
+    [
+        (256, 12_101, 3, False),  # the serving cell's sizes: capacity 16,384
+        (8, 120, 3, True),   # capacity pinned to the widest step: the last
+                             # node's K-wide read would run off the row
+        (8, 300, 4, False),
+        (256, 30, 3, False),  # capacity 64 < K: every K-wide read is clipped
+    ],
+)
+def test_children_read_through_offsets_is_exact_at_every_rank(k, n, depth, fill):
+    """For EVERY rank in [0, C] at every step — the live nodes, the padded
+    range, the dead sentinel — the legal mask is PackedTrie's on live
+    ranks and all-False elsewhere; the ragged form with mixed steps
+    equals the static calls row by row; the draft weights equal the
+    corpus's own sums."""
+    rng = np.random.default_rng(k * 1000 + n)
+    valid = _random_corpus(rng, n, depth, k)
+    item_w = rng.integers(1, 5, len(valid)).astype(np.float64)
+    cap = None
+    if fill:
+        cap = max(TensorTrie.build(valid, k).n_nodes())
+        assert cap >= k  # the widest row is full and longer than one read
+    tt = TensorTrie.build(valid, k, capacity=cap, item_weights=item_w).device()
+    ref = PackedTrie.build(valid, k)
+    want_w = _corpus_weights(valid, item_w, k)
+    C = tt.capacity
+    assert C == (cap or capacity_for(max(tt.n_nodes())))
+    # Every rank, then the sentinel again to fill whole chunks of `rows`
+    # rows of B: the CPU backend writes the (nodes, K, K) compare out, so
+    # a call holds at most 2**26 of its elements.
+    B = 32
+    rows = max(1, min(-(-(C + 1) // B), 2**26 // (B * k * k)))
+    ranks = np.full(-(-(C + 1) // (B * rows)) * B * rows, C, np.int32)
+    ranks[: C + 1] = np.arange(C + 1)
+    chunks = jnp.asarray(ranks.reshape(-1, rows, B))
+    live = [len(w) for w in want_w]  # prefixes of length t: step t-1's nodes
+
+    shape = (len(chunks), rows, B, k)
+    static, want = [], []
+    for t in range(depth):
+        mask_t = jax.jit(lambda p, t=t: tt.legal_mask(p, t))
+        got = np.stack([np.asarray(mask_t(c)) for c in chunks]).reshape(-1, k)
+        np.testing.assert_array_equal(
+            got[: live[t]],
+            np.asarray(ref.legal_mask(jnp.arange(live[t], dtype=jnp.int32), t)),
+        )
+        np.testing.assert_array_equal(got[: live[t]], want_w[t] > 0)
+        assert not got[live[t]:].any(), f"step {t}: a rank with no node has children"
+        static.append(got.reshape(shape))
+        want.append(np.zeros((len(ranks), k), np.float32))
+        want[t][: live[t]] = want_w[t]
+        want[t] = want[t].reshape(shape)
+
+    # Mixed steps in one call; over the `depth` shifts every row meets
+    # every step.
+    mask_r = jax.jit(tt.legal_mask_ragged)
+    weights_r = jax.jit(tt.child_weights_ragged)
+    for shift in range(depth):
+        steps = (np.arange(rows) + shift) % depth
+        got_m = np.stack([np.asarray(mask_r(c, jnp.asarray(steps))) for c in chunks])
+        got_w = np.stack([np.asarray(weights_r(c, jnp.asarray(steps))) for c in chunks])
+        assert got_m.shape == got_w.shape == shape
+        for t in range(depth):
+            at = steps == t
+            np.testing.assert_array_equal(got_m[:, at], static[t][:, at])
+            np.testing.assert_array_equal(got_w[:, at], want[t][:, at])
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A v5e chip that is described and not attached: the TPU compiler
+    compiles for it here. Only inside this fixture (never while a module
+    is imported): one process at a time may load the TPU's library."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu here, or it is taken
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_legal_mask_compiles_to_one_segment_gather_on_the_chip(one_chip):
+    """What the chip's compiler makes of the serving cell's mask (16 slots,
+    beam 10, codebook 256, capacity 16,384): no loop — a `searchsorted`
+    per candidate code was a 15-round `while` of 40,960-value gathers,
+    three quarters of the cell's device time — and one gather of the
+    S x beam x K child keys."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from genrec_tpu.analysis.ir import hlo_ops_of_size
+
+    S, beam, k, C, depth = 16, 10, 256, 16_384, 3
+
+    def described(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    trie = TensorTrie(
+        described((depth, C), jnp.int32), described((depth, C + 1), jnp.int32),
+        k, described((depth, C), jnp.float32),
+    )
+    # What is compiled for a described chip cannot be read back from the
+    # persistent cache without one: keep it out.
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        hlo = (
+            jax.jit(lambda t, p, s: t.legal_mask_ragged(p, s))
+            .lower(trie, described((S, beam), jnp.int32), described((S,), jnp.int32))
+            .compile().as_text()
+        )
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    loops = [line.strip()[:200] for line in hlo.splitlines() if " while(" in line]
+    assert not loops, loops
+    gathers = [line for op, line in hlo_ops_of_size(hlo, S * beam * k)
+               if op == "gather"]
+    assert len(gathers) <= 1, gathers
+
+
 def test_tensor_trie_tuples_are_valid_and_capacity_ladder(rng):
     valid = _random_corpus(rng, 25, 3)
     tt = TensorTrie.build(valid, K_CB).device()
