@@ -18,6 +18,13 @@ random weights from a seed, synthetic data from a seed:
    requests of mixed history lengths with one repeated (a warm prefix
    admit), then the compiled text of every paged executable read for a
    whole-pool copy (there must be none), drain, stop.
+   Then **serve_lcrec**: one request, cold and warm, through
+   `LCRecGenerativeHead`'s paged path on a backbone of Solar-Open2's
+   layer kinds at its published widths (gated NoPE GQA 64/8 x 128 whose
+   K and V go to pages; three KDA layers of 64 x 128 with negative
+   eigenvalues whose states live a beam in the slot table; sigmoid
+   routing over 320 outputs, 8 experts held), with the same reads of the
+   compiled text for a whole-pool or whole-state copy.
 3. **kernels** — the `kernels.preflight` legs compiled (interpret=False):
    the paged kernel at this engine's shapes in fp32, int8 and the pool's
    own dtype; the other default-on kernels at their preflight shapes.
@@ -534,6 +541,92 @@ def phase_serve(model, params, item_sem_ids, cfg: dict) -> dict:
     }
 
 
+#: The paged LCRec leg's backbone: Solar-Open2's layer kinds at its published
+#: widths (benchmark/configs/solar_open2_250b), one period, a share of 8 of the
+#: 320 experts and a small vocabulary so that the leg takes seconds.
+LCREC_PAGED = dict(
+    hidden_size=4096, intermediate_size=10240, num_hidden_layers=4,
+    num_attention_heads=64, num_key_value_heads=8, head_dim=128,
+    attention_bias=False, tie_word_embeddings=False, rms_norm_eps=1e-5,
+    use_rope=False, attn_output_gate=True, kda_neg_eigval=True,
+    kda_layers=(2, 3, 4), kda_heads=64, kda_head_dim=128,
+    num_experts=320, num_experts_per_tok=8, moe_intermediate_size=1280,
+    moe_capacity_factor=None, moe_experts_held=8, moe_scoring="sigmoid",
+    n_shared_experts=1, router_aux_coef=0.0,
+)
+#: --rehearse only: the same kinds at a width one CPU core finishes.
+LCREC_PAGED_REHEARSE = dict(
+    hidden_size=64, intermediate_size=128, num_attention_heads=8,
+    num_key_value_heads=2, head_dim=16, kda_heads=4, kda_head_dim=16,
+    num_experts=16, num_experts_per_tok=4, moe_intermediate_size=32,
+    moe_experts_held=4, sparse_chunk=32,
+)
+
+
+def phase_serve_lcrec(rehearse: bool) -> dict:
+    """One request, cold and then warm, through the paged LCRec head: the
+    prompt's K and V in pages, a KDA state a beam in the slot table, the
+    snapshot in the prefix entry."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from genrec_tpu.models.backbones.qwen import QwenConfig, QwenLM
+    from genrec_tpu.serving import (
+        BucketLadder, PagedConfig, Request, ServingEngine,
+    )
+    from genrec_tpu.serving.heads import LCRecGenerativeHead
+
+    C, K, base, items = 5, 256, 1024, 64
+    sizes = {**LCREC_PAGED, **(LCREC_PAGED_REHEARSE if rehearse else {})}
+    dtype = jnp.bfloat16
+    model = QwenLM(QwenConfig(vocab_size=base + C * K, **sizes), dtype=dtype)
+    keep = ("A_log", "dt_bias")  # float32 whatever the weights are
+    params = jax.jit(lambda k: jax.tree_util.tree_map_with_path(
+        lambda path, x: x if str(path[-1].key) in keep else x.astype(dtype),
+        model.init(k, jnp.zeros((1, 4), jnp.int32))["params"]))(jax.random.key(0))
+    rng = np.random.default_rng(0)
+    item_sem_ids = np.unique(rng.integers(0, K, (2000, C)), axis=0)
+    head = LCRecGenerativeHead(model, base, C, K, item_sem_ids=item_sem_ids,
+                               top_k=BEAMS, name="lcrec")
+    page = 128 if not rehearse else 8
+    paged_config = PagedConfig(
+        max_slots=4, page_size=page,
+        pages_per_slot=-(-head.paged_kv_tokens(items, items) // page))
+    engine = ServingEngine(
+        [head], params, paged=True, paged_config=paged_config,
+        ladder=BucketLadder((1, 2), (items // 2, items)), max_batch=2,
+        handle_signals=False,
+    )
+    t0 = time.perf_counter()
+    engine.start()
+    warmup_s = time.perf_counter() - t0
+    try:
+        req = Request(head=head.name, history=rng.integers(0, len(item_sem_ids), 48))
+        cold = engine.submit(req).result(300)
+        warm = engine.submit(req).result(300)
+        _check_responses([cold, warm], item_sem_ids)
+        np.testing.assert_array_equal(warm.items, cold.items)
+        np.testing.assert_array_equal(warm.scores, cold.scores)
+        runner = engine._runners[head.name]
+        pool_ops = _check_pool_stays_put(runner)
+        state_ops = _check_slot_state_stays_put(runner)
+        recurrent = runner.slots.recurrent_nbytes
+    finally:
+        stats = engine.stop()
+    check(stats["completed"] == 2 and stats["recompilations"] == 0, stats)
+    prefix = stats["prefix_cache"][head.name]
+    check(prefix["hits"] == 1 and prefix["snapshot_bytes"] == 0, prefix)
+    return {
+        "warmup_s": round(warmup_s, 2),
+        "warmup_executables": stats["warmup_compiles"],
+        "answered": stats["completed"], "warm_prefix_hits": prefix["hits"],
+        "page_layers": head.paged_layout()[0], "layers": sizes["num_hidden_layers"],
+        "recurrent_state_bytes": recurrent,
+        "pool_sized_ops": pool_ops, "slot_state_sized_ops": state_ops,
+    }
+
+
 def phase_kernels(entry: dict, paged_shape, interpret: bool) -> dict:
     from genrec_tpu.kernels.preflight import DEFAULT_ON, run_legs
 
@@ -661,6 +754,8 @@ def main(argv=None) -> int:
         item_sem_ids = data.valid_item_sem_ids()
         with phases.phase("serve") as entry:
             entry.update(phase_serve(model, params, item_sem_ids, cfg))
+        with phases.phase("serve_lcrec") as entry:
+            entry.update(phase_serve_lcrec(rehearse=args.rehearse))
         with phases.phase("kernels") as entry:
             kernels = phase_kernels(
                 entry, phases.report["serve"]["paged_config"],

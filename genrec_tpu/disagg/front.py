@@ -164,6 +164,7 @@ class DisaggFront:
                     "disagg is opt-in per head; serve it on the "
                     "co-located ServingEngine instead"
                 )
+            h.paged_check_options(handoff=True)
         self._params = params
         self._params_by_head = (
             params_by_head if params_by_head is not None

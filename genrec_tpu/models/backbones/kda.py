@@ -1,5 +1,9 @@
 """Kimi Delta Attention (KDA): a gated delta-rule linear-attention mixer with
 a PER-CHANNEL forget gate (Kimi Linear, arXiv:2510.26692).
+With ``QwenConfig.kda_neg_eigval`` the write strength below is 2 * sigmoid
+instead of sigmoid: b_t in (0, 2), so I - b_t k_t k_t^T has the eigenvalue
+1 - b_t in (-1, 1) along the unit key. Nothing else changes, in the
+recurrent step and the chunked form alike.
 
 One head keeps a state S (K x V, float32), zero before the row:
 
@@ -151,13 +155,16 @@ def _conv_heads(u, w, prefix, head_dim: int):
     return unit_vector(q) * head_dim ** -0.5, unit_vector(k), v, conv
 
 
-@jax.checkpoint
-def _gates(f, a_log, dt_bias, b, m):
+@functools.partial(jax.checkpoint, static_argnums=(5,))
+def _gates(f, a_log, dt_bias, b, m, b_scale: float = 1.0):
     """Forget gate g (B, L, H, K) and write strength (B, L, H), float32,
-    both zero at padding. f (B, L, HK), b (B, L, H) pre-activations."""
+    both zero at padding. f (B, L, HK), b (B, L, H) pre-activations;
+    ``b_scale`` 2 lets the transition's eigenvalue along the key go
+    negative (``QwenConfig.kda_neg_eigval``)."""
     f = f.astype(jnp.float32).reshape(f.shape[:2] + dt_bias.shape)
     g = forget_gate(f, a_log, dt_bias) * m[:, :, None, None]
-    return g, jax.nn.sigmoid(b.astype(jnp.float32)) * m[:, :, None]
+    b = jax.nn.sigmoid(b.astype(jnp.float32)) * m[:, :, None]
+    return g, b if b_scale == 1.0 else b * b_scale
 
 
 @functools.partial(jax.checkpoint, static_argnums=(3, 4))
@@ -213,7 +220,8 @@ class KimiDeltaAttention(nn.Module):
             g, b = _gates(dense(H * K, "f_b_proj")(dense(K, "f_a_proj")(x)),
                           self.param("A_log", _a_log_init, (H,)),
                           self.param("dt_bias", _dt_bias_init, (H, K)),
-                          dense(H, "b_proj")(x), m)
+                          dense(H, "b_proj")(x), m,
+                          2.0 if cfg.kda_neg_eigval else 1.0)
             if cache is None:
                 keep = jnp.sum(jnp.exp(g) * m[:, :, None, None])
                 self.sow("counters", "kda_state_keep_share",

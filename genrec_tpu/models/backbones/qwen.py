@@ -76,7 +76,8 @@ class QwenConfig:
     # each query attends the `sparse_topk` keys its indexer scores
     # highest among the real keys at or before it (all of them where
     # there are no more). `sparse_chunk` is the query tile in which
-    # scores and selection are computed (never block-level selection).
+    # scores and selection are computed (never block-level selection),
+    # and the query tile of every attention that builds no (L, L) bias.
     sparse_topk: int = 0
     indexer_heads: int = 0
     indexer_head_dim: int = 0
@@ -109,6 +110,16 @@ class QwenConfig:
     moe_scoring: str = "softmax"
     routed_scaling_factor: float = 1.0
     n_shared_experts: int = 0
+    # Full attention without positions (``use_rope`` False: q and k are never
+    # rotated; ``rope_theta`` is then unread), and an output gate: the
+    # attention's output times sigmoid(x W_g), W_g hidden -> heads x head_dim
+    # without bias, element by element, before ``o_proj``.
+    use_rope: bool = True
+    attn_output_gate: bool = False
+    # KDA: the write strength is 2 * sigmoid(.) instead of sigmoid(.), so the
+    # transition I - b k k^T has the eigenvalue 1 - b in (-1, 1) along the
+    # unit key (a published ``kda_allow_neg_eigval``).
+    kda_neg_eigval: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -357,6 +368,49 @@ def causal_attention(q, k, v, key_valid, chunk: int, q_slot=None):
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
 
 
+def gqa_decode_paged(q, k, v, cache):
+    """One new token a beam against a prompt held in pages and the beam's
+    own generated suffix: the serving decode of a full-attention layer.
+
+    q (S*W, 1, H, hd), k and v (S*W, 1, KV, hd): W beams of each of S
+    slots. ``cache``: ``k_pool``/``v_pool`` (pages, page, KV*hd) with the
+    slots' ``block_tables`` (S, pages) and ``seq_lens`` (S,): the prompt,
+    shared by a slot's beams; ``sk``/``sv`` (S, W, T, KV*hd): each beam's
+    suffix, this token written at slot ``t`` (S,) and read up to it. The
+    H/KV query heads of a key-value head ride the paged read's beam axis;
+    the two partial softmaxes are merged into the joint one
+    (`ops.paged.merge_attention_stats`). Returns (S*W, 1, H*hd) and the
+    suffix with the token in it."""
+    from genrec_tpu.ops.paged import NEG, merge_attention_stats, paged_attention_stats
+
+    bt, sl, t = cache["block_tables"], cache["seq_lens"], cache["t"]
+    S = bt.shape[0]
+    H, hd = q.shape[2:]
+    KV = k.shape[2]
+    W, rep, T = q.shape[0] // S, H // KV, cache["sk"].shape[2]
+    hit = (jnp.arange(T)[None, :] == t[:, None])[:, None, :, None]
+    sk = jnp.where(hit, k.reshape(S, W, 1, KV * hd), cache["sk"])
+    sv = jnp.where(hit, v.reshape(S, W, 1, KV * hd), cache["sv"])
+    q5 = q.reshape(S, W, KV, rep, hd)
+    with jax.named_scope("kv_attend"):
+        # head h = g * rep + r reads key-value head g
+        acc, m, l = paged_attention_stats(
+            q5.transpose(0, 1, 3, 2, 4).reshape(S, W * rep, KV, hd),
+            cache["k_pool"], cache["v_pool"], bt, sl)
+        beams = lambda a: a.reshape((S, W, rep, KV) + a.shape[3:]).swapaxes(2, 3)
+        acc, m, l = beams(acc), beams(m), beams(l)
+    with jax.named_scope("suffix_attend"):
+        s = jnp.einsum("swgrd,swtgd->swgrt", q5, sk.reshape(S, W, T, KV, hd),
+                       preferred_element_type=jnp.float32) * (hd ** -0.5)
+        s = jnp.where(jnp.arange(T) > t[:, None, None, None, None], NEG, s)
+        m_s = s.max(axis=-1)
+        e = jnp.exp(s - m_s[..., None])
+        acc_s = jnp.einsum("swgrt,swtgd->swgrd", e,
+                           sv.reshape(S, W, T, KV, hd).astype(jnp.float32))
+        out = merge_attention_stats(acc, m, l, acc_s, m_s, e.sum(axis=-1))
+    return out.reshape(S * W, 1, H * hd).astype(q.dtype), {"sk": sk, "sv": sv}
+
+
 class QwenAttention(nn.Module):
     cfg: QwenConfig
     dtype: jnp.dtype = jnp.float32
@@ -400,18 +454,24 @@ class QwenAttention(nn.Module):
         if cfg.qk_norm:
             q = RMSNorm(hd, cfg.rms_norm_eps, name="q_norm")(q)
             k = RMSNorm(hd, cfg.rms_norm_eps, name="k_norm")(k)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        if cfg.use_rope:
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
         if sparse:
             if self.ring_axis is not None:
                 raise ValueError("sparse attention is not wired with ring attention")
             q_idx, w_idx, k_idx = self._indexer(x)
-            q_idx = _rope(q_idx, positions, cfg.rope_theta)
-            k_idx = _rope(k_idx[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+            if cfg.use_rope:
+                q_idx = _rope(q_idx, positions, cfg.rope_theta)
+                k_idx = _rope(k_idx[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+        gate = None
+        if cfg.attn_output_gate:
+            gate = nn.Dense(H * hd, use_bias=False, dtype=self.dtype, name="gate_proj")(x)
 
         new_cache = None
         q_slot = None
-        if cache is not None:
+        paged = cache is not None and "k_pool" in cache
+        if cache is not None and not paged:
             # cache: dict(k=(B, S, KV, hd), v=..., idx scalar): static-size
             # decode cache updated at position idx (sparse attention keeps
             # the indexer's keys beside K and V).
@@ -423,10 +483,16 @@ class QwenAttention(nn.Module):
             if sparse:
                 k_idx = jax.lax.dynamic_update_slice(cache["ki"], k_idx, (0, idx, 0))
                 new_cache["ki"] = k_idx
+            if sparse or attn_bias is None:
                 q_slot = idx + jnp.arange(L)
 
         rep = H // KV  # GQA expansion factor
-        if sparse:
+        if paged:
+            # serving: the prompt in pages, the beam's own suffix beside it
+            if sparse:
+                raise ValueError("sparse attention has no paged decode")
+            out, new_cache = gqa_decode_paged(q, k, v, cache)
+        elif sparse:
             if key_valid is None:
                 key_valid = jnp.ones(k.shape[:2], bool)
             key_valid = key_valid.astype(bool)
@@ -447,6 +513,15 @@ class QwenAttention(nn.Module):
                 q, k, v, axis_name=self.ring_axis, axis_size=self.ring_size,
                 causal=True, kv_valid=ring_kv_valid, kv_rep=rep,
             ).reshape(B, L, H * hd)
+        elif attn_bias is None:
+            # No (L, L) bias was built: the caller's rows are too long for
+            # one score matrix a head (a serving prefill: 6.7 GB a row at
+            # 5,120 tokens), so the same softmax runs a query tile at a time.
+            if key_valid is None:
+                key_valid = jnp.ones(k.shape[:2], bool)
+            out = causal_attention(
+                q.reshape(B, L, KV, rep, hd), k, v, key_valid.astype(bool),
+                cfg.sparse_chunk, q_slot=q_slot).reshape(B, L, H * hd)
         else:
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
@@ -454,6 +529,8 @@ class QwenAttention(nn.Module):
             scores = scores + attn_bias  # (B or 1, 1, L, S) additive
             attn = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
             out = jnp.einsum("bhls,bshd->blhd", attn, v).reshape(B, L, H * hd)
+        if gate is not None:
+            out = (out * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(self.dtype)
         out = nn.Dense(cfg.hidden_size, use_bias=False, dtype=self.dtype, name="o_proj")(out)
         return out, new_cache
 
@@ -960,6 +1037,40 @@ class QwenLM(nn.Module):
         for block, cache in zip(self.blocks, caches):
             x, nc = block(x, positions, bias, cache, token_mask=token_mask,
                           key_valid=pad_mask)
+            new_caches.append(nc)
+        h = self.norm(x).astype(self.dtype)
+        return self._head(h)[:, -1, :], new_caches
+
+
+    # ---- serving through pages (models/lcrec.py) ---------------------------
+
+    def prefill_cached(self, input_ids, attention_mask):
+        """A left-padded prompt against fresh caches of exactly its length:
+        `decode_step` from slot 0 with the head on the last position only.
+        Returns (logits (B, V), caches): by layer K and V (B, L, KV, hd) of
+        every prompt slot, or a KDA layer's end state and its convolutions'
+        last inputs. No (L, L) bias is built, whatever the layers: a prompt
+        is as long as the history bucket, so a full-attention layer runs
+        `causal_attention`'s query tiles (of ``sparse_chunk``) over the mask."""
+        B, L = input_ids.shape
+        positions = jnp.maximum(jnp.cumsum(attention_mask, axis=1) - 1, 0)
+        x = self.embed_tokens[input_ids].astype(self.dtype)
+        new_caches = []
+        for block, cache in zip(self.blocks, self.init_cache(B, L)):
+            x, nc = block(x, positions, None, cache, token_mask=attention_mask,
+                          key_valid=attention_mask)
+            new_caches.append(nc)
+        h = self.norm(x[:, -1:]).astype(self.dtype)
+        return self._head(h)[:, 0, :], new_caches
+
+    def decode_paged(self, input_ids, positions, caches, token_mask):
+        """One token a row against per-layer serving caches: a full-attention
+        layer's `gqa_decode_paged` cache, a KDA layer's ``s``/``conv``.
+        input_ids, positions, token_mask (N, 1) -> (logits (N, V), caches)."""
+        x = self.embed_tokens[input_ids].astype(self.dtype)
+        new_caches = []
+        for block, cache in zip(self.blocks, caches):
+            x, nc = block(x, positions, None, cache, token_mask=token_mask)
             new_caches.append(nc)
         h = self.norm(x).astype(self.dtype)
         return self._head(h)[:, -1, :], new_caches

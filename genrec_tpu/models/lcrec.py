@@ -342,6 +342,72 @@ def generate_greedy(
     return toks.T  # (B, max_new)
 
 
+# ---------------------------------------------------------------------------
+# The beam over the codebook cascade: one definition for the dense generate
+# loop below and the paged decode step (`lcrec_paged_decode_step`).
+# ---------------------------------------------------------------------------
+
+
+def first_beams(trie, logp_w, W: int, C: int):
+    """The beams after code 0, from the prompt's last position. logp_w
+    (B, K): log-probabilities of the first codebook's slice. All beams are
+    identical before it, so the best ``W`` codes of the ONE row open them;
+    with W > K only K distinct first codes exist and the rest start at -inf
+    (displaced by real candidates at the next code). Returns beam_tokens
+    (B, W, C), beam_scores (B, W) and beam_rank (B, W): each beam's trie rank
+    (0 without a trie). Dead beams carry the trie's sentinel rank, whose
+    legal mask is all False: their scores stay -inf from the step that
+    killed them."""
+    B, K = logp_w.shape
+    if trie is not None:
+        logp_w = jnp.where(trie.legal_mask(jnp.zeros((B,), jnp.int32), 0),
+                           logp_w, -jnp.inf)
+    W0 = min(W, K)
+    scores, toks = jax.lax.top_k(logp_w, W0)
+    if W0 < W:
+        scores = jnp.concatenate(
+            [scores, jnp.full((B, W - W0), -jnp.inf)], axis=1)
+        toks = jnp.concatenate(
+            [toks, jnp.zeros((B, W - W0), toks.dtype)], axis=1)
+    beam_tokens = jnp.zeros((B, W, C), jnp.int32).at[:, :, 0].set(toks)
+    beam_rank = jnp.zeros((B, W), jnp.int32)
+    if trie is not None:
+        beam_rank = trie.advance(beam_rank, toks.astype(jnp.int32), 0)
+    return beam_tokens, scores, beam_rank
+
+
+def beam_step(trie, logp_w, beam_tokens, beam_scores, beam_rank, step):
+    """Extend every beam by code ``step`` and keep the best W of the W * K
+    candidates. logp_w (B, W, K): each beam's log-probabilities over the
+    codebook's slice. ``step`` is one code for the whole batch (an int: the
+    dense loop) or a code a row ((B,) int32: serving slots, each at its own).
+    Returns beam_tokens, beam_scores, beam_rank and ``parent`` (B, W): the
+    beam each survivor extends, which its caches must follow."""
+    from genrec_tpu.ops.trie import advance_ragged, legal_mask_ragged
+
+    B, W, K = logp_w.shape
+    ragged = not isinstance(step, int)
+    if trie is not None:
+        legal = (legal_mask_ragged(trie, beam_rank, step) if ragged
+                 else trie.legal_mask(beam_rank, step))
+        logp_w = jnp.where(legal, logp_w, -jnp.inf)
+    combined = (beam_scores[..., None] + logp_w).reshape(B, W * K)
+    beam_scores, idx = jax.lax.top_k(combined, W)
+    parent, tok = idx // K, idx % K
+    beam_tokens = jnp.take_along_axis(beam_tokens, parent[..., None], axis=1)
+    if ragged:
+        hit = jnp.arange(beam_tokens.shape[-1])[None, None, :] == step[:, None, None]
+        beam_tokens = jnp.where(hit, tok[..., None], beam_tokens)
+    else:
+        beam_tokens = beam_tokens.at[:, :, step].set(tok)
+    if trie is not None:
+        rank = jnp.take_along_axis(beam_rank, parent, axis=1)
+        tok32 = tok.astype(jnp.int32)
+        beam_rank = (advance_ragged(trie, rank, tok32, step) if ragged
+                     else trie.advance(rank, tok32, step))
+    return beam_tokens, beam_scores, beam_rank, parent
+
+
 def generate_topk_constrained(
     model: QwenLM,
     params,
@@ -396,13 +462,6 @@ def generate_topk_constrained(
     pad_bw = jnp.repeat(pad, W, axis=0)
     next_pos = positions[:, -1] + 1  # (B,)
 
-    beam_tokens = jnp.zeros((B, W, C), jnp.int32)
-    beam_scores = jnp.full((B, W), -jnp.inf).at[:, 0].set(0.0)
-    # Per-beam trie rank of the emitted prefix; root rank is 0. Dead
-    # beams carry the trie's sentinel rank, whose legal_mask is all
-    # False — their scores stay -inf from the step that killed them.
-    beam_rank = jnp.zeros((B, W), jnp.int32)
-
     for c in range(C):
         lo = base_vocab + c * K
         logp = jax.nn.log_softmax(
@@ -410,47 +469,12 @@ def generate_topk_constrained(
         )
         logp_w = jax.lax.dynamic_slice_in_dim(logp, lo, K, axis=1)
         if c == 0:
-            if trie is not None:
-                root = jnp.zeros((B,), jnp.int32)
-                logp_w = jnp.where(
-                    trie.legal_mask(root, 0), logp_w, -jnp.inf
-                )
-            # First step: all beams identical; expand from the B-row
-            # logits. With beam_width > codebook_size only K distinct
-            # first tokens exist — fill the rest with -inf beams (they
-            # are displaced by real W*K candidates at step 1).
-            W0 = min(W, K)
-            scores, toks = jax.lax.top_k(logp_w, W0)  # (B, W0)
-            if W0 < W:
-                scores = jnp.concatenate(
-                    [scores, jnp.full((B, W - W0), -jnp.inf)], axis=1
-                )
-                toks = jnp.concatenate(
-                    [toks, jnp.zeros((B, W - W0), toks.dtype)], axis=1
-                )
-            beam_scores = scores
-            beam_tokens = beam_tokens.at[:, :, 0].set(toks)
-            if trie is not None:
-                beam_rank = trie.advance(
-                    jnp.zeros((B, W), jnp.int32), toks.astype(jnp.int32), 0
-                )
+            beam_tokens, beam_scores, beam_rank = first_beams(
+                trie, logp_w, W, C)
         else:
-            logp_w = logp_w.reshape(B, W, K)
-            if trie is not None:
-                logp_w = jnp.where(
-                    trie.legal_mask(beam_rank, c), logp_w, -jnp.inf
-                )
-            combined = (beam_scores[..., None] + logp_w).reshape(B, W * K)
-            beam_scores, idx = jax.lax.top_k(combined, W)
-            parent = idx // K
-            tok = idx % K
-            beam_tokens = jnp.take_along_axis(beam_tokens, parent[..., None], axis=1)
-            beam_tokens = beam_tokens.at[:, :, c].set(tok)
-            if trie is not None:
-                beam_rank = trie.advance(
-                    jnp.take_along_axis(beam_rank, parent, axis=1),
-                    tok.astype(jnp.int32), c,
-                )
+            beam_tokens, beam_scores, beam_rank, parent = beam_step(
+                trie, logp_w.reshape(B, W, K), beam_tokens, beam_scores,
+                beam_rank, c)
             # Reorder caches to follow the selected parents.
             flat_parent = (parent + jnp.arange(B)[:, None] * W).reshape(B * W)
             caches = [map_cache(lambda a: a[flat_parent], cc) for cc in caches]
@@ -467,3 +491,185 @@ def generate_topk_constrained(
             )
 
     return LCRecGenerationOutput(sem_ids=beam_tokens, log_probas=beam_scores)
+
+
+# ---------------------------------------------------------------------------
+# Serving through pages (serving/heads.LCRecGenerativeHead's paged protocol):
+# the prompt's K and V of every full-attention layer live in the engine's
+# page pool, shared by a slot's beams; what a beam owns lives in its slot's
+# row: the K and V of its few generated tokens, and the recurrent state and
+# convolution tails of every KDA layer. Layers of other kinds (latent or
+# sparse attention) have no paged form.
+# ---------------------------------------------------------------------------
+
+
+def paged_layer_kinds(cfg: QwenConfig) -> tuple:
+    """The mixer of each layer, refusing what has no paged form."""
+    kinds = tuple(cfg.mixer_kind(i) for i in range(cfg.num_hidden_layers))
+    if cfg.sparse_topk > 0 or "mla" in kinds:
+        raise ValueError(
+            "the paged LCRec path holds full-attention K/V pages and KDA "
+            "states; sparse (indexer) and latent attention layers have no "
+            "page row yet")
+    return kinds
+
+
+def lcrec_paged_state_zeros(model: QwenLM, n_slots: int, beams: int,
+                            num_codebooks: int) -> dict:
+    """The zeroed slot-major decode state, a flat dict. A row's leaves:
+
+    - ``beam_seqs`` (W, C), ``beam_logps`` (W,), ``beam_rank`` (W,): the beam;
+    - ``parent`` (W,): the beam each beam extended at the last step. The
+      per-beam leaves below are stored as the step computed them and follow
+      ``parent`` when the NEXT step reads them, so a reorder moves a state
+      once (on its way into the recurrence), not twice;
+    - a KDA layer i: ``kda_s{i}`` (W, H, K, K) float32 and ``kda_conv{i}``
+      (W, 3, 3HK) float32, a beam's own; ``kda_s0_{i}`` (H, K, K) and
+      ``kda_conv0_{i}`` (3, 3HK), the prompt's end state, which the prefill
+      (or a warm admit) writes and the slot's first step hands every beam;
+    - a full-attention layer j: ``suf_k{j}``, ``suf_v{j}`` (W, C-1, KV*hd),
+      the K and V of the beam's generated tokens."""
+    from genrec_tpu.models.backbones.kda import _CONV_KERNEL
+
+    cfg = model.cfg
+    W, T = beams, max(num_codebooks - 1, 1)
+    state = {
+        "beam_seqs": jnp.zeros((n_slots, W, num_codebooks), jnp.int32),
+        "beam_logps": jnp.zeros((n_slots, W), jnp.float32),
+        "beam_rank": jnp.zeros((n_slots, W), jnp.int32),
+        "parent": jnp.zeros((n_slots, W), jnp.int32),
+    }
+    H, K = cfg.kda_heads, cfg.kda_head_dim
+    for i, kind in enumerate(paged_layer_kinds(cfg)):
+        if kind == "kda":
+            conv = (_CONV_KERNEL - 1, 3 * H * K)
+            state[f"kda_s{i}"] = jnp.zeros((n_slots, W, H, K, K), jnp.float32)
+            state[f"kda_conv{i}"] = jnp.zeros((n_slots, W) + conv, jnp.float32)
+            state[f"kda_s0_{i}"] = jnp.zeros((n_slots, H, K, K), jnp.float32)
+            state[f"kda_conv0_{i}"] = jnp.zeros((n_slots,) + conv, jnp.float32)
+        else:
+            kv = (n_slots, W, T, cfg.num_key_value_heads * cfg.head_dim)
+            state[f"suf_k{i}"] = jnp.zeros(kv, model.dtype)
+            state[f"suf_v{i}"] = jnp.zeros(kv, model.dtype)
+    return state
+
+
+def lcrec_prefill_paged(model: QwenLM, params, trie, input_ids, attention_mask,
+                        block_tables, k_pools, v_pools, base_vocab: int,
+                        num_codebooks: int, codebook_size: int,
+                        beam_width: int, temperature: float = 1.0):
+    """The left-padded prompt through every layer, its full-attention K and
+    V WRITTEN into the page pools through the batch's block tables (a row's
+    real tokens first: nothing here depends on a position once K is made),
+    the beams opened from the last position's trie-masked log-probabilities.
+
+    Returns (k_pools, v_pools, init, counters). ``init``: a row a request of
+    the leaves a slot starts from (``beam_*``, ``kda_s0_*``, ``kda_conv0_*``):
+    what a bind writes and a prefix entry snapshots. ``counters``: the
+    backbone's (`qwen.collect_counters`), e.g. the real token-expert pairs a
+    held expert saw in this launch."""
+    from genrec_tpu.models.backbones.qwen import collect_counters
+    from genrec_tpu.ops.paged import write_pages
+
+    kinds = paged_layer_kinds(model.cfg)
+    B, L = input_ids.shape
+    (logits, caches), mut = model.apply(
+        {"params": params}, input_ids, attention_mask,
+        method=QwenLM.prefill_cached, mutable=["counters"])
+    # real tokens first: page rows past a slot's length are never read
+    n_pad = L - jnp.sum(attention_mask, axis=1).astype(jnp.int32)
+    src = (jnp.arange(L)[None, :] + n_pad[:, None]) % L
+    front = lambda a: jnp.take_along_axis(a, src[:, :, None, None], axis=1)
+    k_pools, v_pools = list(k_pools), list(v_pools)
+    init, page_layer = {}, 0
+    for i, (kind, c) in enumerate(zip(kinds, caches)):
+        if kind == "kda":
+            init[f"kda_s0_{i}"] = c["s"]
+            init[f"kda_conv0_{i}"] = c["conv"]
+        else:
+            k_pools[page_layer] = write_pages(
+                k_pools[page_layer], block_tables, jnp.moveaxis(front(c["k"]), 1, 2))
+            v_pools[page_layer] = write_pages(
+                v_pools[page_layer], block_tables, jnp.moveaxis(front(c["v"]), 1, 2))
+            page_layer += 1
+    with jax.named_scope("beam_update"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32) / temperature, axis=-1)
+        logp_w = jax.lax.dynamic_slice_in_dim(logp, base_vocab, codebook_size, axis=1)
+        seqs, scores, rank = first_beams(trie, logp_w, beam_width, num_codebooks)
+    init.update(beam_seqs=seqs, beam_logps=scores, beam_rank=rank)
+    return tuple(k_pools), tuple(v_pools), init, collect_counters(mut)
+
+
+def lcrec_paged_decode_step(model: QwenLM, params, trie, state: dict, steps,
+                            block_tables, seq_lens, k_pools, v_pools,
+                            base_vocab: int, codebook_size: int,
+                            temperature: float = 1.0) -> dict:
+    """Advance every slot by one code: slot s, at ``steps[s]`` = c in
+    1..C-1, feeds each beam's code c-1 through the layers (a full-attention
+    layer reads the prompt's pages and the beam's suffix, a KDA layer
+    advances the beam's own state) and extends the beams by code c, with the
+    trie mask and beam update of `generate_topk_constrained`. Rows at step 0
+    (free slots) take no expert, write no state and are never read.
+
+    Returns the leaves it changed (`lcrec_paged_state_zeros`); the prompt's
+    end states ``kda_s0_*``/``kda_conv0_*`` are read-only."""
+    kinds = paged_layer_kinds(model.cfg)
+    S, W, C = state["beam_seqs"].shape
+    K = codebook_size
+    live = steps >= 1
+    c_in = jnp.clip(steps - 1, 0, C - 1)  # the code fed; its suffix slot too
+    c_out = jnp.clip(steps, 0, C - 1)  # the code this step decides
+    first = steps == 1  # the beams still share the prompt's end state
+    parent = state["parent"]
+
+    def own(leaf, shared=None):
+        """A per-beam leaf (S, W, ...) as this step's beams see it: gathered
+        by the last step's ``parent``; at a slot's first step the prompt's,
+        to all."""
+        idx = parent.reshape((S, W) + (1,) * (leaf.ndim - 2))
+        out = jnp.take_along_axis(leaf, idx, axis=1)
+        if shared is not None:
+            pick = first.reshape((S,) + (1,) * (leaf.ndim - 1))
+            out = jnp.where(pick, shared[:, None], out)
+        return out
+
+    rows = lambda a: a.reshape((S * W,) + a.shape[2:])  # a row a beam
+
+    caches, page_layer = [], 0
+    for i, kind in enumerate(kinds):
+        if kind == "kda":
+            caches.append({
+                "s": rows(own(state[f"kda_s{i}"], state[f"kda_s0_{i}"])),
+                "conv": rows(own(state[f"kda_conv{i}"], state[f"kda_conv0_{i}"])),
+                "idx": jnp.zeros((), jnp.int32)})
+        else:
+            caches.append({
+                "k_pool": k_pools[page_layer], "v_pool": v_pools[page_layer],
+                "block_tables": block_tables, "seq_lens": seq_lens, "t": c_in,
+                "sk": own(state[f"suf_k{i}"]), "sv": own(state[f"suf_v{i}"])})
+            page_layer += 1
+
+    tok = jnp.take_along_axis(state["beam_seqs"], c_in[:, None, None], axis=2)[..., 0]
+    tok_ids = (tok + base_vocab + c_in[:, None] * K).reshape(S * W, 1)
+    beams = lambda a: jnp.repeat(a, W, axis=0)[:, None]
+    logits, caches = model.apply(
+        {"params": params}, tok_ids, beams(seq_lens + c_in), caches,
+        beams(live.astype(jnp.int32)), method=QwenLM.decode_paged)
+
+    with jax.named_scope("beam_update"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32) / temperature, axis=-1)
+        lo = base_vocab + c_out * K
+        logp_w = jax.vmap(lambda row, at: jax.lax.dynamic_slice(row, (at,), (K,)))(
+            logp, jnp.repeat(lo, W)).reshape(S, W, K)
+        seqs, scores, rank, parent = beam_step(
+            trie, logp_w, state["beam_seqs"], state["beam_logps"],
+            state["beam_rank"], c_out)
+    out = {"beam_seqs": seqs, "beam_logps": scores, "beam_rank": rank,
+           "parent": parent}
+    for i, (kind, c) in enumerate(zip(kinds, caches)):
+        if kind == "kda":
+            out[f"kda_s{i}"] = c["s"].reshape(state[f"kda_s{i}"].shape)
+            out[f"kda_conv{i}"] = c["conv"].reshape(state[f"kda_conv{i}"].shape)
+        else:
+            out[f"suf_k{i}"], out[f"suf_v{i}"] = c["sk"], c["sv"]
+    return out

@@ -208,6 +208,9 @@ class _PagedRunner:
             if isinstance(spec_cfg, (set, frozenset, list, tuple))
             else bool(spec_cfg)
         )
+        # A head refuses, by name, what its paged path does not implement.
+        head.paged_check_options(kv_dtype=cfg.kv_dtype, spec_decode=want_spec,
+                                 mesh=engine._mesh)
         self.spec_topology = None
         if (want_spec and getattr(head, "supports_spec", False)
                 and head.spec_depth >= 1):
@@ -481,7 +484,7 @@ class _PagedRunner:
         eng.metrics.record_evict(len(lost))
         self.clear_prefix_cache("kv_pools_lost")
         self.pool.reset_device_pools()
-        eng.metrics.set_pool_gauges(self.head.name, self.pool.stats())
+        eng.metrics.set_pool_gauges(self.head.name, self.pool_stats())
         eng._flight.record("kv_pools_lost", head=self.head.name,
                            slots_failed=len(lost))
         eng._log.error(
@@ -572,11 +575,24 @@ class _PagedRunner:
         slot = self.pool.admit_shared(centry.pages, centry.n_tokens)
         self.prefix.touch(centry.key)
         centry.hits += 1
+        t_bind = time.monotonic()
         self.slots.bind(
             slot,
             head.paged_warm_state(centry.init, centry.n_tokens, own_L)
             if centry.init is not None else None,
         )
+        if self.slots.recurrent_nbytes and centry.init is not None:
+            # A head with recurrent leaves: the snapshot is megabytes, not
+            # a beam's few numbers, so its rows go to the device NOW, and
+            # the lane shows what the restore cost (`admit.restore_state`).
+            # Every other head's rows wait for the next step's flush.
+            staged = self.slots.flush()
+            if eng._tracer.enabled:
+                self._phases.append((
+                    "admit.restore_state", t_bind, time.monotonic(),
+                    {"snapshot_bytes": centry.init_nbytes,
+                     "staged_bytes": staged},
+                ))
         t_admit = time.monotonic()
         self.entries[slot] = (*e, t_admit)
         self.buckets[slot] = centry.bucket
@@ -615,6 +631,13 @@ class _PagedRunner:
                 self._publish_prefix_gauges()
             return self.pool.admit(n_tok)  # may still raise: defer
 
+    def pool_stats(self) -> dict:
+        """The pool's gauges and, beside them, the bytes of the slot
+        table's recurrent leaves (state that is not KV: on the device for
+        as long as the table is, whatever the slots hold)."""
+        return {**self.pool.stats(),
+                "recurrent_state_bytes": self.slots.recurrent_nbytes}
+
     def prefix_stats(self) -> dict:
         if self.prefix is None:
             return {}
@@ -649,7 +672,7 @@ class _PagedRunner:
                 "prefix_cache_invalidated", head=self.head.name,
                 reason=reason, entries=n,
             )
-            eng.metrics.set_pool_gauges(self.head.name, self.pool.stats())
+            eng.metrics.set_pool_gauges(self.head.name, self.pool_stats())
         self._publish_prefix_gauges()
         return n
 
@@ -664,7 +687,7 @@ class _PagedRunner:
                 reason=reason, pages=n,
             )
             self.engine.metrics.set_pool_gauges(self.head.name,
-                                                self.pool.stats())
+                                                self.pool_stats())
         return n
 
     def _run_prefill(self, entries, slots, L: int,
@@ -692,7 +715,11 @@ class _PagedRunner:
         # The init rows come to the host (one fetch): the table stages
         # them with its next row write, and the prefix cache snapshots
         # them below.
-        init = {k: v[:n] for k, v in jax.device_get(init).items()}
+        init = jax.device_get(init)
+        # One number a launch, not rows (`Head.paged_prefill_counters`).
+        counters = {k: init.pop(k) for k in head.paged_prefill_counters
+                    if k in init}
+        init = {k: v[:n] for k, v in init.items()}
         self.slots.bind(slots, init)
         t_prefilled = time.monotonic()
         inserted = 0
@@ -718,14 +745,18 @@ class _PagedRunner:
                 eng.metrics.record_prefix_insert(head.name)
                 inserted += 1
             self._publish_prefix_gauges()
-        # Real history positions, in the ladder's own unit (what L counts).
+        # Real history positions, in the ladder's own unit (what L counts),
+        # and the prompt tokens they are (the KV tokens the slots hold).
         tokens = sum(min(max(head.natural_len(r), 1), L) for r in reqs)
+        prompt_tokens = int(self.pool.seq_lens[slots].sum())
         if eng._tracer.enabled:
             self._phases += [
                 ("prefill.stage", t_admit, t_launch,
-                 {"bucket_b": B, "bucket_l": L, "rows": n, "tokens": tokens}),
+                 {"bucket_b": B, "bucket_l": L, "rows": n, "tokens": tokens,
+                  "prompt_tokens": prompt_tokens}),
                 ("prefill.launch", t_launch, t_launched, {}),
-                ("prefill.pull", t_launched, t_prefilled, {}),
+                ("prefill.pull", t_launched, t_prefilled,
+                 {k: float(v) for k, v in counters.items()}),
                 ("prefill.retain", t_prefilled, time.monotonic(),
                  {"inserted": inserted}),
             ]
@@ -748,7 +779,8 @@ class _PagedRunner:
                                    parent_id=root, bucket_b=B, bucket_l=L,
                                    **ident)
         eng.metrics.record_admit(n)
-        eng.metrics.record_batch(head.name, (B, L), rows=n, tokens=tokens)
+        eng.metrics.record_batch(head.name, (B, L), rows=n, tokens=tokens,
+                                 prompt_tokens=prompt_tokens)
         self._sweep_finished()  # heads whose init step == total finish here
 
     # -- decode (one fixed-shape step over all slots) ------------------------
@@ -874,7 +906,7 @@ class _PagedRunner:
             self.entries[slot] = None
             self.buckets[slot] = None
             eng.metrics.record_evict(1)
-        eng.metrics.set_pool_gauges(head.name, self.pool.stats())
+        eng.metrics.set_pool_gauges(head.name, self.pool_stats())
         self._publish_prefix_gauges()
         return len(done)
 

@@ -78,6 +78,19 @@ class Head:
       operands);
     - ``paged_finalize(state_row, req)``: slot state -> response payload.
 
+    A head's page layers are a SUBSET of its layers: ``paged_layout``
+    describes only the layers that have keys (the LCRec head's
+    full-attention layers; its KDA layers have none). What the other
+    layers carry from token to token lives in the slot's row:
+    ``paged_recurrent_leaves`` names those state leaves (a constant-size
+    recurrent state a beam, and the prompt's end state a slot that the
+    prefill hands over in ``init`` and a prefix entry snapshots), so the
+    engine can count their bytes. ``paged_prefill_counters`` names keys of
+    the prefill's ``init`` that are one number a LAUNCH, not rows of slot
+    state: the runner takes them out before the bind (and reads them only
+    with the tracer on). ``paged_check_options`` refuses, by name, engine
+    options the head's paged path does not implement.
+
     Catalog heads additionally thread their trie through
     ``runtime_operands()`` (the engine inserts it between params and the
     batch in every compiled call), so the corpus swaps without a
@@ -100,6 +113,16 @@ class Head:
     #: prefill compilation so the head can extend both with drafter
     #: hints (TIGER's prefill-computed step-0 logits).
     supports_spec = False
+    #: State leaves that hold recurrent (non-KV) state, and keys of the
+    #: prefill's ``init`` that are launch-level counters (class docstring).
+    paged_recurrent_leaves: tuple = ()
+    paged_prefill_counters: tuple = ()
+
+    def paged_check_options(self, *, kv_dtype: str = "float32",
+                            spec_decode: bool = False, mesh=None,
+                            handoff: bool = False) -> None:
+        """Raise ValueError naming the first engine option this head's
+        paged path does not implement. Default: everything goes."""
 
     @property
     def spec_depth(self) -> int:
@@ -945,9 +968,21 @@ class LCRecGenerativeHead(Head):
     right edge (models/lcrec.py's HF left-pad convention). Decoding runs
     ``generate_topk_constrained`` with the snapshot's TensorTrie as a
     runtime operand: every emitted tuple is a corpus item, mapped back to
-    an item id through ``_CorpusLookup`` exactly like TIGER/COBRA. Dense
-    family only (``supports_paged=False``): warmup AOT-compiles every
-    ladder combo and steady state never recompiles.
+    an item id through ``_CorpusLookup`` exactly like TIGER/COBRA.
+
+    Two paths. The dense bucket path (``make_fn``): one executable a
+    (batch, history) bucket runs the whole generate loop over a dense
+    cache. The PAGED path (``supports_paged``, for a backbone whose
+    mixers are full attention and KDA): the prompt's K and V of the
+    full-attention layers go to the engine's page pool, shared by a
+    slot's beams; each KDA layer's recurrent state and convolution tails
+    live in the slot's row, a beam's own, beside the K and V of its few
+    generated tokens (`models/lcrec.lcrec_prefill_paged`,
+    `lcrec_paged_decode_step`). Code 0 is resolved AT PREFILL from the
+    prompt's last position (COBRA's pattern: ``paged_init_step`` 1), so a
+    request takes C - 1 decode steps, one model pass each, none wasted.
+    The paged path refuses int8 pages, speculative decode, a mesh and the
+    disaggregated hand-off (`paged_check_options`).
     """
 
     generative = True
@@ -1066,6 +1101,127 @@ class LCRecGenerativeHead(Head):
                  sem_ids=np.asarray(sem_ids[i]))
             for i in range(len(reqs))
         ]
+
+    # ---- paged decode protocol ---------------------------------------------
+
+    @property
+    def supports_paged(self) -> bool:
+        """Whether every mixer of the backbone has a paged form (a head
+        over latent or sparse attention keeps the dense bucket path)."""
+        try:
+            self._paged_kinds()
+        except ValueError:
+            return False
+        return True
+
+    def paged_check_options(self, *, kv_dtype: str = "float32",
+                            spec_decode: bool = False, mesh=None,
+                            handoff: bool = False) -> None:
+        refused = {
+            "kv_dtype='int8'": kv_dtype == "int8",
+            "spec_decode": bool(spec_decode),
+            "mesh": mesh is not None,
+            "the disaggregated hand-off": bool(handoff),
+        }
+        for option, asked in refused.items():
+            if asked:
+                raise ValueError(
+                    f"head {self.name!r}: the paged LCRec path does not "
+                    f"implement {option} (its slot rows hold a recurrent "
+                    "state a beam beside the KV pages); serve it with that "
+                    "option off, or on the dense bucket path (paged=False)")
+
+    @property
+    def paged_init_step(self) -> int:
+        return 1  # code 0 resolves at prefill
+
+    @property
+    def paged_total_steps(self) -> int:
+        return self.num_codebooks
+
+    paged_result_leaves = ("beam_seqs", "beam_logps")
+    #: The backbone's counters the prefill hands out beside its rows.
+    paged_prefill_counters = ("expert_pairs_per_held_expert",)
+
+    def _paged_kinds(self) -> tuple:
+        from genrec_tpu.models.lcrec import paged_layer_kinds
+
+        return paged_layer_kinds(self.model.cfg)
+
+    @property
+    def paged_recurrent_leaves(self) -> tuple:
+        return tuple(
+            f"{leaf}{i}" for i, kind in enumerate(self._paged_kinds())
+            if kind == "kda"
+            for leaf in ("kda_s", "kda_conv", "kda_s0_", "kda_conv0_"))
+
+    @property
+    def paged_init_leaves(self) -> tuple:
+        return ("beam_seqs", "beam_logps", "beam_rank") + tuple(
+            leaf for leaf in self.paged_recurrent_leaves if "0_" in leaf)
+
+    def paged_layout(self):
+        """The layers that HAVE pages: the full-attention ones."""
+        cfg = self.model.cfg
+        n = sum(kind == "attention" for kind in self._paged_kinds())
+        if n == 0:
+            raise ValueError(
+                f"head {self.name!r}: no full-attention layer, so nothing "
+                "to page; serve a pure-recurrent backbone on the dense path")
+        return n, cfg.num_key_value_heads, cfg.head_dim, self.model.dtype
+
+    def paged_kv_tokens(self, n_items: int, L_bucket: int) -> int:
+        # C codebook tokens an item; an emptied history attends one pad token
+        return max(min(int(n_items), self._clamp(L_bucket)) * self.num_codebooks, 1)
+
+    def paged_state_zeros(self, n_slots: int) -> dict:
+        from genrec_tpu.models.lcrec import lcrec_paged_state_zeros
+
+        return lcrec_paged_state_zeros(
+            self.model, n_slots, self.top_k, self.num_codebooks)
+
+    def make_prefill_paged_fn(self, B: int, L: int):
+        from genrec_tpu.models.lcrec import lcrec_prefill_paged
+
+        del B, L  # shapes come from make_batch/block_tables
+
+        def fn(params, trie, ids, mask, block_tables, k_pools, v_pools):
+            k_pools, v_pools, init, counters = lcrec_prefill_paged(
+                self.model, params, trie, ids, mask, block_tables, k_pools,
+                v_pools, self.base_vocab, self.num_codebooks,
+                self.codebook_size, self.top_k)
+            init.update({k: counters[k] for k in self.paged_prefill_counters
+                         if k in counters})
+            return k_pools, v_pools, init
+
+        return fn
+
+    def make_decode_paged_fn(self):
+        from genrec_tpu.models.lcrec import lcrec_paged_decode_step
+
+        def fn(params, trie, state, steps, block_tables, seq_lens,
+               k_pools, v_pools):
+            return lcrec_paged_decode_step(
+                self.model, params, trie, state, steps, block_tables,
+                seq_lens, k_pools, v_pools, self.base_vocab,
+                self.codebook_size)
+
+        return fn
+
+    def paged_finalize(self, row: dict, req) -> dict:
+        sem = np.asarray(row["beam_seqs"])
+        return dict(items=self._lookup(sem), scores=np.asarray(row["beam_logps"]),
+                    sem_ids=sem)
+
+    def prefix_key_tokens(self, req, max_history: int):
+        """The effective item history alone (no user conditioning). The
+        layers are causal, but the prefill also opens the beams from the
+        last position and ends the recurrent states there: a grown history
+        needs both again, so only a full-key match is admissible (an
+        incremental prefill FROM the snapshot is ROADMAP M7)."""
+        h = _clip_history(req.history, self._clamp(max_history))
+        h = h[h < len(self.item_sem_ids)]  # same drop rule as make_batch
+        return tuple(int(x) for x in h)
 
 
 class NoteLLMRetrievalHead(Head):

@@ -503,16 +503,22 @@ class PrefixEntry:
     ``init`` is the donor's post-prefill slot-state rows (host numpy) —
     what a warm admission restores instead of running the prefill
     executable; None for heads whose prefill leaves the state zeroed
-    (TIGER). ``bucket`` records the donor's prefill (B, L) for the
-    response's provenance field."""
+    (TIGER). For a head with recurrent layers it is the SNAPSHOT a warm
+    admit needs beside the pages: each such layer's end state and
+    convolution tails, megabytes where a beam's numbers are bytes, so
+    ``init_nbytes`` counts it. ``bucket`` records the donor's prefill
+    (B, L) for the response's provenance field."""
 
-    __slots__ = ("key", "n_tokens", "pages", "init", "bucket", "hits")
+    __slots__ = ("key", "n_tokens", "pages", "init", "init_nbytes", "bucket",
+                 "hits")
 
     def __init__(self, key, n_tokens, pages, init=None, bucket=None):
         self.key = tuple(key)
         self.n_tokens = int(n_tokens)
         self.pages = list(pages)
         self.init = init
+        self.init_nbytes = sum(
+            int(np.asarray(v).nbytes) for v in (init or {}).values())
         self.bucket = bucket
         self.hits = 0
 
@@ -558,6 +564,7 @@ class PrefixIndex:
             collections.OrderedDict()
         )
         self._retained_pages = 0
+        self._snapshot_bytes = 0  # of the entries' ``init`` rows (host)
 
     def __len__(self) -> int:
         return len(self._lru)
@@ -567,6 +574,10 @@ class PrefixIndex:
         """Page refs the index holds (entries never share pages with
         each other: each run came from one donor prefill)."""
         return self._retained_pages
+
+    def entries(self) -> list[PrefixEntry]:
+        """The retained entries, least recently used first."""
+        return list(self._lru.values())
 
     def lookup(self, key) -> tuple[PrefixEntry | None, int]:
         """(exact entry or None, matched token depth). Only a FULL-key
@@ -612,6 +623,7 @@ class PrefixIndex:
         node.entry = entry
         self._lru[key] = entry
         self._retained_pages += len(entry.pages)
+        self._snapshot_bytes += entry.init_nbytes
         return entry
 
     def remove(self, key) -> PrefixEntry | None:
@@ -635,6 +647,7 @@ class PrefixIndex:
     def _release(self, entry: PrefixEntry) -> None:
         self._alloc.free(entry.pages)
         self._retained_pages -= len(entry.pages)
+        self._snapshot_bytes -= entry.init_nbytes
 
     def _evict_lru(self) -> PrefixEntry:
         key = next(iter(self._lru))
@@ -675,4 +688,8 @@ class PrefixIndex:
         return {
             "entries": len(self._lru),
             "retained_pages": self._retained_pages,
+            # The entries' state snapshots: rows kept on the HOST (a warm
+            # admit stages them), so none of these bytes are device bytes.
+            "snapshot_bytes": self._snapshot_bytes,
+            "snapshot_device_bytes": 0,
         }

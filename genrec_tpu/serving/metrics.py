@@ -109,6 +109,7 @@ class ServingMetrics:
         self.prefill_row_slots = 0
         self.prefill_tokens = 0
         self.prefill_token_slots = 0
+        self.prefill_prompt_tokens = 0  # KV tokens the prefills wrote
         self.rejected_by_head: collections.Counter = collections.Counter()
         # Per-head submit/deferral attribution (the SLO monitor's rate
         # denominators/numerators — engine totals would let one head's
@@ -309,11 +310,12 @@ class ServingMetrics:
             self.catalog_swaps += 1
 
     def record_batch(self, head: str, bucket: tuple[int, int],
-                     rows: int | None = None, tokens: int = 0) -> None:
+                     rows: int | None = None, tokens: int = 0,
+                     prompt_tokens: int = 0) -> None:
         """One bucketed executable call. A paged prefill also gives its
         real ``rows`` and ``tokens`` (history positions in the ladder's
         own unit, `head.natural_len`), counted against the bucket's
-        B and B x L."""
+        B and B x L, and the ``prompt_tokens`` it wrote to pages."""
         with self._lock:
             self.batches += 1
             self.bucket_hits[(head, *bucket)] += 1
@@ -322,6 +324,7 @@ class ServingMetrics:
                 self.prefill_row_slots += bucket[0]
                 self.prefill_tokens += tokens
                 self.prefill_token_slots += bucket[0] * bucket[1]
+                self.prefill_prompt_tokens += prompt_tokens
 
     def record_response(self, queue_wait: float, compute: float, total: float,
                         head: str | None = None) -> None:
@@ -423,6 +426,7 @@ class ServingMetrics:
                 prefill_row_slots=self.prefill_row_slots,
                 prefill_tokens=self.prefill_tokens,
                 prefill_token_slots=self.prefill_token_slots,
+                prefill_prompt_tokens=self.prefill_prompt_tokens,
                 overload_rejected=self.overload_rejected,
             )
             decode_steps_by_slots = {

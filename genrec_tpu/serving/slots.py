@@ -113,6 +113,11 @@ class SlotTable:
         )
         self._state = jax.device_put(zeros, self._placement)
         self._avals = sds_tree(self._state)  # donated buffers come and go
+        #: Bytes of the leaves the head names as recurrent state
+        #: (`Head.paged_recurrent_leaves`): what the table holds that is
+        #: not KV, for the engine's gauges.
+        self.recurrent_nbytes = tree_nbytes(
+            [self._avals[k] for k in getattr(head, "paged_recurrent_leaves", ())])
         # The host's copy of the leaves the head's finalize reads, as of
         # the last launch or bind: what `row` serves.
         self._host = {k: np.array(zeros[k]) for k in head.paged_result_leaves}
@@ -274,7 +279,7 @@ class SlotTable:
         self._steps[idx] = self.head.paged_init_step
         self._active[idx] = True
 
-    def _flush(self) -> int:
+    def flush(self) -> int:
         """Write the rows queued by `bind` to the device: one launch of
         the row-write program for each R of them, dispatched and not
         waited for. Returns the bytes staged."""
@@ -305,7 +310,7 @@ class SlotTable:
         trip that nothing on the serving path makes."""
         keys = self._host if keys is None else keys
         if any(k not in self._host for k in keys):
-            self._flush()
+            self.flush()
         return {
             k: np.array((self._host if k in self._host else self._state)[k][slot])
             for k in keys
@@ -337,7 +342,7 @@ class SlotTable:
         # inside the verify call, so staging is the only host-visible
         # slice of the draft phase.
         t_stage = time.monotonic()
-        staged_bytes = self._flush()
+        staged_bytes = self.flush()
         # The step's vectors as ONE staged array, a row a slot: its step
         # (0 on an inactive slot), its KV length, its block table. A
         # host-to-device transfer costs by the array, not by the byte.

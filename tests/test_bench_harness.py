@@ -154,6 +154,7 @@ def test_chip_smoke_last_line_is_the_drivers_object(monkeypatch, tmp_path, capsy
                         lambda *a, **k: (None, data, None, {}))
     monkeypatch.setattr(chip_smoke, "phase_serve",
                         lambda *a: {"paged_config": [8, 10, 6, 64, 16, 4]})
+    monkeypatch.setattr(chip_smoke, "phase_serve_lcrec", lambda *a, **k: {})
     monkeypatch.setattr(chip_smoke, "phase_kernels", lambda *a, **k: {})
     monkeypatch.setattr(chip_smoke, "phase_lcrec_keye", lambda *a, **k: None)
     monkeypatch.setattr(chip_smoke, "phase_lcrec_kimi_linear", lambda *a, **k: None)
@@ -163,8 +164,9 @@ def test_chip_smoke_last_line_is_the_drivers_object(monkeypatch, tmp_path, capsy
     head, _, summary = lines[-2].partition("chip_smoke: summary ")
     summary = json.loads(summary)
     assert head == "" and list(summary)[-1] == "claim" and summary["claim"] is None
-    assert set(summary["phases"]) == {"train", "serve", "kernels", "lcrec_keye",
-                                      "lcrec_kimi_linear", "four_chip"}
+    assert set(summary["phases"]) == {"train", "serve", "serve_lcrec", "kernels",
+                                      "lcrec_keye", "lcrec_kimi_linear",
+                                      "four_chip"}
     assert summary["phases"]["four_chip"]["ran"] is False  # one chip found
 
 

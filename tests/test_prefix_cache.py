@@ -165,9 +165,12 @@ def test_cobra_warm_hit_matches_cold_serving_including_full_bucket_edge(
     head = CobraGenerativeHead(model, valid, item_text_tokens=item_text,
                                top_k=4, name="cobra")
     # 8 items x (C+1) = 32 KV tokens -> 4 pages of 8.
+    from genrec_tpu.obs.spans import SpanTracer
+
+    tracer = SpanTracer(capacity=20_000, enabled=True)
     eng = ServingEngine(
         [head], params, ladder=BucketLadder((2,), (4, 8)), max_batch=2,
-        max_wait_ms=4.0, handle_signals=False, params_step=1,
+        max_wait_ms=4.0, handle_signals=False, params_step=1, tracer=tracer,
         paged_config=PagedConfig(max_slots=2, page_size=8, pages_per_slot=4,
                                  num_pages=25),
     ).start()
@@ -204,6 +207,11 @@ def test_cobra_warm_hit_matches_cold_serving_including_full_bucket_edge(
         st = eng.stats()
         assert st["prefix_cache"]["cobra"]["hits"] == 2
         assert st["recompilations"] == 0
+        # a snapshot of a beam's few numbers rides the next step's row
+        # write: only a head with recurrent leaves restores at the admit
+        assert eng._runners["cobra"].slots.recurrent_nbytes == 0
+        names = {s.name for s in tracer.spans()}
+        assert "warm_admit" in names and "admit.restore_state" not in names
     finally:
         eng.stop()
 
