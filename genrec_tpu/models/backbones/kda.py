@@ -26,6 +26,40 @@ PAIRWISE difference exp(G_i - G_j), j <= i, which never passes 1: the
 factorised form (k_i exp(G_i)) . (k_j exp(-G_j)) that a scalar gate allows
 overflows float32 here, where -G passes 88 inside 64 tokens. The pairwise
 tensor is (C, C, K) a head, which is why the chunk is small.
+
+Only the last three lines read S_0, so `kda_chunked` runs a row in GROUPS of
+m chunks, three stages a group (`_group`), all float32:
+
+1. `_state_free`, a chunk at a time: G, the pairwise sums for the rows k (A
+   before b) and q (the q-k products of the o line), the right-hand side
+   b * [V, K * exp(G)], q * exp(G), k * exp(G_C - G), exp(G_C). The decay
+   exp(G_i - G_j) times k_j, (C, C, K) a row and head, is summed against
+   k_i and q_i in the fusion that makes it; written out it would be 67 MB a
+   chunk at Kimi-Linear's training shape (B 1, H 32, K 128; 8.6 GB over a
+   row's 128 chunks) and 268 MB a chunk at Solar-Open2's two-row prefill
+   (B 2, H 64), so this stage stays a loop over chunks and never sees a row.
+2. ONE `solve_triangular` over the m * B * H systems (I + A) X = rhs, 64 x 64
+   with 256 right-hand columns. On the TPU the solve inverts the block in a
+   kernel that lays the BATCH along the 128 lanes, so a call on one chunk's
+   32 heads costs what a call on 128 systems costs (86 us: a row's 4,096
+   systems take 11.0 ms in 128 calls and 2.7 ms in 32). `_GROUP_BYTES`, the
+   (C, C, K) decay a group may stand for, is set to what makes 128 systems
+   at C 64, K 128, whatever B and H: 4 chunks a group at Kimi's shape, 2 and
+   1 at Solar's one- and two-row prefill. Larger groups read slower on the
+   chip.
+3. `_state_step`, a chunk at a time, all that reads the state: u = X_v - X_k S,
+   o = (q * exp(G)) S + qk u, S' = S * exp(G_C) + k_end^T u. Four products
+   and two elementwise lines; no exponential and no solve.
+
+A group hands from stage to stage the pairwise sums (m B H 2C C), the
+right-hand side and X (m B H C 2K each), q * exp(G) and k_end (m B H C K
+each): 29 MB at Kimi's shape and at both of Solar's. Held for a whole row
+they are 940 MB at Kimi's shape (2.35 GB at Solar's two-row 1,024-item
+bucket) and a backward pass keeps them, with cotangents as large beside
+them; that form was built and its step did not fit the chip (PERF.md
+section 6, PR 37). The group loop is rematerialised instead, as the chunk
+loop was, and keeps the state at each group's start (67 MB a layer at Kimi's
+shape, where the chunk loop kept 268).
 """
 
 from __future__ import annotations
@@ -41,6 +75,7 @@ from genrec_tpu.models.backbones.qwen import QwenConfig
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 _CHUNK = 64  # tokens a chunk: the pairwise decay is (C, C, K) a head
+_GROUP_BYTES = 1 << 28  # float32 (C, C, K) decay a group of chunks: 128 systems a solve at K = 128
 _CONV_KERNEL = 4  # the published short_conv_kernel_size
 
 
@@ -77,57 +112,89 @@ def kda_recurrent_step(q, k, v, g, b, s):
     return jnp.einsum("bhk,bhkv->bhv", q, s, precision=_HIGHEST), s
 
 
-def _chunk(s, x):
-    """One chunk, every row and head at once. s (B, H, K, V); q, k, g
-    (B, C, H, K); v (B, C, H, V); b (B, C, H)."""
+def _state_free(x):
+    """Stage 1's loop body, one chunk, every row and head at once: all that
+    does not read the state. q, k, g (B, C, H, K); v (B, C, H, V); b (B, C, H)
+    -> heads first: the pairwise sums (B, H, 2C, C), the right-hand side
+    (B, H, C, V + K), q * exp(G) and k decayed to the chunk's end (B, H, C, K),
+    exp(G_C) (B, H, K, 1)."""
     q, k, v, g, b = x
     C = q.shape[1]
     G = jnp.cumsum(g, axis=1)
-    # sum_c row_ic k_jc exp(G_ic - G_jc) for j <= i, the rows being k then q:
-    # one pass over the (2C, C, K) pairs; above the diagonal the difference
-    # is positive and never exponentiated
-    rows = jnp.concatenate([k, q], axis=1)
-    diff = jnp.concatenate([G, G], axis=1)[:, :, None] - G[:, None]
-    i, j = jnp.arange(2 * C)[:, None] % C, jnp.arange(C)[None, :]
-    decay = jnp.exp(jnp.where((j <= i)[None, :, :, None, None], diff, -jnp.inf))
-    pair = jnp.sum(rows[:, :, None] * decay * k[:, None], axis=-1)  # (B, 2C, C, H)
-    pair = pair.transpose(0, 3, 1, 2)  # (B, H, 2C, C)
-    bh = b.transpose(0, 2, 1)  # (B, H, C)
-    A = jnp.tril(pair[:, :, :C], -1) * bh[..., None]
-    qk = jnp.tril(pair[:, :, C:])
+    # sum_c row_ic k_jc exp(G_ic - G_jc) for j <= i, the rows being k then q.
+    # The (C, C, K) decay times k_j is made once and summed against both; above
+    # the diagonal the difference is positive and never exponentiated
+    i, j = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
+    decay = jnp.exp(jnp.where((j <= i)[None, :, :, None, None],
+                              G[:, :, None] - G[:, None], -jnp.inf))
+    kd = decay * k[:, None]  # (B, C, C, H, K)
+    pair = jnp.concatenate([jnp.sum(r[:, :, None] * kd, axis=-1) for r in (k, q)],
+                           axis=1)  # (B, 2C, C, H)
     eG = jnp.exp(G)
     heads_first = lambda a: a.transpose(0, 2, 1, 3)  # (B, H, C, .)
-    rhs = bh[..., None] * jnp.concatenate(
+    rhs = b.transpose(0, 2, 1)[..., None] * jnp.concatenate(
         [heads_first(v), heads_first(k * eG)], axis=-1)
-    sol = jax.scipy.linalg.solve_triangular(
-        jnp.eye(C, dtype=A.dtype) + A, rhs, lower=True, unit_diagonal=True)
-    V = v.shape[-1]
+    k_end = heads_first(k * jnp.exp(G[:, -1:] - G))  # decayed to the chunk's end
+    return (pair.transpose(0, 3, 1, 2), rhs, heads_first(q * eG), k_end,
+            heads_first(eG[:, -1:]).swapaxes(-1, -2))
+
+
+def _state_step(s, x):
+    """Stage 3's loop body, one chunk: all that reads the state. s (B, H, K, V);
+    sol (B, H, C, V + K) the solve's [X_v, X_k]; qk (B, H, C, C); qe, k_end
+    (B, H, C, K); eGC (B, H, K, 1) -> s and o (B, C, H, V)."""
+    sol, qk, qe, k_end, eGC = x
+    V = s.shape[-1]
     mm = lambda a, b_: jnp.matmul(a, b_, precision=_HIGHEST)
     u = sol[..., :V] - mm(sol[..., V:], s)  # (B, H, C, V)
-    o = mm(heads_first(q * eG), s) + mm(qk, u)
-    k_end = heads_first(k * jnp.exp(G[:, -1:] - G))  # decayed to the chunk's end
-    s = s * heads_first(eG[:, -1:]).swapaxes(-1, -2) + mm(k_end.swapaxes(-1, -2), u)
-    return s, o.transpose(0, 2, 1, 3)
+    o = mm(qe, s) + mm(qk, u)
+    return s * eGC + mm(k_end.swapaxes(-1, -2), u), o.transpose(0, 2, 1, 3)
+
+
+def _group(s, x):
+    """One group of m chunks: stage 1 a chunk at a time, stage 2 over all m
+    at once, stage 3 a chunk at a time. s (B, H, K, V); q, k, g
+    (m, B, C, H, K); v (m, B, C, H, V); b (m, B, C, H) -> s and o
+    (m, B, C, H, V)."""
+    *_, b = x
+    pair, rhs, qe, k_end, eGC = jax.lax.map(jax.checkpoint(_state_free), x)
+    C = rhs.shape[-2]
+    # A_ij = b_i sum_c k_ic k_jc ...: scaled HERE, so that b's gradient reads
+    # the group's own pair and stage 1's backward need not sum it again
+    A = pair[..., :C, :] * b.swapaxes(2, 3)[..., None]
+    # (I + A) X = rhs, every chunk, row and head one batch axis: the diagonal
+    # is taken as 1, and neither it nor what lies above it is read
+    sol = jax.scipy.linalg.solve_triangular(
+        A.reshape((-1, C, C)), rhs.reshape((-1,) + rhs.shape[-2:]),
+        lower=True, unit_diagonal=True).reshape(rhs.shape)
+    return jax.lax.scan(_state_step, s, (sol, pair[..., C:, :], qe, k_end, eGC))
 
 
 def kda_chunked(q, k, v, g, b, chunk: int = _CHUNK, s0=None):
-    """The recurrence over a whole block of L tokens, ``chunk`` at a time,
-    the chunk loop rematerialised in the backward pass (what it keeps is
-    the state at each chunk's start). q, k, g (B, L, H, K); v (B, L, H, V);
+    """The recurrence over a whole block of L tokens, ``chunk`` at a time, in
+    groups of chunks sized by `_GROUP_BYTES` (the module docstring's three
+    stages), each group rematerialised in the backward pass (what it keeps
+    is the state at each group's start). q, k, g (B, L, H, K); v (B, L, H, V);
     b (B, L, H); all float32, g = 0 and b = 0 at padding (the state passes
     through). Returns o (B, L, H, V) and the final state (B, H, K, V)."""
     B, L, H, K = q.shape
+    V = v.shape[-1]
     if s0 is None:
-        s0 = jnp.zeros((B, H, K, v.shape[-1]), jnp.float32)
+        s0 = jnp.zeros((B, H, K, V), jnp.float32)
     n = -(-L // chunk)
+    # chunks whose pairwise decay (C, C, K float32 a row and head) fits the budget
+    groups = -(-n // max(1, _GROUP_BYTES // (4 * B * H * chunk * chunk * K)))
+    m = -(-n // groups)
 
-    def chunks(a):
-        a = jnp.pad(a, [(0, 0), (0, n * chunk - L)] + [(0, 0)] * (a.ndim - 2))
-        return jnp.moveaxis(a.reshape((B, n, chunk) + a.shape[2:]), 1, 0)
+    def grouped(a):
+        a = jnp.pad(a, [(0, 0), (0, groups * m * chunk - L)] + [(0, 0)] * (a.ndim - 2))
+        a = a.reshape((B, groups, m, chunk) + a.shape[2:])
+        return jnp.moveaxis(a, 0, 2)  # (groups, m, B, chunk, ...)
 
-    s, o = jax.lax.scan(jax.checkpoint(_chunk), s0,
-                        tuple(chunks(a) for a in (q, k, v, g, b)))
-    return jnp.moveaxis(o, 0, 1).reshape((B, n * chunk) + o.shape[3:])[:, :L], s
+    s, o = jax.lax.scan(jax.checkpoint(_group), s0,
+                        tuple(grouped(a) for a in (q, k, v, g, b)))
+    o = jnp.moveaxis(o.reshape((groups * m, B, chunk, H, V)), 0, 1)  # (B, n, C, H, V)
+    return o.reshape((B, -1, H, V))[:, :L], s
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):

@@ -73,23 +73,105 @@ def _scan_inputs(fast_decay: bool):
     return q, k, v, g * m[..., None, None], b * m[..., None]
 
 
+def _start_state():
+    return jnp.asarray(np.random.default_rng(5).normal(size=(3, 4, 16, 16)) * 0.5,
+                       jnp.float32)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, outermost first, through whatever holds a
+    jaxpr of its own (a loop's body, a rematerialised function)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _scans(jaxpr):
+    return [e for e in _eqns(jaxpr) if e.primitive.name == "scan"]
+
+
+# (chunk, a start state, _GROUP_BYTES): the budget of the last two holds two
+# chunks of these shapes, so 96 tokens are 3 groups of 2 chunks of 16, and 2
+# groups of 2 chunks of 32 with a chunk of padding after the row
+_CASES = [(32, False, None), (kda._CHUNK, False, None), (128, False, None),
+          (kda._CHUNK, True, None), (16, True, 400_000), (32, True, 1_600_000)]
+
+
 @pytest.mark.parametrize("fast_decay", [False, True])
-@pytest.mark.parametrize("chunk", [32, kda._CHUNK, 128])
-def test_kda_chunked_form_is_the_recurrence(tiny, chunk, fast_decay):
+@pytest.mark.parametrize("chunk, start, group_bytes", _CASES)
+def test_kda_chunked_form_is_the_recurrence(tiny, monkeypatch, chunk, start,
+                                            group_bytes, fast_decay):
     """Outputs and final state, with left padding, at a row shorter than one
-    chunk (and a chunk longer than the row), and with no clamp where the
-    running decay leaves float32's range."""
+    chunk (and a chunk longer than the row), with no clamp where the running
+    decay leaves float32's range, from a start state that is not zero, and
+    over several groups of chunks."""
     ref = tiny[1]
     q, k, v, g, b = _scan_inputs(fast_decay)
+    s0 = _start_state() if start else None
     if fast_decay:
         assert float(jnp.cumsum(g[0], axis=0)[31].min()) < -100.0
-    o, s = kda.kda_chunked(q, k, v, g, b, chunk)
+    if group_bytes is not None:
+        monkeypatch.setattr(kda, "_GROUP_BYTES", group_bytes)
+        groups = _scans(jax.make_jaxpr(
+            lambda: kda.kda_chunked(q, k, v, g, b, chunk, s0))().jaxpr)[0]
+        assert groups.params["length"] == {16: 3, 32: 2}[chunk]
+    o, s = kda.kda_chunked(q, k, v, g, b, chunk, s0)
     assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(s).all())
     for r in range(3):
-        o_ref, s_ref = ref.delta_rule(q[r], k[r], v[r], g[r], b[r])
+        o_ref, s_ref = ref.delta_rule(q[r], k[r], v[r], g[r], b[r],
+                                      None if s0 is None else s0[r])
         np.testing.assert_allclose(np.asarray(o[r]), np.asarray(o_ref), atol=2e-6)
         np.testing.assert_allclose(np.asarray(s[r]), np.asarray(s_ref), atol=2e-5)
-    np.testing.assert_array_equal(np.asarray(o[2, :87]), 0.0)  # nothing written yet
+    if s0 is None:
+        np.testing.assert_array_equal(np.asarray(o[2, :87]), 0.0)  # nothing written yet
+
+
+@pytest.mark.parametrize("group_bytes", [None, 400_000])
+def test_kda_chunked_gradient_is_the_recurrences(tiny, monkeypatch, group_bytes):
+    """Every input's gradient, the start state's among them, through the
+    outputs and the final state; one group, and 3 groups of 2 chunks of 16."""
+    ref = tiny[1]
+    args = _scan_inputs(False) + (_start_state(),)
+    rng = np.random.default_rng(6)
+    wo = jnp.asarray(rng.normal(size=args[2].shape), jnp.float32)
+    ws = jnp.asarray(rng.normal(size=args[5].shape), jnp.float32)
+    if group_bytes is not None:
+        monkeypatch.setattr(kda, "_GROUP_BYTES", group_bytes)
+
+    def loss(scan):
+        def f(*a):
+            o, s = scan(*a)
+            return jnp.sum(wo * o) + jnp.sum(ws * s)
+        return f
+
+    got = jax.grad(loss(lambda q, k, v, g, b, s0: kda.kda_chunked(
+        q, k, v, g, b, 16, s0)), argnums=tuple(range(6)))(*args)
+    want = jax.grad(loss(jax.vmap(ref.delta_rule)), argnums=tuple(range(6)))(*args)
+    for name, a, t in zip("q k v g b s0".split(), got, want):
+        assert float(jnp.linalg.norm(t)) > 0, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(t), err_msg=name,
+                                   atol=2e-5 * max(1.0, float(jnp.abs(t).max())))
+
+
+@pytest.mark.parametrize("group_bytes", [kda._GROUP_BYTES, 400_000])
+def test_kda_state_scan_holds_no_state_free_work(monkeypatch, group_bytes):
+    """The one loop that runs a chunk at a time WITH the state carries no
+    ``exp`` and no triangular solve: the pairwise decay, the solve and the
+    decayed keys and queries are made before it, over a whole group."""
+    monkeypatch.setattr(kda, "_GROUP_BYTES", group_bytes)
+    q, k, v, g, b = _scan_inputs(False)
+    jaxpr = jax.make_jaxpr(lambda *a: kda.kda_chunked(*a, 16, _start_state()))(
+        q, k, v, g, b).jaxpr
+    assert {"exp", "triangular_solve"} <= {e.primitive.name for e in _eqns(jaxpr)}
+    carrying = [e for e in _scans(jaxpr) if e.params["num_carry"] > 0]
+    groups, state = carrying  # the groups' loop, and the chunks' inside it
+    assert state in _scans(groups.params["jaxpr"].jaxpr)
+    assert groups.params["length"] * state.params["length"] == 6  # 96 tokens
+    body = {e.primitive.name for e in _eqns(state.params["jaxpr"].jaxpr)}
+    assert "dot_general" in body
+    assert not body & {"exp", "exp2", "triangular_solve", "scan", "while",
+                       "cumsum", "custom_linear_solve"}, body
 
 
 def test_kda_mixer_matches_the_reference_and_counts_what_it_keeps(tiny):
