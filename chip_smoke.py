@@ -50,7 +50,7 @@ Every check raises and every phase's failure is the script's:
 nothing is caught and carried past. Off a TPU it exits non-zero before
 doing any work. Only if everything passed does it exit 0, and then its
 last two lines are ``chip_smoke: summary {...}`` — per-phase wall /
-XLA-compile / run seconds, persistent-cache hits and misses, peak device
+XLA-compile / cache-load / run seconds, peak device
 memory, the kernel table, ``"claim": null`` — and, LAST, the one JSON
 object the driver reads: ``{"ok": true, "device": {"platform", "kind",
 "count"}}`` with exactly those keys, the device as JAX reports it.
@@ -140,8 +140,10 @@ def check(ok, *detail) -> None:
 
 
 class _Phases:
-    """Wall / XLA-compile / run seconds per phase, from the host clock and
-    the repo's process-wide compile tap (obs.goodput.CompileEvents)."""
+    """Wall / XLA-compile / cache-load / run seconds per phase, from the
+    host clock and the repo's process-wide compile tap
+    (obs.goodput.CompileEvents: a load from the persistent cache is not a
+    compile)."""
 
     def __init__(self):
         from genrec_tpu.obs.goodput import CompileEvents
@@ -153,30 +155,18 @@ class _Phases:
     def phase(self, name: str):
         print(f"chip_smoke: phase {name} ...", flush=True)
         t0, (n0, s0) = time.perf_counter(), self._events.snapshot()
+        l0, ls0 = self._events.load_snapshot()
         entry = self.report[name] = {}
         yield entry
         wall, (n1, s1) = time.perf_counter() - t0, self._events.snapshot()
+        l1, ls1 = self._events.load_snapshot()
+        built = (s1 - s0) + (ls1 - ls0)
         entry.update(
             wall_s=round(wall, 2), xla_compiles=n1 - n0,
-            xla_compile_s=round(s1 - s0, 2), run_s=round(wall - (s1 - s0), 2),
+            xla_compile_s=round(s1 - s0, 2), cache_loads=l1 - l0,
+            cache_load_s=round(ls1 - ls0, 2), run_s=round(wall - built, 2),
         )
         print(f"chip_smoke: phase {name} ok {json.dumps(entry)}", flush=True)
-
-
-def _cache_counters() -> dict:
-    """Count JAX's persistent-compile-cache hit/miss events from here on."""
-    import jax.monitoring
-
-    counts = {"hits": 0, "misses": 0}
-
-    def listen(event: str, **kwargs) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            counts["hits"] += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            counts["misses"] += 1
-
-    jax.monitoring.register_event_listener(listen)
-    return counts
 
 
 def _gin_argv(save_dir: str, bindings: dict) -> list[str]:
@@ -737,7 +727,6 @@ def main(argv=None) -> int:
     )
     if not args.rehearse:
         require_tpu("chip_smoke")
-    cache = _cache_counters()
     phases = _Phases()
     bindings = dict(SMOKE_BINDINGS)
     if args.rehearse:
@@ -782,7 +771,7 @@ def main(argv=None) -> int:
     print("chip_smoke: summary " + json.dumps({
         "jax": jax.__version__,
         "rehearsal": args.rehearse,
-        "compile_cache": {"dir": cache_dir, **cache},
+        "compile_cache": {"dir": cache_dir},
         "phases": phases.report,
         "peak_bytes_in_use": peak,
         "kernels": kernels,

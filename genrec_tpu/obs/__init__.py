@@ -6,7 +6,9 @@ serving, streaming-training -> hot-serving) sit on:
 - `spans`           — request/step-scoped tracer, Chrome-trace export,
                       jax.profiler bridging
 - `goodput`         — training wall-time classified into buckets,
-                      fleet-wide aggregation, XLA compile-event tap
+                      fleet-wide aggregation, and the compile tap: every
+                      JAX trace, lowering, XLA compile and cache load,
+                      registered when this package is imported
 - `flight_recorder` — bounded structured-event ring dumped atomically on
                       SIGTERM / crash / chaos kill points
 - `export`          — Prometheus-style text exposition of any snapshot
@@ -39,6 +41,11 @@ from genrec_tpu.obs.memory import (
 )
 from genrec_tpu.obs.slo import SLOMonitor, SLOTarget
 from genrec_tpu.obs.spans import NULL_TRACER, Span, SpanTracer, TraceContext
+
+# From here on every compile of the process is in the tap's log, the
+# adapters' and trainers' weight initialisation included; a listener costs
+# nothing until JAX compiles.
+CompileEvents.ensure()
 
 __all__ = [
     "BUCKETS",

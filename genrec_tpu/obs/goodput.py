@@ -16,7 +16,8 @@ step-section time:
 - ``restore``        — inside `loop.resume` (integrity ladder + device put)
 - ``preemption_drain``— inside the preemption save + monitor flush
 - ``compile``        — XLA compile seconds observed DURING step dispatch
-                       (`CompileEvents`, a process-wide jax.monitoring tap)
+                       (`CompileEvents`, a process-wide jax.monitoring tap;
+                       a persistent-cache load is not a compile)
 - ``nonfinite_skipped``— the step time attributed to steps the jitted
                        guard skipped (streak steps * mean step time — the
                        flag read is deferred one step, so per-step
@@ -40,9 +41,13 @@ cross-cutting leaf layer — every layer feeds it, it imports none of them
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import dataclasses
+import itertools
 import threading
 import time
+import weakref
 from typing import Mapping
 
 #: Reporting order. compute/other are derived; the rest are measured.
@@ -60,37 +65,158 @@ BUCKETS = (
 _MEASURED = ("checkpoint_save", "restore", "data_wait", "preemption_drain")
 
 
-class CompileEvents:
-    """Process-wide tap on jax.monitoring backend-compile events.
+#: JAX's compile-pipeline events (`jax._src.dispatch`), by the work each
+#: times: tracing a function to a jaxpr, lowering it to an MLIR module, and
+#: the backend step, which is an XLA compile or a persistent-cache load.
+COMPILE_KINDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
 
-    One listener, registered once per process (jax.monitoring has no
-    unregister, so scoped consumers take snapshot deltas instead of their
-    own listeners). ``snapshot()`` returns ``(count, seconds)`` of XLA
-    backend compiles observed so far — the packed loop diffs it around
-    step dispatch to catch an unexpected mid-run recompile the moment it
-    happens instead of discovering it in a slow epoch.
+#: The persistent cache's verdicts, fired on the compiling thread inside
+#: the backend event they belong to (a miss only where an entry is written,
+#: which `parallel.mesh.enable_compile_cache`'s zero thresholds make every
+#: miss).
+_CACHE_VERDICTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+
+#: Trace id of the compile lane (`spans.LANE_PREFIXES`).
+COMPILE_LANE = "compile"
+
+
+@dataclasses.dataclass(frozen=True)
+class CompileEvent:
+    seq: int
+    kind: str  # "trace" | "lower" | "backend"
+    fun: str
+    t0: float  # time.monotonic(), like every span of the ring
+    t1: float
+    cache: str = ""  # backend only: "hit" | "miss" | "off"
+    thread: int = 0
+
+    def record(self, tracer) -> None:
+        attrs = {"fun": self.fun, "seq": self.seq}
+        if self.cache:
+            attrs["cache"] = self.cache
+        tracer.record_span(f"compile.{self.kind}", COMPILE_LANE, self.t0,
+                           self.t1, **attrs)
+
+
+class CompileEvents:
+    """Process-wide tap on JAX's compile pipeline: every jaxpr trace, MLIR
+    lowering and backend step (XLA compile or persistent-cache load).
+
+    One pair of listeners, registered once per process when `genrec_tpu.obs`
+    is imported; scoped consumers take snapshot deltas or attach a tracer
+    instead of registering listeners of their own. JAX times each
+    stage on the wall clock and reports it twice on exit, as a duration and
+    as a time span; the time-span listener takes both (its end is read as
+    `time.monotonic()` at the callback and the duration subtracted). A
+    backend event is a LOAD when the cache's hit fired on its thread inside
+    it: ``cache="hit"``, ``"miss"`` where an entry was written, ``"off"``
+    where neither (no cache in use).
+
+    ``snapshot()`` counts XLA compiles only, ``load_snapshot()`` the loads:
+    the packed loop diffs the first around step dispatch to catch an
+    unexpected mid-run recompile the moment it happens. Every event also
+    goes to a bounded log (`LOG_CAPACITY`, ``dropped`` counts what fell
+    off), and `attach` replays that log onto an enabled `SpanTracer` as the
+    lane `compile` and sends it each later event: set-up's compiles then
+    sit on the span clock beside everything else. With no tracer attached
+    an event costs one deque append.
+
+    The log keeps the OUTERMOST trace of a nest: a jitted function traced
+    inside another's trace reports first, and leaves the log when the
+    enclosing trace on its thread reports (it adds nothing to the lane's
+    union, and a model's init or step nests thousands: 6,600 to 12,400 a
+    process on the chip, which would push set-up's early events off a log
+    of this size). A tracer attached by then got it live all the same.
     """
+
+    LOG_CAPACITY = 4096
 
     _instance: "CompileEvents | None" = None
     _instance_lock = threading.Lock()
 
     def __init__(self):
         self._lock = threading.Lock()
-        self.count = 0
+        self._local = threading.local()
+        self.count = 0  # XLA compiles: backend events the cache did not serve
         self.seconds = 0.0
+        self.loads = 0  # persistent-cache loads
+        self.load_seconds = 0.0
+        self.dropped = 0
+        self._log: collections.deque[CompileEvent] = collections.deque(
+            maxlen=self.LOG_CAPACITY)
+        self._seq = itertools.count(1)
+        self._tracers: "weakref.WeakSet" = weakref.WeakSet()
 
-    def _listen(self, key: str, seconds: float, **kwargs) -> None:
-        # One event per XLA backend compile; the jaxpr-trace/MLIR-lower
-        # events for the same jit are folded into the same bucket.
-        if not key.endswith("backend_compile_duration"):
+    def _on_event(self, event: str, **kwargs) -> None:
+        verdict = _CACHE_VERDICTS.get(event)
+        if verdict is not None:
+            self._local.cache = verdict  # the next backend event's
+
+    def _on_span(self, event: str, start_time: float, end_time: float,
+                 **kwargs) -> None:
+        kind = COMPILE_KINDS.get(event)
+        if kind is None:
             return
+        t1 = time.monotonic()
+        seconds = max(float(end_time) - float(start_time), 0.0)
+        cache = ""
+        if kind == "backend":
+            cache = getattr(self._local, "cache", None) or "off"
+            self._local.cache = None
         with self._lock:
-            self.count += 1
-            self.seconds += float(seconds)
+            ev = CompileEvent(next(self._seq), kind,
+                              str(kwargs.get("fun_name", "")), t1 - seconds,
+                              t1, cache, threading.get_ident())
+            log = self._log
+            while (kind == "trace" and log and log[-1].kind == "trace"
+                   and log[-1].thread == ev.thread and log[-1].t0 >= ev.t0):
+                log.pop()
+            if len(self._log) == self.LOG_CAPACITY:
+                self.dropped += 1
+            self._log.append(ev)
+            if cache == "hit":
+                self.loads += 1
+                self.load_seconds += seconds
+            elif cache:
+                self.count += 1
+                self.seconds += seconds
+            tracers = list(self._tracers) if self._tracers else ()
+        for tracer in tracers:
+            ev.record(tracer)
 
     def snapshot(self) -> tuple[int, float]:
+        """(count, seconds) of XLA compiles so far; loads are not compiles."""
         with self._lock:
             return self.count, self.seconds
+
+    def load_snapshot(self) -> tuple[int, float]:
+        """(count, seconds) of persistent-cache loads so far."""
+        with self._lock:
+            return self.loads, self.load_seconds
+
+    def events(self) -> list[CompileEvent]:
+        with self._lock:
+            return list(self._log)
+
+    def attach(self, tracer) -> None:
+        """Replay the log onto an enabled tracer as compile spans, then send
+        it every later event. Once per tracer; a disabled one is ignored,
+        and a tracer is held weakly."""
+        if tracer is None or not tracer.enabled:
+            return
+        with self._lock:
+            if tracer in self._tracers:
+                return
+            for ev in self._log:
+                ev.record(tracer)
+            self._tracers.add(tracer)
 
     @classmethod
     def ensure(cls) -> "CompileEvents":
@@ -99,7 +225,8 @@ class CompileEvents:
                 inst = cls()
                 import jax.monitoring
 
-                jax.monitoring.register_event_duration_secs_listener(inst._listen)
+                jax.monitoring.register_event_listener(inst._on_event)
+                jax.monitoring.register_event_time_span_listener(inst._on_span)
                 cls._instance = inst
             return cls._instance
 

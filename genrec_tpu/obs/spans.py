@@ -34,13 +34,13 @@ One clock with a device profile: `profile_anchor()`, called right
 after `jax.profiler.start_trace` (`core.profiling.ProfileWindow` does),
 enters a `TraceAnnotation` named `ANCHOR` and records a span of that name
 at the same `time.monotonic()` reading, so the ring and the captured
-`.xplane.pb` join by one offset. ``bridge_jax=True`` additionally enters
-`jax.profiler.TraceAnnotation` for every context-manager span.
+`.xplane.pb` join by one offset.
 
 Besides request and epoch traces the ring holds LANES: trace ids under
-`LANE_PREFIXES` (`batcher/<head>`, `train-e<n>`, `profile-<n>`) carry
-flat per-iteration phase spans, not a rooted tree, and readers that
-count requests leave them out (`is_lane`).
+`LANE_PREFIXES` (`batcher/<head>`, `train-e<n>`, `profile-<n>`, and
+`compile`, which `goodput.CompileEvents.attach` fills) carry flat phase
+spans, not a rooted tree, and readers that count requests leave them out
+(`is_lane`).
 """
 
 from __future__ import annotations
@@ -59,7 +59,7 @@ from typing import Any, Mapping
 ANCHOR = "span_clock_anchor"
 
 #: Trace-id prefixes of lanes: flat phase spans of one thread's loop.
-LANE_PREFIXES = ("batcher/", "train-e", "profile-")
+LANE_PREFIXES = ("batcher/", "train-e", "profile-", "compile")
 
 
 def is_lane(trace_id: str) -> bool:
@@ -147,7 +147,7 @@ _NULL_CTX = _NullCtx()
 
 class _SpanCtx:
     __slots__ = ("_tracer", "name", "trace_id", "attrs", "_t0", "span_id",
-                 "_parent", "_jax_ctx")
+                 "_parent")
 
     def __init__(self, tracer: "SpanTracer", name: str, trace_id: str | None,
                  attrs: dict):
@@ -166,19 +166,11 @@ class _SpanCtx:
         self._parent = stack[-1][1] if stack else None
         self.span_id = tracer._next_span_id()
         stack.append((self.trace_id, self.span_id))
-        self._jax_ctx = None
-        if tracer.bridge_jax:
-            import jax
-
-            self._jax_ctx = jax.profiler.TraceAnnotation(self.name)
-            self._jax_ctx.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
         t1 = time.monotonic()
-        if self._jax_ctx is not None:
-            self._jax_ctx.__exit__(*exc)
         tracer = self._tracer
         stack = tracer._stack()
         # Pop OUR frame even if an inner span leaked (exception unwound
@@ -199,9 +191,8 @@ class SpanTracer:
     """Thread-safe span recorder with a bounded completed-span ring."""
 
     def __init__(self, capacity: int = 8192, enabled: bool = True,
-                 bridge_jax: bool = False, max_exemplars: int = 8):
+                 max_exemplars: int = 8):
         self.enabled = enabled
-        self.bridge_jax = bridge_jax
         self.max_exemplars = max_exemplars
         self._ring: collections.deque[Span] = collections.deque(maxlen=capacity)
         self._lock = threading.Lock()

@@ -43,7 +43,12 @@ def enable_compile_cache() -> str:
     environment's to choose — JAX reads the variable itself and no code
     sets another. Otherwise the cache is `COMPILE_CACHE_DIR`. Either way
     the two thresholds drop to zero, so the small AOT bucket executables
-    of the serving ladder are kept too."""
+    of the serving ladder are kept too. The compile tap is registered here
+    as well (`obs.CompileEvents`), so it sees every load and compile of
+    the process from its entry on."""
+    from genrec_tpu.obs import CompileEvents
+
+    CompileEvents.ensure()
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)

@@ -118,6 +118,7 @@ import numpy as np
 
 from genrec_tpu.core import chaos
 from genrec_tpu.obs.flight_recorder import get_flight_recorder
+from genrec_tpu.obs.goodput import CompileEvents
 from genrec_tpu.obs.memory import MemoryLedger, tree_nbytes
 from genrec_tpu.obs.slo import SLOMonitor, SLOTarget
 from genrec_tpu.obs.spans import NULL_TRACER, SpanTracer
@@ -1043,6 +1044,7 @@ class ServingEngine:
         # default NULL_TRACER keeps every hot-path check to one attribute
         # read. The flight recorder is always on (bounded ring).
         self._tracer = tracer if tracer is not None else NULL_TRACER
+        CompileEvents.ensure().attach(tracer)
         # Every flight event this engine records is stamped with its
         # owner identity (component + replica_id, evaluated at record
         # time — the fleet router assigns replica_id AFTER construction),
@@ -1321,8 +1323,10 @@ class ServingEngine:
         """Swap the tracer LIVE (turn tracing on/off against a running
         engine — no recompile, no restart). Requests submitted before the
         swap keep the trace context minted at their submit; every record
-        site guards on that per-entry context, so mixing is safe."""
+        site guards on that per-entry context, so mixing is safe. An
+        enabled tracer gets the process's compiles (`CompileEvents.attach`)."""
         self._tracer = tracer if tracer is not None else NULL_TRACER
+        CompileEvents.ensure().attach(tracer)
 
     def _span_ident(self) -> dict:
         """Identity attrs stamped on every span this engine records:
@@ -1456,9 +1460,17 @@ class ServingEngine:
     # -- batcher -------------------------------------------------------------
 
     def _batch_loop(self) -> None:
+        # (start, tracer) of the empty period in progress (nothing queued,
+        # every runner idle), where a tracer is on. Only a submit ends it:
+        # the iteration that finds a request queued ends it there and
+        # records it once, as `batcher.empty`, after its admission and
+        # before any phase of its own is committed.
+        empty_period, empty_end = None, None
         try:
             while True:
                 try:
+                    if empty_period is not None and any(self._queues.values()):
+                        empty_end = time.monotonic()
                     if (
                         self._guard is not None
                         and self._guard.fired
@@ -1486,6 +1498,9 @@ class ServingEngine:
                         if not swap_pending:
                             progressed |= runner.admit()
                         progressed |= runner.step()
+                        if empty_end is not None:
+                            self._record_empty(*empty_period, empty_end)
+                            empty_period = empty_end = None
                         if runner._phases:
                             runner.flush_phases(self._seq)
                     batch = self._next_batch()
@@ -1499,6 +1514,10 @@ class ServingEngine:
                         empty = all(not q for q in self._queues.values())
                         runners_idle = all(r.idle for r in self._runners.values())
                         done = self._draining and empty and runners_idle
+                        if (empty_period is None and empty and runners_idle
+                                and not done and self._runners
+                                and self._tracer.enabled):
+                            empty_period = (time.monotonic(), self._tracer)
                         if not done:
                             if self._tracer.enabled and not empty:
                                 waited = {
@@ -1531,6 +1550,8 @@ class ServingEngine:
                                 live=runner.slots.live, **ident,
                             )
                     if done:
+                        if empty_period is not None:
+                            self._record_empty(*empty_period, time.monotonic())
                         # Drained: release every retained prefix page —
                         # and any speculative scratch reservation — so
                         # the pool accounts clean at shutdown ("all pages
@@ -1547,6 +1568,18 @@ class ServingEngine:
                     self._log.exception("serving: batcher iteration failed")
         finally:
             self._drained.set()
+
+    def _record_empty(self, t0: float, tracer: SpanTracer, t1: float) -> None:
+        """One `batcher.empty` span on every runner's lane: the engine held
+        no request from ``t0`` to ``t1``, so a device-idle gap there is the
+        traffic's, not the host's. Dropped where the tracer was swapped
+        since the period began."""
+        if tracer is not self._tracer:
+            return
+        ident = self._span_ident()
+        for runner in self._runners.values():
+            tracer.record_span("batcher.empty", runner.lane, t0, t1,
+                               seq=self._seq, **ident)
 
     def _poll_slo(self) -> None:
         """Feed the SLO monitor (batcher thread, rate-limited to

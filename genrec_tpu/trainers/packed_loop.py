@@ -177,6 +177,7 @@ class PackedTrainLoop:
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.recompiles = 0
         self._compile_events = CompileEvents.ensure()
+        self._compile_events.attach(tracer)
         self._steps_run = 0
         self._in_preempt = False
         self._flight = get_flight_recorder()
@@ -197,7 +198,9 @@ class PackedTrainLoop:
         # epoch-0-then-E — restart latency sits inside the preemption
         # grace window on large datasets.
         if self.pack_sequences and self._arrays_epoch != epoch:
-            self._arrays, rep = self._repack(epoch)
+            with self.tracer.span("train.repack", trace_id=f"train-e{epoch}",
+                                  epoch=epoch):
+                self._arrays, rep = self._repack(epoch)
             self._arrays_epoch = epoch
             if self._report is None:
                 # Rates only (n_examples/n_rows for timers): the example
@@ -247,7 +250,8 @@ class PackedTrainLoop:
         return any_across_processes(self.guard.fired)
 
     def _note_compile(self, n: int, seconds: float, global_step: int) -> None:
-        """Compile events observed during step dispatch. The run's FIRST
+        """XLA compiles observed during step dispatch (a persistent-cache
+        load is not one). The run's FIRST
         step compiles by design; any later one is an unexpected mid-run
         recompile (shape drift, donation mismatch, cache eviction) —
         counted, logged at warning, and flight-recorded, the same
@@ -420,9 +424,11 @@ class PackedTrainLoop:
             if max_steps is not None and global_step >= max_steps:
                 break
             c_n0, c_s0 = self._compile_events.snapshot()
+            l_n0, l_s0 = self._compile_events.load_snapshot()
             state, m = step_fn(state, sharded)
             t_dispatched = time.monotonic()
             c_n1, c_s1 = self._compile_events.snapshot()
+            l_n1, l_s1 = self._compile_events.load_snapshot()
             # Guard-skipped steps contribute 0 to the epoch mean — one
             # NaN batch must not turn the whole epoch summary NaN (NaN*0
             # is still NaN, so select, don't scale; the per-step wandb
@@ -474,10 +480,10 @@ class PackedTrainLoop:
                     step=global_step)
                 rec("train.sync", trace_id, t_dispatched, t_done,
                     step=global_step)
-                if c_n1 > c_n0:
+                if c_n1 > c_n0 or l_n1 > l_n0:
                     rec("train.compile", trace_id, t_step, t_dispatched,
                         step=global_step, n=c_n1 - c_n0,
-                        seconds=c_s1 - c_s0)
+                        seconds=c_s1 - c_s0, loads=l_n1 - l_n0)
             tail = (t_done, global_step)
             if self.step_hook is not None:
                 self.step_hook(state, epoch, consumed, global_step)

@@ -41,9 +41,9 @@ dump them beside the spans) are rendered as a per-component table —
 every event carries component/replica_id/worker_id stamps since the
 lineage PR, so a multi-replica ring reads attributably.
 
-Lanes (`batcher/<head>`, `train-e<n>`, `profile-<n>`: flat per-iteration
-phase spans of one thread's loop, docs/OBSERVABILITY.md "The batcher
-lane") show in the phase table like any span, but are not requests: they
+Lanes (`batcher/<head>`, `train-e<n>`, `profile-<n>`, `compile`: flat
+phase spans of one thread's loop or of the process's compiles,
+docs/OBSERVABILITY.md "The batcher lane", "The compile lane") show in the phase table like any span, but are not requests: they
 are counted apart from the traces and never enter the critical path, so
 they cannot read as unrooted.
 
@@ -60,7 +60,7 @@ from collections import defaultdict
 
 #: Trace-id prefixes of lanes (genrec_tpu/obs/spans.LANE_PREFIXES; kept
 #: here too so this CLI needs nothing but the trace file).
-LANE_PREFIXES = ("batcher/", "train-e", "profile-")
+LANE_PREFIXES = ("batcher/", "train-e", "profile-", "compile")
 
 
 def is_lane(trace_id) -> bool:

@@ -42,7 +42,7 @@ from genrec_tpu.obs import (
     prometheus_text,
     tree_nbytes,
 )
-from genrec_tpu.obs.spans import NULL_TRACER
+from genrec_tpu.obs.spans import NULL_TRACER, is_lane
 from genrec_tpu.parallel import get_mesh, replicate
 from genrec_tpu.trainers.packed_loop import PackedTrainLoop
 
@@ -237,9 +237,112 @@ def test_compile_events_tap_counts_fresh_jits():
     tap = CompileEvents.ensure()
     assert tap is CompileEvents.ensure()  # singleton
     n0, s0 = tap.snapshot()
-    jax.jit(lambda x: x * 2.0 + 1.23456)(jnp.ones(5))  # fresh shape+expr
+    # A constant of this run's own: no persistent cache holds the program,
+    # so it is an XLA compile and not a load.
+    c = float(time.time_ns() % 1_000_003) / 7.0
+    jax.jit(lambda x: x * 2.0 + c)(jnp.ones(5))  # fresh shape+expr
     n1, s1 = tap.snapshot()
     assert n1 > n0 and s1 > s0
+
+
+def test_compile_tap_persistent_cache_load_is_not_a_compile(tmp_path):
+    """A fresh persistent cache: the first compile of a function is a
+    `compile.backend` with ``cache="miss"``; after `jax.clear_caches()` the
+    same function comes back from the cache, a `compile.backend` with
+    ``cache="hit"`` that adds a load and nothing to the compile count, nor
+    to the goodput meter's compile bucket (which the loop feeds from the
+    count's delta)."""
+    from jax._src import compilation_cache
+
+    tap = CompileEvents.ensure()
+    tracer = SpanTracer()
+    tap.attach(tracer)
+    t_start = time.monotonic()
+    was = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+    compilation_cache.reset_cache()
+    try:
+        def f(x):
+            return jnp.sin(x) * 3.0 + 0.5
+        x = jnp.ones(11)
+        jax.jit(f)(x).block_until_ready()
+        jax.clear_caches()
+        (n0, s0), (l0, _) = tap.snapshot(), tap.load_snapshot()
+        meter = GoodputMeter()
+        t0 = time.perf_counter()
+        jax.jit(f)(x).block_until_ready()
+        (n1, s1), (l1, ls1) = tap.snapshot(), tap.load_snapshot()
+        meter.note_step(time.perf_counter() - t0, compile_seconds=s1 - s0)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+        compilation_cache.reset_cache()
+    assert (n1, s1) == (n0, s0) and l1 == l0 + 1 and ls1 > 0
+    assert meter.end_epoch()["buckets"]["compile"] == 0.0
+    ours = [s for s in tracer.spans("compile") if s.t0 >= t_start]
+    backend = [s for s in ours
+               if s.name == "compile.backend" and s.attrs["fun"] == "jit(f)"]
+    assert [s.attrs["cache"] for s in backend] == ["miss", "hit"]
+    # The pipeline's other stages ran both times: no cache saves them.
+    for stage, fun in (("compile.trace", "f"), ("compile.lower", "jit(f)")):
+        assert sum(s.name == stage and s.attrs["fun"] == fun
+                   for s in ours) == 2
+
+
+def test_compile_lane_replayed_then_live():
+    """An enabled tracer attached to the tap gets the process's past
+    compiles (the log) and then each new one as it happens, once each, on
+    the lane `compile` and on `time.monotonic()`; a disabled one gets
+    nothing."""
+    tap = CompileEvents.ensure()
+    c = float(time.time_ns() % 1_000_003) / 11.0
+    t_before = time.monotonic()
+    jax.jit(lambda x: x - c)(jnp.ones(3))
+    past = [e for e in tap.events() if e.t0 >= t_before]
+    assert {e.kind for e in past} >= {"trace", "lower", "backend"}
+    assert all(t_before <= e.t0 <= e.t1 <= time.monotonic() for e in past)
+
+    tracer, off = SpanTracer(), SpanTracer(enabled=False)
+    tap.attach(tracer)
+    tap.attach(tracer)  # once per tracer
+    tap.attach(off)
+    ring = tracer.spans()
+    assert all(s.trace_id == "compile" and is_lane(s.trace_id) for s in ring)
+    got = {s.attrs["seq"] for s in ring}
+    assert {e.seq for e in past} <= got
+    assert len(got) == len(ring)  # no event twice
+    n = len(ring)
+    jax.jit(lambda x: x * c)(jnp.ones(3))  # after the attach: live
+    live = tracer.spans()[n:]
+    assert {s.name for s in live} >= {"compile.trace", "compile.lower",
+                                      "compile.backend"}
+    assert all("cache" in s.attrs for s in live
+               if s.name == "compile.backend")
+    assert not off.spans()
+
+
+def test_compile_log_keeps_the_outermost_trace():
+    """A jitted function traced inside another's trace leaves the log when
+    the enclosing trace reports; a tracer attached before got both."""
+    tap = CompileEvents.ensure()
+    tracer = SpanTracer()
+    tap.attach(tracer)
+    c = float(time.time_ns() % 1_000_003) / 13.0
+    t_before = time.monotonic()
+
+    @jax.jit
+    def inner(x):
+        return x * c
+
+    def outer(x):
+        return inner(x) + 1.0
+
+    jax.jit(outer)(jnp.ones(3))
+    logged = {e.fun for e in tap.events()
+              if e.kind == "trace" and e.t0 >= t_before}
+    assert "outer" in logged and "inner" not in logged
+    live = {s.attrs["fun"] for s in tracer.spans("compile")
+            if s.name == "compile.trace" and s.t0 >= t_before}
+    assert {"outer", "inner"} <= live
 
 
 # ---------------------------------------------------------------------------
@@ -1043,9 +1146,13 @@ def test_log_serving_stats_hbm_line_per_head():
 
 
 def _toy_loop(tmp_path, tracer=None):
+    # A constant of this run's own, so that no persistent cache holds the
+    # step: its first dispatch is an XLA compile, never a load.
+    fresh = (time.time_ns() % 1_000_003) * 1e-12
+
     def loss_fn(params, batch, rng):
         pred = batch["x"] @ params["w"]
-        return jnp.mean((pred - batch["y"]) ** 2), {}
+        return jnp.mean((pred - batch["y"]) ** 2) + fresh, {}
 
     params = {"w": jax.random.normal(jax.random.key(0), (4, 2))}
     opt = optax.adam(1e-2)
