@@ -10,6 +10,7 @@ new shape.
 from __future__ import annotations
 
 import dataclasses
+from operator import itemgetter
 from typing import Iterator, Mapping, Sequence
 
 import numpy as np
@@ -173,6 +174,66 @@ class PackingReport:
         )
 
 
+def _ffd_layout(
+    lengths: np.ndarray, capacity: int, max_segments: int | None,
+) -> tuple[np.ndarray, np.ndarray, int]:
+    """First-fit-decreasing in array form: ``(order, bin_of, n_bins)``,
+    where ``order`` is the processing order (a stable sort by decreasing
+    length) and ``bin_of[j]`` the bin of item ``order[j]``.
+
+    Items are placed a run of equal lengths at a time. Within a run of
+    length ``n``, first fit fills the open bins in index order: a bin
+    takes ``min(remaining // n, max_segments - count)`` items, and one
+    that stops fitting stays full for the rest of the run (its room only
+    shrinks). The run's leftover items then open new bins of
+    ``min(capacity // n, max_segments)`` items each. So a run is a
+    cumsum, a searchsorted and a bincount over the open bins — the same
+    bins the item-by-item scan makes, with no per-item Python."""
+    if lengths.size and int(lengths.max()) > capacity:
+        raise ValueError(
+            f"example length {int(lengths.max())} exceeds row capacity {capacity}"
+        )
+    if (lengths <= 0).any():
+        raise ValueError("every example must have at least one token")
+    if max_segments is not None and max_segments < 1:
+        raise ValueError(f"max_segments must be at least 1, got {max_segments}")
+    # Every item holds >= 1 slot, so a bin never holds more than `capacity`.
+    seg_cap = capacity if max_segments is None else max_segments
+    order = np.argsort(-lengths, kind="stable")
+    if not order.size:
+        return order, order, 0
+    ordered = lengths[order]
+    starts = np.flatnonzero(np.r_[True, ordered[1:] != ordered[:-1]])
+    ends = np.r_[starts[1:], len(ordered)]
+    bin_of = np.empty(len(ordered), np.int64)
+    remaining = np.empty(len(ordered), np.int64)  # at most one bin/item
+    count = np.empty(len(ordered), np.int64)
+    n_bins = 0
+    for s, e in zip(starts.tolist(), ends.tolist()):
+        n = int(ordered[s])
+        placed = 0
+        if n_bins:
+            take = np.minimum(remaining[:n_bins] // n, seg_cap - count[:n_bins])
+            cum = np.cumsum(take)
+            placed = min(e - s, int(cum[-1]))
+        if placed:
+            b = np.searchsorted(cum, np.arange(placed), side="right")
+            bin_of[s:s + placed] = b
+            added = np.bincount(b, minlength=n_bins)
+            remaining[:n_bins] -= added * n
+            count[:n_bins] += added
+        left = e - s - placed
+        if left:
+            per = min(capacity // n, seg_cap)
+            new = n_bins + np.arange(left) // per
+            bin_of[s + placed:e] = new
+            fill = np.bincount(new - n_bins)
+            remaining[n_bins:n_bins + len(fill)] = capacity - fill * n
+            count[n_bins:n_bins + len(fill)] = fill
+            n_bins += len(fill)
+    return order, bin_of, n_bins
+
+
 def first_fit_decreasing(
     lengths: Sequence[int], capacity: int, max_segments: int | None = None,
 ) -> list[list[int]]:
@@ -186,36 +247,20 @@ def first_fit_decreasing(
     consumers that allocate per-segment work (TIGER's per-example
     decoders) pay for that max on every row.
 
-    The first-fit scan runs in numpy (one C-speed pass over open bins per
-    example) — the pure-Python scan was minutes of startup at Amazon
-    scale (~1e5 examples, ~2e4 bins)."""
-    lengths = np.asarray(lengths, np.int64)
-    if lengths.size and int(lengths.max()) > capacity:
-        raise ValueError(
-            f"example length {int(lengths.max())} exceeds row capacity {capacity}"
-        )
-    if (lengths <= 0).any():
-        raise ValueError("every example must have at least one token")
-    order = np.argsort(-lengths, kind="stable")
-    bins: list[list[int]] = []
-    n_bins = 0
-    remaining = np.empty(len(lengths), np.int64)  # at most one bin/example
-    for idx in order:
-        n = int(lengths[idx])
-        fits = np.nonzero(remaining[:n_bins] >= n)[0]
-        if fits.size:
-            b = int(fits[0])
-            bins[b].append(int(idx))
-            remaining[b] -= n
-            if max_segments is not None and len(bins[b]) == max_segments:
-                remaining[b] = -1  # full: no further examples
-        else:
-            bins.append([int(idx)])
-            remaining[n_bins] = capacity - n
-            if max_segments == 1:
-                remaining[n_bins] = -1
-            n_bins += 1
-    return bins
+    The first fit places one run of equal lengths at a time, with a few
+    array operations over the open bins each (`_ffd_layout`); a bin lists
+    its examples in the order they were placed."""
+    order, bin_of, _ = _ffd_layout(
+        np.asarray(lengths, np.int64), capacity, max_segments)
+    by_bin = np.argsort(bin_of, kind="stable")
+    items = order[by_bin].tolist()
+    bins = bin_of[by_bin].tolist()
+    out: list[list[int]] = []
+    for b, idx in zip(bins, items):
+        if b == len(out):
+            out.append([])
+        out[b].append(idx)
+    return out
 
 
 def pack_examples(
@@ -256,54 +301,73 @@ def pack_examples(
     """
     if not examples:
         raise ValueError("pack_examples needs at least one example")
-    if seed is not None:
-        perm = np.random.default_rng(seed).permutation(len(examples))
-        examples = [examples[int(i)] for i in perm]
+    n_ex = len(examples)
+    perm = (np.random.default_rng(seed).permutation(n_ex)
+            if seed is not None else np.arange(n_ex))
+    first = examples[int(perm[0])]
     seg_keys = tuple(segment_keys)
-    token_keys = [k for k in examples[0].keys() if k not in seg_keys]
+    token_keys = [k for k in first.keys() if k not in seg_keys]
     if not token_keys:
         raise ValueError("examples carry no token arrays")
-    lengths = [len(np.asarray(ex[token_keys[0]])) for ex in examples]
-    for ex, n in zip(examples, lengths):
-        for k in token_keys:
-            if len(np.asarray(ex[k])) != n:
-                raise ValueError(f"token key {k!r} length mismatch within example")
-    bins = first_fit_decreasing(lengths, row_len, max_segments)
-    R = len(bins)
+
+    def lengths_of(k):
+        return np.fromiter(map(len, map(itemgetter(k), examples)), np.int64, n_ex)
+
+    lengths = lengths_of(token_keys[0])
+    for k in token_keys[1:]:
+        if (lengths_of(k) != lengths).any():
+            raise ValueError(f"token key {k!r} length mismatch within example")
+    # The FFD sees the examples in permuted order.
+    order, bin_of, R = _ffd_layout(lengths[perm], row_len, max_segments)
+
+    # Lay the segments out row by row, in the order each row took them:
+    # `src` is the input index of each segment in that layout.
+    by_bin = np.argsort(bin_of, kind="stable")
+    rows = bin_of[by_bin]
+    src = perm[order[by_bin]]
+    seg_len = lengths[src]
+    row_start = np.searchsorted(rows, np.arange(R))  # first segment of a row
+    seg = np.arange(n_ex) - row_start[rows]  # 0-based segment index
+    seg_end = np.cumsum(seg_len)
+    seg_start = seg_end - seg_len  # in the laid-out token stream
     # With a cap, the segment axis is pinned to it so re-packs (per-epoch
     # seeds) keep a STATIC shape — no jit recompile when the realized
     # max shifts between epochs.
-    S = max_segments if max_segments is not None else max(len(b) for b in bins)
+    S = max_segments if max_segments is not None else int(seg.max()) + 1
 
-    out: dict[str, np.ndarray] = {
-        k: np.zeros((R, row_len), np.asarray(examples[0][k]).dtype)
-        for k in token_keys
-    }
+    # Each key is gathered once in input order (memory order: cheaper than
+    # visiting the examples shuffled), then taken into layout order by one
+    # index. A row's tokens fill its first slots contiguously, so a boolean
+    # mask of those slots takes the laid-out stream in one assignment.
+    positions = np.arange(int(seg_end[-1])) - np.repeat(seg_start, seg_len)
+    in_start = np.cumsum(lengths) - lengths  # an example's start, input order
+    take = np.repeat(in_start[src], seg_len) + positions
+    slots = np.arange(row_len) < np.add.reduceat(seg_len, row_start)[:, None]
+    seg_slot = rows * S + seg
+    # Dtypes follow the first (permuted) example's: values are cast on the
+    # gather, never upcast.
+    out: dict[str, np.ndarray] = {}
+    for k in token_keys:
+        dtype = np.asarray(first[k]).dtype
+        stream = np.concatenate(
+            list(map(itemgetter(k), examples)), dtype=dtype, casting="unsafe")
+        out[k] = np.zeros((R, row_len), dtype)
+        out[k][slots] = stream[take]
     out["segment_ids"] = np.zeros((R, row_len), np.int32)
+    out["segment_ids"][slots] = np.repeat(seg + 1, seg_len)
     out["positions"] = np.zeros((R, row_len), np.int32)
+    out["positions"][slots] = positions
     for k in seg_keys:
-        proto = np.asarray(examples[0][k])
-        out[k] = np.zeros((R, S) + proto.shape, proto.dtype)
+        proto = np.asarray(first[k])
+        values = np.array(list(map(itemgetter(k), examples)), proto.dtype)
+        flat = np.zeros((R * S,) + proto.shape, proto.dtype)
+        flat[seg_slot] = values[src]
+        out[k] = flat.reshape((R, S) + proto.shape)
     out["segment_valid"] = np.zeros((R, S), np.int32)
-
-    real_tokens = 0
-    for r, bin_idx in enumerate(bins):
-        cursor = 0
-        for s, idx in enumerate(bin_idx):
-            n = lengths[idx]
-            sl = slice(cursor, cursor + n)
-            for k in token_keys:
-                out[k][r, sl] = np.asarray(examples[idx][k])
-            out["segment_ids"][r, sl] = s + 1
-            out["positions"][r, sl] = np.arange(n)
-            for k in seg_keys:
-                out[k][r, s] = np.asarray(examples[idx][k])
-            out["segment_valid"][r, s] = 1
-            cursor += n
-            real_tokens += n
+    out["segment_valid"].reshape(-1)[seg_slot] = 1
     report = PackingReport(
-        n_examples=len(examples), n_rows=R, row_len=row_len,
-        real_tokens=real_tokens, max_segments=S,
+        n_examples=n_ex, n_rows=R, row_len=row_len,
+        real_tokens=int(seg_end[-1]), max_segments=S,
     )
     return out, report
 

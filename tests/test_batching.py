@@ -230,3 +230,208 @@ def test_batch_iterator_start_batch_resumes_exact_order():
         assert len(tail) == len(full) - k
         for a, b in zip(full[k:], tail):
             np.testing.assert_array_equal(a, b)
+
+
+# ------------------------------------------------- packer against its oracle
+#
+# The packer's item-by-item scan and per-example fill, kept verbatim as the
+# oracle: the run-at-a-time FFD and the columnar fill must give the same
+# bins and the same arrays, dtypes included.
+
+
+def _ref_first_fit_decreasing(lengths, capacity, max_segments=None):
+    lengths = np.asarray(lengths, np.int64)
+    if lengths.size and int(lengths.max()) > capacity:
+        raise ValueError(
+            f"example length {int(lengths.max())} exceeds row capacity {capacity}"
+        )
+    if (lengths <= 0).any():
+        raise ValueError("every example must have at least one token")
+    order = np.argsort(-lengths, kind="stable")
+    bins = []
+    n_bins = 0
+    remaining = np.empty(len(lengths), np.int64)  # at most one bin/example
+    for idx in order:
+        n = int(lengths[idx])
+        fits = np.nonzero(remaining[:n_bins] >= n)[0]
+        if fits.size:
+            b = int(fits[0])
+            bins[b].append(int(idx))
+            remaining[b] -= n
+            if max_segments is not None and len(bins[b]) == max_segments:
+                remaining[b] = -1  # full: no further examples
+        else:
+            bins.append([int(idx)])
+            remaining[n_bins] = capacity - n
+            if max_segments == 1:
+                remaining[n_bins] = -1
+            n_bins += 1
+    return bins
+
+
+def _ref_pack_examples(examples, row_len, *, segment_keys=(), max_segments=None,
+                       seed=None):
+    if not examples:
+        raise ValueError("pack_examples needs at least one example")
+    if seed is not None:
+        perm = np.random.default_rng(seed).permutation(len(examples))
+        examples = [examples[int(i)] for i in perm]
+    seg_keys = tuple(segment_keys)
+    token_keys = [k for k in examples[0].keys() if k not in seg_keys]
+    if not token_keys:
+        raise ValueError("examples carry no token arrays")
+    lengths = [len(np.asarray(ex[token_keys[0]])) for ex in examples]
+    for ex, n in zip(examples, lengths):
+        for k in token_keys:
+            if len(np.asarray(ex[k])) != n:
+                raise ValueError(f"token key {k!r} length mismatch within example")
+    bins = _ref_first_fit_decreasing(lengths, row_len, max_segments)
+    R = len(bins)
+    S = max_segments if max_segments is not None else max(len(b) for b in bins)
+
+    out = {
+        k: np.zeros((R, row_len), np.asarray(examples[0][k]).dtype)
+        for k in token_keys
+    }
+    out["segment_ids"] = np.zeros((R, row_len), np.int32)
+    out["positions"] = np.zeros((R, row_len), np.int32)
+    for k in seg_keys:
+        proto = np.asarray(examples[0][k])
+        out[k] = np.zeros((R, S) + proto.shape, proto.dtype)
+    out["segment_valid"] = np.zeros((R, S), np.int32)
+
+    real_tokens = 0
+    for r, bin_idx in enumerate(bins):
+        cursor = 0
+        for s, idx in enumerate(bin_idx):
+            n = lengths[idx]
+            sl = slice(cursor, cursor + n)
+            for k in token_keys:
+                out[k][r, sl] = np.asarray(examples[idx][k])
+            out["segment_ids"][r, sl] = s + 1
+            out["positions"][r, sl] = np.arange(n)
+            for k in seg_keys:
+                out[k][r, s] = np.asarray(examples[idx][k])
+            out["segment_valid"][r, s] = 1
+            cursor += n
+            real_tokens += n
+    return out, (len(examples), R, row_len, real_tokens, S)
+
+
+def _corpus(kind, seed, capacity):
+    """Lengths of one seeded corpus of the given kind."""
+    rng = np.random.default_rng([seed, capacity])
+    if kind == "random":
+        return rng.integers(1, capacity + 1, int(rng.integers(1, 400)))
+    if kind == "skewed":  # short histories dominate, as in the Amazon cell
+        return np.minimum(1 + rng.geometric(0.15, 500), capacity)
+    if kind == "runs":  # long runs of equal lengths, in shuffled order
+        vals = rng.integers(1, capacity + 1, 6)
+        return rng.permutation(np.repeat(vals, rng.integers(1, 80, 6)))
+    if kind == "at_capacity":  # whole rows mixed with items that fill gaps
+        return rng.permutation(np.r_[np.full(30, capacity),
+                                     rng.integers(1, capacity + 1, 120)])
+    if kind == "single":
+        return rng.integers(1, capacity + 1, 1)
+    if kind == "all_equal":
+        return np.full(int(rng.integers(1, 300)), int(rng.integers(1, capacity + 1)))
+    raise ValueError(kind)
+
+
+_KINDS = ("random", "skewed", "runs", "at_capacity", "single", "all_equal")
+
+
+@pytest.mark.parametrize("max_segments", [None, 1, 2, 3, 4])
+@pytest.mark.parametrize("kind", _KINDS)
+def test_ffd_matches_scan_oracle(kind, max_segments):
+    for seed in range(8):
+        for capacity in (1, 7, 16, 61):
+            lengths = _corpus(kind, seed, capacity)
+            assert first_fit_decreasing(lengths, capacity, max_segments) == (
+                _ref_first_fit_decreasing(lengths, capacity, max_segments)
+            ), (kind, seed, capacity)
+
+
+def test_ffd_rejects_a_cap_below_one():
+    with pytest.raises(ValueError, match="max_segments"):
+        first_fit_decreasing([3, 4], 16, max_segments=0)
+
+
+def test_ffd_of_nothing_is_no_bins():
+    assert first_fit_decreasing([], 16) == []
+
+
+def _rich_examples(n, row, seed, mixed_dtypes=False):
+    """Examples as the TIGER cell makes them: int32 token keys, a float
+    token key, a shaped segment key and a 0-d one. With ``mixed_dtypes``
+    later examples carry wider dtypes than the first, which the packer
+    casts down to the first (permuted) example's."""
+    rng = np.random.default_rng([seed, 5])
+    out = []
+    for i in range(n):
+        ln = int(rng.integers(1, row + 1))
+        wide = mixed_dtypes and i % 3 == 1
+        out.append({
+            "item_input_ids": rng.integers(1, 5000, ln).astype(
+                np.int64 if wide else np.int32),
+            "token_type_ids": (np.arange(ln) % 3).astype(np.int32),
+            "timestamps": rng.random(ln).astype(np.float64 if wide else np.float32),
+            "target_ids": rng.integers(0, 256, 3).astype(np.int64 if wide else np.int32),
+            "example_id": np.int32(i),
+        })
+    return out
+
+
+def _assert_packs_equal(got, want):
+    arrays, rep = got
+    ref_arrays, ref_rep = want
+    assert (rep.n_examples, rep.n_rows, rep.row_len, rep.real_tokens,
+            rep.max_segments) == ref_rep
+    assert list(arrays) == list(ref_arrays)
+    for k, v in ref_arrays.items():
+        assert arrays[k].dtype == v.dtype, k
+        assert arrays[k].shape == v.shape, k
+        np.testing.assert_array_equal(arrays[k], v, err_msg=k)
+
+
+@pytest.mark.parametrize("mixed_dtypes", [False, True])
+@pytest.mark.parametrize("max_segments", [None, 1, 4])
+@pytest.mark.parametrize("seed", [None, (7, 3), 2**31 + 12345])
+def test_pack_examples_matches_per_example_oracle(seed, max_segments, mixed_dtypes):
+    kw = dict(segment_keys=("target_ids", "example_id"),
+              max_segments=max_segments, seed=seed)
+    for corpus_seed, (n, row) in enumerate([(1, 16), (37, 16), (300, 61)]):
+        exs = _rich_examples(n, row, corpus_seed, mixed_dtypes)
+        _assert_packs_equal(pack_examples(exs, row, **kw),
+                            _ref_pack_examples(exs, row, **kw))
+
+
+def test_pack_examples_rejects_token_length_mismatch():
+    exs = _examples(n=6)
+    exs[4] = {**exs[4], "targets": np.ones(len(exs[4]["input_ids"]) + 1, np.int32)}
+    with pytest.raises(ValueError, match="'targets' length mismatch"):
+        pack_examples(exs, 16)
+    with pytest.raises(ValueError, match="'targets' length mismatch"):
+        _ref_pack_examples(exs, 16)
+
+
+@pytest.mark.parametrize("epoch", [0, 1, 2])
+def test_pack_examples_matches_oracle_on_the_tiger_cell_corpus(epoch):
+    """The TIGER training cell's own corpus (its generator and config,
+    cut to 3,000 examples), repacked as the cell repacks it each epoch."""
+    import json
+    from pathlib import Path
+
+    from benchmark.configs.tiger_amazon import adapter
+
+    root = Path(__file__).resolve().parents[1] / "benchmark"
+    cfg = json.loads((root / "configs/tiger_amazon/config.json").read_text())
+    traffic = json.loads((root / "traffic/train_packed.json").read_text())
+    traffic["corpus_examples"] = 3000
+    seed = 1
+    exs = adapter.make_examples(cfg, traffic, seed, adapter.make_catalog(cfg, seed))
+    kw = dict(segment_keys=("target_ids", "example_id"),
+              max_segments=int(traffic["pack_max_segments"]), seed=(seed, epoch))
+    row_len = 1 + cfg["max_items"] * cfg["sem_id_dim"]
+    _assert_packs_equal(pack_examples(exs, row_len, **kw),
+                        _ref_pack_examples(exs, row_len, **kw))
