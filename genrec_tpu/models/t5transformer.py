@@ -19,7 +19,11 @@ and cross-segment pairs are masked by the caller's additive `attn_mask`
 before the softmax. Do not index `rel_bias` with a tensor that has a
 batch axis: a per-row grid costs a gather and a scatter-add of
 rows*H*L*L values into 192 entries a layer, which was 89% of the packed
-step on a v5e (PERF.md section 6).
+step on a v5e (PERF.md section 6). The packed decoder's cross-attention
+reads that memory as the encoder left it, (R, L, d): a query batch S
+times the key batch is S examples a memory row, whose queries are folded
+into one (S*Lq)-long query axis against the row's K/V, so no (R*S, L, ·)
+copy of the memory or its K/V is made.
 
 Incremental decode (the KV-cached engine behind `tiger_generate`):
 beam-search generation keeps all decode tensors in (B, K, ...) layout —
@@ -101,7 +105,15 @@ class T5Attention(nn.Module):
             k, v = jnp.split(self.kv(query), 2, axis=-1)
         q = self.q(query)
 
-        split = lambda x: x.reshape(B, -1, H, hd).transpose(0, 2, 1, 3)
+        # Packed rows (Tiger.forward_packed): the query batch is G times the
+        # key batch, a memory row's G examples one after another. They share
+        # the row's K/V as one query axis of G * Lq; key_padding_mask stays
+        # per example, (B, Lk).
+        Bk = k.shape[0]
+        G = B // Bk
+        if G * Bk != B:
+            raise ValueError(f"query batch {B} is not a multiple of key batch {Bk}")
+        split = lambda x: x.reshape(Bk, -1, H, hd).transpose(0, 2, 1, 3)
         q, k, v = split(q), split(k), split(v)
         Lk = k.shape[2]
 
@@ -109,7 +121,11 @@ class T5Attention(nn.Module):
         scores = scores.astype(jnp.float32)
         if self.has_relative_bias and not self.is_cross_attention:
             scores = scores + self._position_bias(Lq, Lk)
-        if key_padding_mask is not None:  # True = padding
+        if key_padding_mask is not None and G > 1:
+            pad = key_padding_mask.reshape(Bk, 1, G, 1, Lk)
+            grouped = scores.reshape(Bk, H, G, Lq, Lk)
+            scores = jnp.where(pad, _NEG, grouped).reshape(scores.shape)
+        elif key_padding_mask is not None:  # True = padding
             scores = jnp.where(key_padding_mask[:, None, None, :], _NEG, scores)
         if attn_mask is not None:  # additive, (Lq, Lk) or broadcastable
             scores = scores + attn_mask

@@ -217,7 +217,10 @@ class Tiger(nn.Module):
         the benchmark's adapter). Rows laid out any other way (a segment
         split or interleaved) would need the per-row bias back. Decoders
         stay per example — (R*S, D+1) rows cross-attending into their own
-        segment of the packed memory via a per-example memory mask.
+        segment of the packed memory via a per-example memory mask. The
+        memory itself stays (R, L): cross-attention projects each row's
+        K/V once and attends the row's S examples against them together
+        (`T5Attention`, query batch S times the key batch).
 
         Shapes: token operands (R, L); ``target_ids`` (R, S, D);
         ``segment_valid`` (R, S) with S = max segments per row. Loss is the
@@ -246,15 +249,13 @@ class Tiger(nn.Module):
         tgt_types = jnp.broadcast_to(jnp.arange(D), (N, D))
         dec = self._decoder_input(N, tgt_flat, tgt_types)
         dec = self.in_proj(self.drop(self.norm(dec), deterministic=deterministic))
-        # Per-example memory: segment s of row r, selected by mask. The
-        # repeat is decoder-side only (N ≈ examples, same as the unpacked
-        # decoder batch) — the packed ENCODER ran R rows, which is the win.
-        mem = jnp.repeat(memory, S, axis=0)  # (N, L, attn_dim)
+        # Example n = r * S + s reads segment s + 1 of row r's memory, the
+        # rest masked; the memory stays (R, L, attn_dim).
         seg_of = jnp.tile(jnp.arange(1, S + 1), R)  # (N,)
         mem_pad = jnp.repeat(segment_ids, S, axis=0) != seg_of[:, None]
         with jax.named_scope("decoder"):
             out = self.transformer.decoder(
-                dec, mem,
+                dec, memory,
                 attn_mask=causal_mask(dec.shape[1]),
                 memory_key_padding_mask=mem_pad,
                 deterministic=deterministic,

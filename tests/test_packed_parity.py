@@ -372,6 +372,53 @@ def test_tiger_packed_per_example_losses_match_unpacked():
             assert per_packed[r, s] == pytest.approx(per_unpacked[idx], abs=1e-5)
 
 
+def _cross_attention_case(R=3, S=4, Lq=4, L=7, d=32, H=4):
+    """A cross-attention module, its params, R memory rows of L keys, R*S
+    query examples of Lq positions and their per-example key mask: row 0
+    holds three segments and an empty fourth slot, row 1 one segment,
+    row 2 all four, so slots with no key at all (every key masked) occur."""
+    from genrec_tpu.models.t5transformer import T5Attention
+
+    attn = T5Attention(d, H, is_cross_attention=True, has_relative_bias=False)
+    rng = np.random.default_rng(7)
+    memory = jnp.asarray(rng.normal(size=(R, L, d)), jnp.float32)
+    query = jnp.asarray(rng.normal(size=(R * S, Lq, d)), jnp.float32)
+    segment_ids = np.array([[1, 1, 2, 2, 2, 3, 0],
+                            [1, 1, 1, 1, 1, 0, 0],
+                            [1, 2, 2, 3, 4, 4, 4]])[:R, :L]
+    mask = jnp.asarray(np.repeat(segment_ids, S, axis=0)
+                       != np.tile(np.arange(1, S + 1), R)[:, None])
+    assert bool(mask.all(axis=1).any())  # an empty slot is in the case
+    params = attn.init(jax.random.key(0), query, memory, memory)["params"]
+    return attn, params, memory, query, mask
+
+
+def test_grouped_cross_attention_equals_repeated_memory():
+    """Queries of R*S examples against R memory rows (each row's K/V read
+    once, the S examples grouped) == the same module fed the memory
+    repeated S-fold, forward and gradients w.r.t. params and memory."""
+    S = 4
+    attn, params, memory, query, mask = _cross_attention_case(S=S)
+    w = jnp.asarray(np.random.default_rng(8).normal(size=query.shape), jnp.float32)
+
+    def loss(p, mem, repeat):
+        kv = jnp.repeat(mem, S, axis=0) if repeat else mem
+        out = attn.apply({"params": p}, query, kv, kv, key_padding_mask=mask)
+        return jnp.sum(out * w), out
+
+    (_, grouped), g_grouped = jax.value_and_grad(loss, (0, 1), has_aux=True)(
+        params, memory, False)
+    (_, repeated), g_repeated = jax.value_and_grad(loss, (0, 1), has_aux=True)(
+        params, memory, True)
+    np.testing.assert_allclose(np.asarray(grouped), np.asarray(repeated), atol=1e-5)
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=2e-5, rtol=2e-4
+        ),
+        g_grouped, g_repeated,
+    )
+
+
 def test_tiger_packed_accum_weighting_invariant_to_row_order():
     """Under gradient accumulation, packed microbatches carry VARYING
     example counts; the trainer rescales each microbatch loss by
@@ -549,6 +596,43 @@ def test_tiger_packed_grad_has_no_per_row_bias_lookup():
     old_sizes = _lookup_sizes(old.jaxpr)
     assert ("gather", per_row) in old_sizes
     assert any(n != "gather" and s == per_row for n, s in old_sizes)
+
+
+def test_tiger_packed_grad_never_repeats_the_memory():
+    """The packed decoder reads each row's memory once: no operation of
+    the compiled gradient yields R*S*L*attn_dim elements, the size of a
+    memory repeated per example ([R*S, L, attn_dim]: the repeat, its K
+    and V) and of its head-split K/V ([R*S, H, L, hd])."""
+    from genrec_tpu.analysis.ir import hlo_ops_of_size, optimized_hlo
+
+    R, L, S = 5, 19, 4
+    model, params = _tiger_small(n_layers=4)  # 2 encoder + 2 decoder layers
+    z = lambda *shape: np.zeros(shape, np.int32)
+    packed = {
+        "item_input_ids": z(R, L), "token_type_ids": z(R, L),
+        "user_token_ids": z(R, L), "user_mask": z(R, L),
+        "segment_ids": z(R, L), "positions": z(R, L),
+        "target_ids": z(R, S, 3), "segment_valid": z(R, S),
+    }
+    hlo = optimized_hlo(jax.grad(_tiger_packed_loss(model, packed)), params)
+    repeated = hlo_ops_of_size(hlo, R * S * L * model.attn_dim)
+    assert not repeated, repeated[:3]
+
+    # The detector bites on the form it exists to catch: the same
+    # cross-attention fed an explicit jnp.repeat of the memory.
+    attn, p, memory, query, mask = _cross_attention_case(S=S)
+    n_rows, n_keys, d = memory.shape
+
+    def cross_grad(repeat):
+        def loss(p, mem):
+            kv = jnp.repeat(mem, S, axis=0) if repeat else mem
+            return attn.apply({"params": p}, query, kv, kv,
+                              key_padding_mask=mask).sum()
+        return optimized_hlo(jax.grad(loss, (0, 1)), p, memory)
+
+    size = n_rows * S * n_keys * d
+    assert hlo_ops_of_size(cross_grad(repeat=True), size)
+    assert not hlo_ops_of_size(cross_grad(repeat=False), size)
 
 
 def test_tiger_packed_rel_bias_grads_match_at_the_segment_cap():
